@@ -30,6 +30,7 @@ from __future__ import annotations
 import dataclasses
 import enum
 import json
+import logging
 import os
 from typing import Iterable, Optional, Sequence
 
@@ -925,6 +926,12 @@ def load_game_dataset_avro(
                                   policy=policy)
     if fast is not None:
         return fast
+    # said out loud: the interpreted reader is orders of magnitude slower,
+    # and a missing toolchain or a stale library would otherwise only show
+    # as a slow ingest
+    logging.getLogger(__name__).warning(
+        "native columnar Avro decoder unavailable or declined this input; "
+        "using the interpreted Avro reader for %d path(s)", len(paths))
     if policy is not None:
         # shard-granular interpreted fallback: quarantine per part file
         part_files = [f for p in paths for f in _columnar_part_paths(p)]

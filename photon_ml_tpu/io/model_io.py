@@ -212,8 +212,9 @@ def save_game_model(model, output_dir: str,
                 raw_ids = np.asarray(vocab)[np.asarray(sub.entity_codes)]
             records = []
             for e in range(coefs.shape[0]):
-                glm = GeneralizedLinearModel(
-                    Coefficients(jnp.asarray(coefs[e])), task)
+                # a host row: writing a file needs no device (this used to
+                # be one host->device->host round trip per entity)
+                glm = GeneralizedLinearModel(Coefficients(coefs[e]), task)
                 records.append(glm_to_record(str(raw_ids[e]), glm, index_map))
             # Partitioned output (numberOfOutputFilesForRandomEffectModel).
             chunks = np.array_split(np.arange(len(records)),

@@ -15,7 +15,8 @@ when armed via ``--device-telemetry``:
   via the AOT API (``fn.lower(*args).compile()``) inside an
   ``xla.compile`` span, records ``compiles{site}`` and
   ``compile_secs{site}``, and captures the executable's
-  ``cost_analysis()`` flops / bytes-accessed into the span labels (and
+  ``cost_analysis()`` flops / bytes-accessed and the donated bytes it
+  aliases (``alias_bytes``) into the span labels (and
   the ``xla_flops{site}`` / ``xla_bytes_accessed{site}`` gauges, which
   ``tools/trace_report.py --device`` joins with span self-time),
 - diffs every *retrace* (a new signature at a site that already
@@ -202,6 +203,16 @@ def _cost_analysis(compiled) -> tuple[Optional[float], Optional[float]]:
             float(nbytes) if nbytes is not None else None)
 
 
+def _alias_bytes(compiled) -> Optional[int]:
+    """Bytes of donated inputs the executable really aliases to outputs
+    (0 = nothing donated, or the donation was unusable), or None where the
+    backend doesn't report a memory analysis."""
+    try:
+        return int(compiled.memory_analysis().alias_size_in_bytes)
+    except Exception:
+        return None
+
+
 def _compile_here(site: "_Site", fn, args, static_argnums, signature):
     """Signature miss: run the compile EXPLICITLY (AOT), attribute it,
     cache the executable. Returns the call's result."""
@@ -220,12 +231,13 @@ def _compile_here(site: "_Site", fn, args, static_argnums, signature):
         # photonlint: allow-W201(host-side compile timing: call() bypasses this whole path when a jax trace is active)
         secs = time.perf_counter() - t0
         site.cache[signature] = _FALLBACK
-        flops = nbytes = None
+        flops = nbytes = alias = None
     else:
         # photonlint: allow-W201(host-side compile timing: call() bypasses this whole path when a jax trace is active)
         secs = time.perf_counter() - t0
         site.cache[signature] = compiled
         flops, nbytes = _cost_analysis(compiled)
+        alias = _alias_bytes(compiled)
         result = _call_compiled(site, fn, compiled, args, static_argnums,
                                 signature)
     labels = {"site": site.name, "secs": round(secs, 6)}
@@ -235,6 +247,8 @@ def _compile_here(site: "_Site", fn, args, static_argnums, signature):
     if nbytes is not None:
         labels["bytes_accessed"] = nbytes
         registry.gauge("xla_bytes_accessed").set(nbytes, site=site.name)
+    if alias is not None:
+        labels["alias_bytes"] = alias
     registry.counter("compiles").inc(site=site.name)
     registry.counter("compile_secs").inc(secs, site=site.name)
     with trace.span("xla.compile", **labels):
@@ -281,7 +295,7 @@ def call(site_name: str, fn, args: Sequence,
         return fn(*args)
     import jax.core
 
-    if not jax.core.trace_state_clean():
+    if not jax.core.trace_ctx.is_top_level():
         # called under jit/vmap/shard_map tracing (e.g. the vmapped
         # per-entity solver): the inner call compiles into the OUTER
         # executable — nothing to attribute here, and AOT would break

@@ -78,11 +78,10 @@ def run_manifest(flags: Optional[dict] = None,
     import jax
 
     if probe_backend:
-        try:
-            device_count = jax.device_count()
-            backend = jax.default_backend()
-        except RuntimeError:  # backend not initializable (bare host)
-            device_count, backend = 0, "uninitialized"
+        # a backend that cannot initialize fails the run here: a manifest
+        # never records a device the process does not have
+        device_count = jax.device_count()
+        backend = jax.default_backend()
     else:
         device_count, backend = None, "deferred"
     repo_dir = os.path.dirname(os.path.dirname(os.path.dirname(

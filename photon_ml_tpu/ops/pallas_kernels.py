@@ -28,15 +28,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU memory spaces (absent on some CPU-only builds)
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-    _SMEM = pltpu.SMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = _SMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from photon_ml_tpu.ops.losses import PointwiseLoss
 
@@ -53,6 +45,15 @@ MAX_PALLAS_DIM = 4096
 # Below this many elements the two-pass XLA form is already cache-resident;
 # the kernel's win is HBM traffic, so only engage at real sizes.
 MIN_PALLAS_ELEMENTS = 1 << 21
+
+
+# f32 operands multiply at full precision. Mosaic's default contracts them
+# in reduced-precision MXU passes: on a v5e at 262144x2048 that left the
+# gradient sum 4e-4 (relative) off the float64 sums where this setting and
+# the two-pass XLA form are within 1e-6, and L-BFGS stalled at a 4x larger
+# gradient norm; full precision costs ~5% of the kernel's time there
+# (PERF.md, PR 22). A bf16 X is already exact in one pass.
+_F32_DOT_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _tile_rows(d: int, itemsize: int = 4) -> int:
@@ -74,7 +75,7 @@ def pallas_supported(n: int, d: int, dtype,
     precision choice (build the batch with dtype=bfloat16)."""
     if os.environ.get("PHOTON_DISABLE_PALLAS"):
         return False
-    if pltpu is None or jax.default_backend() != "tpu":
+    if jax.default_backend() != "tpu":
         return False
     if not inside_shard_map and jax.device_count() > 1:
         return False
@@ -106,6 +107,7 @@ def _kernel(loss: PointwiseLoss, n_rows: int,
     # Zero padded edge rows by SELECTION, not multiplication — out-of-bounds
     # block rows may be NaN (interpret mode pads with NaN) and 0*NaN = NaN.
     x_dtype = x_ref.dtype
+    precision = _F32_DOT_PRECISION if x_dtype == jnp.float32 else None
     X = jnp.where(mask_col > 0.0, x_ref[...], jnp.zeros((), x_dtype))
     # Mosaic wants 2D operands on both matmuls: [T,D]@[D,1] and [1,T]@[T,D].
     # w arrives as a [1, D] f32 block; cast to X's dtype so a bf16 X rides
@@ -113,6 +115,7 @@ def _kernel(loss: PointwiseLoss, n_rows: int,
     w_col = jnp.transpose(w_ref[...], (1, 0)).astype(x_dtype)  # [D, 1]
     z = (jax.lax.dot_general(
         X, w_col, (((1,), (0,)), ((), ())),
+        precision=precision,
         preferred_element_type=jnp.float32).reshape(-1)
         + off_ref[...].reshape(-1) + shift_ref[0, 0])
     y = y_ref[...].reshape(-1)
@@ -126,7 +129,7 @@ def _kernel(loss: PointwiseLoss, n_rows: int,
     pre_ref[0, 0] += jnp.sum(wd)
     vec_ref[...] += jax.lax.dot_general(
         wd.reshape(1, -1).astype(x_dtype), X, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=precision, preferred_element_type=jnp.float32)
 
 
 def _xla_sums(loss: PointwiseLoss, X, labels, offsets, weights, w_eff,
@@ -190,14 +193,14 @@ def fused_value_gradient_sums(
             row_spec,  # weights
             pl.BlockSpec((1, d), lambda i: (0, 0)),  # w_eff
             pl.BlockSpec((1, 1), lambda i: (0, 0),
-                         memory_space=_SMEM if _SMEM else None),
+                         memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0),
-                         memory_space=_SMEM if _SMEM else None),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((1, d), lambda i: (0, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0),
-                         memory_space=_SMEM if _SMEM else None),
+                         memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, 1), jnp.float32),

@@ -40,23 +40,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:  # moved across jax versions
-    from jax import shard_map as _shard_map_new  # jax >= 0.8
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        try:
-            return _shard_map_new(f, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_vma=False)
-        except TypeError:  # older keyword spelling
-            return _shard_map_new(f, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_rep=False)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-
 from photon_ml_tpu.data.batch import Batch, pad_batch
 from photon_ml_tpu.models.glm import GeneralizedLinearModel
 from photon_ml_tpu.optimize.common import OptimizationResult, solver_x0
@@ -68,6 +51,14 @@ from photon_ml_tpu.parallel.quantized_collectives import (
 )
 
 Array = jnp.ndarray
+
+
+def _shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the varying-manual-axes check off: every
+    caller's outputs are psum-identical across devices, which the checker
+    cannot prove through a solver ``while_loop``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def run_glm_shard_map(
@@ -96,32 +87,7 @@ def run_glm_shard_map(
 
     dim = batch.num_features
     x0 = solver_x0(batch.acc_dtype, dim, initial)
-    # psum-ing objective: every reduction crosses the data axis.
-    obj = dataclasses.replace(problem.objective(), axis_name=DATA_AXIS)
-    row_specs = jax.tree_util.tree_map(lambda _: P(DATA_AXIS), batch)
-
-    shard_update = problem.shard_weight_update
-    if shard_update and (problem.box is not None or problem.track_iterates):
-        logging.getLogger(__name__).warning(
-            "shard_weight_update is incompatible with box constraints / "
-            "track_iterates; falling back to the replicated update")
-        shard_update = False
-
-    if shard_update:
-        local_fit = _sharded_update_local_fit(problem, obj, dim, n_shards,
-                                              x0.dtype)
-    else:
-        def local_fit(shard, x0_rep):
-            x, history, progressed = problem.solve(obj, shard, x0_rep)
-            return x, history, progressed
-
-    # grads are psum-identical on every device, but the replication checker
-    # can't prove it through the while_loop — checking is disabled.
-    fit = _shard_map(
-        local_fit, mesh,
-        in_specs=(row_specs, P()),
-        out_specs=(P(), P(), P()),
-    )
+    fit, shard_update = sharded_fit(problem, batch, mesh, x0.dtype)
     x, history, progressed = jax.jit(fit)(batch, x0)
 
     # Host-side collective-traffic ledger (collectives run inside the
@@ -144,6 +110,45 @@ def run_glm_shard_map(
     # Variances/publication run on the full (GSPMD-sharded) batch.
     return problem.publish(x, history, progressed, problem.objective(),
                            batch)
+
+
+def sharded_fit(problem: GLMOptimizationProblem, batch: Batch, mesh,
+                x0_dtype):
+    """The shard_map-wrapped per-device solve ``fit(batch, x0) -> (x,
+    history, progressed)`` for ``batch``'s layout, and whether the sharded
+    weight update engaged. Only the batch's structure and width are read,
+    so its leaves may be arrays or ``jax.ShapeDtypeStruct``s (ahead-of-time
+    compiles for a described topology, tests/test_tpu_compile.py). Rows
+    must already divide the mesh data axis."""
+    n_shards = mesh.shape[DATA_AXIS]
+    dim = batch.num_features
+    # psum-ing objective: every reduction crosses the data axis.
+    obj = dataclasses.replace(problem.objective(), axis_name=DATA_AXIS)
+    row_specs = jax.tree_util.tree_map(lambda _: P(DATA_AXIS), batch)
+
+    shard_update = problem.shard_weight_update
+    if shard_update and (problem.box is not None or problem.track_iterates):
+        logging.getLogger(__name__).warning(
+            "shard_weight_update is incompatible with box constraints / "
+            "track_iterates; falling back to the replicated update")
+        shard_update = False
+
+    if shard_update:
+        local_fit = _sharded_update_local_fit(problem, obj, dim, n_shards,
+                                              x0_dtype)
+    else:
+        def local_fit(shard, x0_rep):
+            x, history, progressed = problem.solve(obj, shard, x0_rep)
+            return x, history, progressed
+
+    # grads are psum-identical on every device, but the replication checker
+    # can't prove it through the while_loop — checking is disabled.
+    fit = _shard_map(
+        local_fit, mesh,
+        in_specs=(row_specs, P()),
+        out_specs=(P(), P(), P()),
+    )
+    return fit, shard_update
 
 
 def _sharded_update_local_fit(problem: GLMOptimizationProblem, obj,

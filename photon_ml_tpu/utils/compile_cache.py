@@ -1,145 +1,69 @@
 """Persistent XLA compilation cache for driver processes.
 
 The reference pays JVM+Spark startup per driver run; our analog cost is
-XLA compilation of the solver/evaluator kernels (~seconds per kernel on a
-remote TPU). A persistent on-disk cache makes every driver run after the
-first reuse compiled executables, so short CLI jobs (heart-sized trainings,
-scoring runs) are not dominated by compile time.
+XLA compilation of the solver/evaluator kernels. A persistent on-disk
+cache makes every driver run after the first reuse compiled executables,
+so short CLI jobs (heart-sized trainings, scoring runs) are not dominated
+by compile time.
 
-The cache directory is keyed by a machine/backend fingerprint: XLA:CPU AOT
-results encode target machine features (AVX-512 variants etc.), and loading
-an entry compiled on a different host can mis-execute ("could lead to
-execution errors such as SIGILL" per XLA's loader). A shared home directory
-must therefore never serve one machine's entries to another.
+Placement: where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
+itself and this module sets no directory. Where it is not, the cache
+lives at ONE fixed path inside the checkout (``<checkout>/.jax_cache``,
+git-ignored) — the path is part of the cache key, so a directory that
+moves (a home directory, a per-process or per-machine name) never hits.
+Every process of a run (train, score, serve) therefore shares one cache.
 
-Growth: when the running JAX exposes ``jax_compilation_cache_max_size`` the
-cache is capped (LRU-evicted by JAX) at 1 GiB and every kernel is persisted,
-however fast it compiled — short CLI runs are dominated by many sub-second
-compiles. On older JAX without the cap, JAX's default persistence thresholds
-(compile time >= 1s) apply instead, which slows growth but does not bound
-it — long-lived hosts on such versions need external cleanup.
-Opt out with ``PHOTON_DISABLE_COMPILE_CACHE=1`` or point the directory
-elsewhere with ``PHOTON_COMPILE_CACHE_DIR``.
+CPU-pinned processes (``JAX_PLATFORMS=cpu`` — the test harness) do not
+persist: XLA:CPU AOT results are specialized to the compiling machine's
+CPU features, and a checkout that travels between hosts must never serve
+one machine's entries to another.
+
+The cache is capped (LRU-evicted by JAX) at 1 GiB unless
+``JAX_COMPILATION_CACHE_MAX_SIZE`` says otherwise, and every kernel is
+persisted, however fast it compiled — short CLI runs are dominated by
+many sub-second compiles. Opt out with ``PHOTON_DISABLE_COMPILE_CACHE=1``.
 """
 
 from __future__ import annotations
 
-import hashlib
 import os
-import platform
 
-_DEFAULT_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "photon_ml_tpu", "xla")
+#: The one in-checkout location used when the environment names none.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-_MAX_CACHE_BYTES = 1 << 30  # 1 GiB, LRU-evicted by JAX where supported
+_MAX_CACHE_BYTES = 1 << 30  # 1 GiB, LRU-evicted by JAX
 
 _enabled = False
 
 
-def _machine_fingerprint(jax) -> str:
-    """Digest of everything that can change generated code: jax/jaxlib
-    versions, the active backend, platform triple, and (on Linux) the CPU
-    feature flags that XLA:CPU AOT results are specialized to."""
-    parts = [
-        platform.system(),
-        platform.machine(),
-        getattr(jax, "__version__", "?"),
-    ]
-    try:
-        import jaxlib
-
-        parts.append(getattr(jaxlib, "__version__", "?"))
-    except ImportError:  # pragma: no cover
-        pass
-    # Requested platform, WITHOUT initializing the backend: drivers enable
-    # the cache first thing in main(), and forcing TPU client init there
-    # would make --help pay multi-second startup and break any later
-    # jax.distributed.initialize() ordering.
-    parts.append(os.environ.get("JAX_PLATFORMS")
-                 or str(jax.config.jax_platforms or "default"))
-    try:
-        with open("/proc/cpuinfo") as f:
-            block = []
-            for ln in f:
-                if not ln.strip():
-                    break  # end of first processor block
-                # Model identity matters beyond the flag list: LLVM enables
-                # tuning "features" like prefer-no-gather per CPU *model*
-                # (Downfall-affected parts), so two hosts with identical
-                # flags can still produce mutually-incompatible AOT code.
-                if ln.split(":")[0].strip() in (
-                        "vendor_id", "cpu family", "model", "model name",
-                        "stepping", "microcode", "flags"):
-                    block.append(ln.strip())
-        parts.extend(block)
-    except OSError:  # pragma: no cover - non-Linux
-        pass
-    return hashlib.sha256("|".join(parts).encode()).hexdigest()[:16]
-
-
-def enable_persistent_compile_cache(allow_cpu: bool = False) -> bool:
+def enable_persistent_compile_cache() -> bool:
     """Idempotently turn on JAX's persistent compilation cache. Returns
-    whether the cache is active (False when disabled via env or the
-    backend rejects it).
-
-    ``allow_cpu=True`` keeps persistence on for CPU-pinned processes too.
-    Self-compiled XLA:CPU AOT entries reload and execute correctly on the
-    same machine (the fingerprinted directory guarantees that), but the
-    loader logs E-level lines about its own tuning-flag set
-    (prefer-no-gather/scatter) on every load — callers that opt in (the
-    bench, whose CPU-fallback glmix sweep otherwise pays ~17s of repeat
-    compiles per process) should suppress those with
-    ``TF_CPP_MIN_LOG_LEVEL=3`` before the first jax import."""
+    whether this call left it active (False when disabled via env or the
+    process is pinned to the CPU platform). Never initializes a backend:
+    drivers call this first thing in ``main()``, before argument parsing
+    and before any ``jax.distributed.initialize()``."""
     global _enabled
     if _enabled:
         return True
     if os.environ.get("PHOTON_DISABLE_COMPILE_CACHE"):
         return False
-    # CPU-only processes skip persistence by default (see allow_cpu above).
-    # Known gap: a host with NO platform pin that resolves to CPU by
-    # default still persists — resolving the real backend here would force
-    # the init this function must avoid (see the fingerprint note below).
-    try:
-        import jax as _jax
+    import jax
 
-        # an in-process jax_platforms override (scripts pin "cpu" before
-        # first backend use) wins over the environment's default
-        platforms = (str(_jax.config.jax_platforms or "")
-                     or os.environ.get("JAX_PLATFORMS", "")).strip().lower()
-    except Exception:  # pragma: no cover
-        platforms = (os.environ.get("JAX_PLATFORMS") or "").strip().lower()
-    if platforms.startswith("cpu") and not allow_cpu:
+    # an in-process jax_platforms override (scripts pin "cpu" before first
+    # backend use) wins over the environment's default
+    platforms = (str(jax.config.jax_platforms or "")
+                 or os.environ.get("JAX_PLATFORMS", "")).strip().lower()
+    if platforms.startswith("cpu"):
         return False
-    base_dir = os.environ.get("PHOTON_COMPILE_CACHE_DIR", _DEFAULT_DIR)
-    try:
-        import jax
-
-        cache_dir = os.path.join(base_dir, _machine_fingerprint(jax))
-        os.makedirs(cache_dir, exist_ok=True)
-        # One-time sweep: earlier releases wrote entries directly under the
-        # base dir (unfingerprinted, possibly compiled on another machine).
-        # JAX never reads or LRU-evicts them from there — dead bytes.
-        for entry in os.listdir(base_dir):
-            path = os.path.join(base_dir, entry)
-            if os.path.isfile(path):
-                try:
-                    os.remove(path)
-                except OSError:
-                    pass
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        try:
-            jax.config.update("jax_compilation_cache_max_size",
-                              _MAX_CACHE_BYTES)
-            capped = True
-        except AttributeError:  # size cap absent on older JAX
-            capped = False
-        if capped:
-            # Growth is bounded by the LRU cap, so persist everything:
-            # short CLI runs (heart-sized trainings, scoring) are dominated
-            # by many sub-second kernel compiles.
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        _enabled = True
-    except Exception:
-        return False
-    return _enabled
+    # whoever placed the cache also sizes it: JAX reads both variables itself
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    if not os.environ.get("JAX_COMPILATION_CACHE_MAX_SIZE"):
+        jax.config.update("jax_compilation_cache_max_size", _MAX_CACHE_BYTES)
+    # growth is bounded by the LRU cap, so persist everything
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    _enabled = True
+    return True

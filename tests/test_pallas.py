@@ -1,7 +1,9 @@
 """Pallas fused value+gradient kernel vs the two-pass XLA formulation.
 
-Runs in interpreter mode on CPU (the TPU path is exercised by bench.py on
-hardware); correctness must hold for every loss and for ragged edge tiles.
+Runs in interpreter mode on CPU: this checks the kernel's arithmetic for
+every loss and for ragged edge tiles, not that Mosaic accepts it (that is
+tests/test_tpu_compile.py, for a described chip) nor that it is right or
+fast on a chip (that is chip_smoke.py, run on one).
 """
 
 import numpy as np

@@ -2032,11 +2032,13 @@ def test_w801_seeded_pallas_accumulator_deletion(tmp_path_factory):
     needle = (
         "    z = (jax.lax.dot_general(\n"
         "        X, w_col, (((1,), (0,)), ((), ())),\n"
+        "        precision=precision,\n"
         "        preferred_element_type=jnp.float32).reshape(-1)\n")
     assert needle in src, "pallas margin matmul moved; update this test"
     target.write_text(src.replace(needle, (
         "    z = (jax.lax.dot_general(\n"
-        "        X, w_col, (((1,), (0,)), ((), ()))).reshape(-1)\n")))
+        "        X, w_col, (((1,), (0,)), ((), ())),\n"
+        "        precision=precision).reshape(-1)\n")))
     report = runner.lint(root, paths=["photon_ml_tpu"],
                          families={"W8"})
     w801 = [f for f in report.new if f.rule == "W801"

@@ -1,0 +1,230 @@
+"""Ahead-of-time compiles for a DESCRIBED TPU v5e:2x2 (no chip attached).
+
+The chip's compiler is installed with jaxlib/libtpu and compiles for a
+topology that is described, not attached — so what Mosaic or XLA:TPU
+would refuse on the chip (a misaligned kernel slice, too much VMEM, an
+unusable donation, a kernel that cannot live inside ``shard_map``) is
+refused here, at real widths, at no chip time. These are the programs
+``chip_smoke.py`` runs: the fused value+gradient kernel at the smoke's
+shapes, an L-BFGS solve with the kernel inside its ``while_loop``, the
+buffer-donating per-entity fits at a GLMix bucket shape, and the sharded
+fixed-effect / per-entity steps on a 2x2 mesh of the described devices.
+
+A compile that passes is not a chip run: nothing executes here, so this
+file says nothing about results or times.
+
+This is the ONLY file that describes the chip, and it does so inside a
+module-scoped, non-autouse fixture: one process at a time may load the
+TPU's library, every xdist worker imports every test file, and only the
+worker that is handed this file may reach that call. Code that asks
+``jax.default_backend()`` would take its CPU branch here; the tests steer
+it (monkeypatch), the program has no option for it.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from photon_ml_tpu.data.batch import DenseBatch
+from photon_ml_tpu.ops.aggregators import GLMObjective
+from photon_ml_tpu.ops.losses import get_loss
+from photon_ml_tpu.ops.pallas_kernels import (
+    MAX_PALLAS_DIM,
+    fused_value_gradient_sums,
+)
+from photon_ml_tpu.optimize.config import (
+    GLMOptimizationConfiguration,
+    OptimizerType,
+    RegularizationContext,
+    RegularizationType,
+    TaskType,
+)
+from photon_ml_tpu.optimize.problem import GLMOptimizationProblem
+from photon_ml_tpu.parallel.mesh import DATA_AXIS, ENTITY_AXIS, make_mesh
+
+# chip_smoke.py's shapes (kept in step by tests/test_chip_smoke.py)
+GLM_SHAPE = (262144, 2048)
+GLMIX_ROWS, GLMIX_FIXED_DIM = 1_000_209, 65  # 64 global features + intercept
+GLMIX_BUCKET = (675, 128, 128)  # largest of the four (E, N, D) buckets
+MESH_ROWS = 262144
+
+MOSAIC_CALL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh(topo):
+    return make_mesh(num_data=2, num_entity=2, devices=list(topo.devices))
+
+
+@pytest.fixture(autouse=True)
+def _as_the_program_runs():
+    """x64 off, as every entry point runs (tests/conftest.py turns it on
+    for finite-difference checks; under it a kernel's index maps come out
+    i64, which Mosaic refuses). And no persistent cache: a
+    described-topology executable would be written to it but cannot be
+    read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    x64_was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_enable_x64", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_x64", x64_was)
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def as_on_one_tpu(monkeypatch):
+    """Steer the kernel gate (ops/pallas_kernels.pallas_supported) the way
+    one attached chip would: its shape and dtype rules still run."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+
+
+@pytest.fixture
+def as_on_tpu_mesh(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _dense(n, d, sharding, dtype=jnp.float32) -> DenseBatch:
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    return DenseBatch(X=sds((n, d), dtype), labels=sds((n,)),
+                      offsets=sds((n,)), weights=sds((n,)))
+
+
+def _l2_problem(max_iter, tolerance, lam, **kw) -> GLMOptimizationProblem:
+    return GLMOptimizationProblem(
+        config=GLMOptimizationConfiguration(
+            max_iterations=max_iter, tolerance=tolerance,
+            regularization_weight=lam, optimizer_type=OptimizerType.LBFGS,
+            regularization_context=RegularizationContext(
+                RegularizationType.L2)),
+        task=TaskType.LOGISTIC_REGRESSION, **kw)
+
+
+@pytest.mark.parametrize("n,d,dtype", [
+    GLM_SHAPE + ("float32",),
+    GLM_SHAPE + ("bfloat16",),
+    (GLMIX_ROWS, GLMIX_FIXED_DIM, "float32"),  # ragged last tile, odd width
+    (65536, MAX_PALLAS_DIM, "float32"),
+])
+def test_fused_kernel_compiles(one_chip, n, d, dtype):
+    loss = get_loss("logistic")
+    b = _dense(n, d, one_chip, jnp.dtype(dtype))
+    w = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=one_chip)
+    shift = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda X, y, o, wt, w, s: fused_value_gradient_sums(
+            loss, False, X, y, o, wt, w, s)
+    ).lower(b.X, b.labels, b.offsets, b.weights, w, shift).compile()
+    assert MOSAIC_CALL in compiled.as_text()
+
+
+def test_lbfgs_solve_compiles_with_kernel_in_loop(one_chip, as_on_one_tpu):
+    """chip_smoke phase 1's program: train_glm_grid's solve at 262144x2048."""
+    n, d = GLM_SHAPE
+    problem = _l2_problem(80, 1e-6, 10.0)
+    x0 = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(problem.solve).lower(
+        problem.objective(), _dense(n, d, one_chip), x0).compile()
+    text = compiled.as_text()
+    assert MOSAIC_CALL in text and "while" in text
+
+
+@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
+@pytest.mark.parametrize("variant", [
+    "_fit_blocks_donate_offsets",
+    "_fit_blocks_donate_offsets_x0",
+])
+def test_donating_random_effect_fit_compiles(one_chip, variant):
+    """The variants game/random_effect._dispatch_fit takes off the CPU. At
+    a GLMix bucket N == D, so a donated [E, N] offsets (and [E, D] x0)
+    buffer has an output of its own shape to alias."""
+    from photon_ml_tpu.game import random_effect
+
+    e, n, d = GLMIX_BUCKET
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    obj = GLMObjective(loss=get_loss("logistic"), l2_lambda=1.0)
+    compiled = getattr(random_effect, variant).lower(
+        sds(e, n, d), sds(e, n), sds(e, n), sds(e, n), sds(e, d), obj,
+        sds(d), solver="lbfgs", max_iter=20, tolerance=1e-7).compile()
+    aliased = compiled.memory_analysis().alias_size_in_bytes
+    # one [E, N] f32 buffer (tile-padded) is all either variant can alias:
+    # the fit has a single [E, D] output, the coefficients. The second
+    # donation of the _x0 variant is unusable and JAX says so.
+    assert e * n * 4 <= aliased < 2 * e * n * 4, (variant, aliased)
+
+
+def test_sharded_fixed_effect_step_compiles(mesh, as_on_tpu_mesh):
+    """``chip_smoke.py --chips 4``'s fixed-effect update: the solver inside
+    ``shard_map`` over the data axis of a 2x2 mesh, the fused kernel on
+    each shard, the weight update sharded (what --re-entity-shards sets)."""
+    from photon_ml_tpu.parallel.distributed import sharded_fit
+
+    problem = _l2_problem(40, 1e-7, 10.0, shard_weight_update=True)
+    batch = _dense(MESH_ROWS, GLMIX_FIXED_DIM,
+                   NamedSharding(mesh, P(DATA_AXIS)))
+    x0 = jax.ShapeDtypeStruct((GLMIX_FIXED_DIM,), jnp.float32,
+                              sharding=NamedSharding(mesh, P()))
+    fit, shard_update = sharded_fit(problem, batch, mesh, jnp.float32)
+    assert shard_update
+    compiled = jax.jit(fit).lower(batch, x0).compile()
+    text = compiled.as_text()
+    assert MOSAIC_CALL in text
+    assert "all-reduce" in text
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    # rows split over the data axis: each device holds half of X
+    assert per_device < 0.6 * MESH_ROWS * GLMIX_FIXED_DIM * 4 * 1.1
+
+
+def test_sharded_random_effect_fit_compiles(mesh):
+    """The entity-sharded per-entity solve: lanes split over the mesh
+    entity axis, no collective inside the solve."""
+    from photon_ml_tpu.game.random_effect import _sharded_fit_fn
+
+    e, n, d = 676, GLMIX_BUCKET[1], GLMIX_BUCKET[2]  # lanes divide entity=2
+    lane = NamedSharding(mesh, P(ENTITY_AXIS))
+    rep = NamedSharding(mesh, P())
+
+    def sds(shape, sharding):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+    obj = GLMObjective(loss=get_loss("logistic"), l2_lambda=1.0)
+    compiled = _sharded_fit_fn(mesh, "lbfgs", 20, 1e-7, False, False).lower(
+        sds((e, n, d), lane), sds((e, n), lane), sds((e, n), lane),
+        sds((e, n), lane), sds((e, d), lane), obj, sds((d,), rep)).compile()
+    text = compiled.as_text()
+    assert "all-reduce" not in text
+    assert (compiled.memory_analysis().argument_size_in_bytes
+            < 0.6 * e * n * d * 4 * 1.1)

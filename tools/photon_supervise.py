@@ -345,6 +345,9 @@ def supervise_fleet(member_args: list[str], *, fleet: int,
     endpoints = [f"unix:{s}" for s in sockets]
 
     def spawn_member(k: int) -> subprocess.Popen:
+        # every member gets the same environment, so on an accelerator
+        # every member takes the same chip(s): K > 1 members need K chips
+        # and a member->chip assignment that does not exist yet (README)
         args = (list(member_args)
                 + ["--listen", endpoints[k],
                    "--trace-dir", os.path.join(fleet_dir, f"member{k}")]
