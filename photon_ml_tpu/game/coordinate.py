@@ -50,7 +50,7 @@ from photon_ml_tpu.game.random_effect import (
     score_random_effect,
 )
 from photon_ml_tpu.models.glm import Coefficients, GeneralizedLinearModel
-from photon_ml_tpu.optimize.common import OptimizationResult
+from photon_ml_tpu.optimize.common import OptimizationResult, record_solve
 from photon_ml_tpu.optimize.config import TaskType
 from photon_ml_tpu.optimize.problem import GLMOptimizationProblem
 from photon_ml_tpu.sampler.samplers import down_sample
@@ -95,13 +95,23 @@ class RandomEffectTracker:
     per-entity arrays materialize with a SINGLE ``jax.device_get`` of the
     whole tuple on first use (``summary()``/``counts_by_convergence()``,
     i.e. log or metrics time), where they are also sliced to ``num_real``
-    entities (the single-block solver returns entity-axis pad lanes)."""
+    entities (the single-block solver returns entity-axis pad lanes).
+
+    The same fetch brings the evaluation counts (``LaneCounts``, None from
+    a caller that has none), and the first materialization books them on
+    the ``solver_*{site}`` counters: a training that never looks at a
+    tracker counts nothing for it."""
 
     iterations: np.ndarray  # [E] (device array until materialized)
     final_values: np.ndarray  # [E]
     convergence_codes: Optional[np.ndarray] = None  # [E] int8
     # lazy slice bound: real entity count (None = already compact)
     num_real: Optional[int] = None
+    # game/random_effect.LaneCounts, field for field
+    evaluations: Optional[np.ndarray] = None  # [E] each entity's own
+    evaluation_rounds: Optional[np.ndarray] = None  # [B]
+    bucket_lanes: Optional[np.ndarray] = None  # [B], pad lanes included
+    site: str = "re.fit_blocks"
 
     def materialize(self) -> "RandomEffectTracker":
         """Fetch the per-entity arrays host-side (one explicit
@@ -109,19 +119,43 @@ class RandomEffectTracker:
         if not isinstance(self.iterations, np.ndarray):
             from photon_ml_tpu.utils.sync_telemetry import record_host_fetch
 
-            it, v, c = jax.device_get(tuple(
+            it, v, c, ev, rounds = jax.device_get(tuple(
                 None if a is None else ensure_addressable(a)
                 for a in (self.iterations, self.final_values,
-                          self.convergence_codes)))
+                          self.convergence_codes, self.evaluations,
+                          self.evaluation_rounds)))
             record_host_fetch(site="tracker.materialize")
             nr = self.num_real
             if nr is not None:
                 it, v = it[:nr], v[:nr]
                 c = None if c is None else c[:nr]
+                ev = None if ev is None else ev[:nr]
             self.iterations, self.final_values = np.asarray(it), np.asarray(v)
             self.convergence_codes = None if c is None else np.asarray(c)
             self.num_real = None
+            if ev is not None:
+                self.evaluations = np.asarray(ev)
+                self.evaluation_rounds = np.asarray(rounds)
+                record_solve(
+                    self.site, int(self.iterations.sum()),
+                    int(self.evaluations.sum()),
+                    lane_evaluations=self._lane_evaluations())
         return self
+
+    def _lane_evaluations(self) -> int:
+        """What the batched loops ran: per program, lanes x rounds."""
+        return int(np.dot(self.bucket_lanes, self.evaluation_rounds))
+
+    def lane_fill(self) -> Optional[float]:
+        """Evaluations the entities needed over the lane-evaluations the
+        batched loops ran for them (pad lanes included): at most 1, by
+        construction an upper bound on the true fill (``rounds`` is a
+        lower bound, see ``game/random_effect._fit_blocks_impl``)."""
+        self.materialize()
+        if self.evaluations is None:
+            return None
+        ran = self._lane_evaluations()
+        return float(self.evaluations.sum()) / ran if ran else None
 
     def counts_by_convergence(self) -> dict[str, int]:
         """reason name -> entity count
@@ -256,13 +290,14 @@ class RandomEffectCoordinate:
         # CD score vector every update, so the solver may reuse its device
         # buffer in place (no-op on CPU; ``coefs`` — the CD loop's live
         # last-good state — is never donated, see _dispatch_fit)
-        new_coefs, iters, values, codes = self.problem.run(
+        new_coefs, iters, values, codes, counts = self.problem.run(
             self.dataset, offsets, initial=coefs, donate=True)
         # lazy tracker: arrays stay on device until log/metrics time; the
         # num_real bound trims the single-block path's entity-axis PAD
         # lanes at materialization (the bucketed path is already compact)
         tracker = RandomEffectTracker(
-            iters, values, codes, num_real=len(self.dataset.entity_codes))
+            iters, values, codes, num_real=len(self.dataset.entity_codes),
+            **counts._asdict())
         return new_coefs, tracker
 
     def score(self, coefs: Array) -> Array:
@@ -364,10 +399,11 @@ class FactoredRandomEffectCoordinate:
                                          random_projector=None)
             # donate=False: ``offsets`` is reused across inner iterations
             # and by the Kronecker refit below — its buffer must survive
-            coefs, iters, values, codes = self.problem.run(
+            coefs, iters, values, codes, counts = self.problem.run(
                 lat_ds, offsets, initial=coefs, donate=False)
             re_tracker = RandomEffectTracker(
-                iters, values, codes, num_real=len(ds.entity_codes))
+                iters, values, codes, num_real=len(ds.entity_codes),
+                **counts._asdict())
             # (2) projection-matrix fit on Kronecker features c_e ⊗ x.
             e, n, d = ds.X.shape
             k = self.latent_dim

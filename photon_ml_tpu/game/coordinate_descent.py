@@ -56,6 +56,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import itertools
 import time
 from typing import Callable, Optional
 
@@ -183,6 +184,10 @@ class _InFlight:
     snapshot_next_ci: int
     t_wall: float
     t_dispatched: float
+    # serial of the dispatch within this run: the ``update`` label that
+    # joins the block's cd.dispatch span to its later cd.pipeline_wait /
+    # cd.epilogue_fetch (a pipelined block is fetched one dispatch later)
+    update: int = 0
     pipelined: bool = False  # a later dispatch was issued before this fetch
 
 
@@ -245,17 +250,18 @@ def make_update_epilogue(task: TaskType, num_samples: int):
     @jax.jit
     def epilogue(score_list, reg_list, state_leaves, labels, weights,
                  offsets):
-        total = _canonical_sum(score_list, num_samples)
-        l, _ = loss.loss_and_d1(total + offsets, labels)
-        train_loss = jnp.sum(weights * l)
-        reg_total = 0.0
-        for r in reg_list:  # ids order (python floats stay op-free)
-            reg_total = reg_total + r
-        objective = train_loss + reg_total
-        state_finite = jnp.asarray(True)
-        for leaf in state_leaves:
-            state_finite = state_finite & jnp.all(jnp.isfinite(leaf))
-        finite = state_finite & jnp.isfinite(objective)
+        with jax.named_scope("cd.epilogue"):
+            total = _canonical_sum(score_list, num_samples)
+            l, _ = loss.loss_and_d1(total + offsets, labels)
+            train_loss = jnp.sum(weights * l)
+            reg_total = 0.0
+            for r in reg_list:  # ids order (python floats stay op-free)
+                reg_total = reg_total + r
+            objective = train_loss + reg_total
+            state_finite = jnp.asarray(True)
+            for leaf in state_leaves:
+                state_finite = state_finite & jnp.all(jnp.isfinite(leaf))
+            finite = state_finite & jnp.isfinite(objective)
         return total, objective, train_loss, reg_total, finite, state_finite
 
     return epilogue
@@ -671,6 +677,8 @@ def run_coordinate_descent(
                         % checkpoint_every_coordinates == 0
                         for ci, _ in block))
 
+    update_serial = itertools.count()
+
     def dispatch_update(block, it, attempt, base_total, overlay,
                         snapshot_due=False, snapshot_next_ci=0):
         """Dispatch one block of candidate updates + ONE fused epilogue
@@ -705,9 +713,10 @@ def run_coordinate_descent(
         new_scores: dict = {}
         new_regs: dict = {}
         cids = ",".join(cid for _, cid in block)
+        update = next(update_serial)
         try:
             with trace.span("cd.dispatch", sweep=it, size=len(block),
-                            coordinates=cids):
+                            coordinates=cids, update=update):
                 for ci, cid in block:
                     coord = coordinates[cid]
                     partial = base_total - (
@@ -758,7 +767,7 @@ def run_coordinate_descent(
             update_counts_before=counts_before,
             snapshot_due=snapshot_due,
             snapshot_next_ci=snapshot_next_ci,
-            t_wall=t_wall, t_dispatched=time.perf_counter())
+            t_wall=t_wall, t_dispatched=time.perf_counter(), update=update)
 
     def _set_update_counts(block, counts):
         for _, cid in block:
@@ -787,7 +796,7 @@ def run_coordinate_descent(
             HOT_LOOP_STATS["pipelined_resolves"] += 1
             HOT_LOOP_STATS["overlap_secs"] += max(0.0,
                                                   t0 - p.t_dispatched)
-        span_labels = {"sweep": p.it}
+        span_labels = {"sweep": p.it, "update": p.update}
         if len(p.block) == 1:
             span_labels["coordinate"] = p.block[0][1]
         else:

@@ -27,7 +27,7 @@ import dataclasses
 import logging
 import time
 from functools import lru_cache, partial
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -76,6 +76,19 @@ CONVERGENCE_CODE_NAMES = {
     CONV_GRADIENT: "GradientConverged",
     CONV_NOT_PROGRESSED: "ObjectiveNotImproving",
 }
+
+
+class LaneCounts(NamedTuple):
+    """Objective evaluations of one coordinate's batched solves: what each
+    lane needed against what the batched loops ran (see
+    :func:`_fit_blocks_impl` for what ``rounds`` can and cannot see). The
+    fields are ``RandomEffectTracker``'s, which takes them as they are."""
+
+    evaluations: Array  # [E] int32: each lane's own count
+    # per dispatched program (a bucket, a compaction chunk, a shard):
+    evaluation_rounds: Array  # [B] int32: the rounds it ran
+    bucket_lanes: np.ndarray  # [B] host: its lanes, pads included
+    site: str  # the obs/compile.py site the programs dispatched through
 
 
 def _vg(w, payload):
@@ -207,8 +220,21 @@ def _fit_blocks_impl(
 ):
     """vmapped solve over entity blocks; returns (coefs [E,D], iters [E],
     final loss values [E], convergence codes [E] int8 — see
-    CONVERGENCE_CODE_NAMES), plus a per-lane solver carry when
-    ``return_carry``. ``solver`` is "lbfgs"/"owlqn"/"tron".
+    CONVERGENCE_CODE_NAMES — evaluations [E] int32, rounds [1] int32), plus
+    a per-lane solver carry when ``return_carry``. ``solver`` is
+    "lbfgs"/"owlqn"/"tron".
+
+    ``evaluations`` is each lane's own count of objective evaluations
+    (the sum of its ``RunHistory.evaluations``: what its solo solve would
+    make). ``rounds`` = Σ_k max over lanes of ``evaluations[k]``: the
+    rounds of evaluation the batched loop ran for this dispatch, as far as
+    the lanes themselves can tell. It is a LOWER bound on what the device
+    executed: a finished lane still rides every later round, and the trips
+    it makes there are discarded, so they are not visible from inside
+    ``vmap``; nor is a round in which lane A needed its 3rd trial of
+    iteration k while lane B was already at k+1 (the per-iteration maxima
+    assume the lanes' iterations line up, which the batched outer loop
+    enforces). Shape [1], so a sharded dispatch concatenates one per shard.
 
     ``boundary_convergence`` is set by the lane-compaction driver on
     NON-final chunks: a lane that satisfies a convergence criterion on
@@ -285,15 +311,22 @@ def _fit_blocks_impl(
         else:
             exhausted = CONV_MAX_ITERATIONS
         code = jnp.where(k >= max_iter, exhausted, converged)
-        if return_carry:
-            return x, k, final_value, code.astype(jnp.int8), carry
-        return x, k, final_value, code.astype(jnp.int8)
+        return (x, k, final_value, code.astype(jnp.int8), hist.evaluations,
+                carry)
 
-    if resume is None:
-        return jax.vmap(
-            lambda Xe, ye, oe, we, x0: solve_one(Xe, ye, oe, we, x0, None)
-        )(X, labels, offsets, weights, initial)
-    return jax.vmap(solve_one)(X, labels, offsets, weights, initial, resume)
+    with jax.named_scope("re.solve"):
+        if resume is None:
+            out = jax.vmap(
+                lambda Xe, ye, oe, we, x0: solve_one(Xe, ye, oe, we, x0,
+                                                     None)
+            )(X, labels, offsets, weights, initial)
+        else:
+            out = jax.vmap(solve_one)(X, labels, offsets, weights, initial,
+                                      resume)
+    x, k, final_value, code, evals_by_iter, carry = out  # [E, max_iter+1]
+    counted = (x, k, final_value, code, jnp.sum(evals_by_iter, axis=1),
+               jnp.sum(jnp.max(evals_by_iter, axis=0), keepdims=True))
+    return counted + (carry,) if return_carry else counted
 
 
 _STATIC = ("solver", "max_iter", "tolerance", "boundary_convergence",
@@ -383,6 +416,7 @@ def _fit_blocks_compacted(X, labels, offsets, weights, x0, obj, l1,
     idx: Optional[np.ndarray] = None
     carry = None  # previous chunk's per-lane solver carry (device)
     cur = (X, labels, offsets, weights, x0)
+    rounds, lanes = [], []  # per chunk dispatch (LaneCounts)
     spent = 0
     chunk_index = 0
     while True:
@@ -414,12 +448,11 @@ def _fit_blocks_compacted(X, labels, offsets, weights, x0, obj, l1,
                                 boundary_convergence=not final_chunk,
                                 resume=carry,
                                 return_carry=not final_chunk)
-            if final_chunk:
-                c, it, v, k = out
-                new_carry = None
-            else:
-                c, it, v, k, new_carry = out
-            still, still_local = state.absorb(idx, c, it, v, k,
+            c, it, v, k, ev, chunk_rounds = out[:6]
+            new_carry = None if final_chunk else out[6]
+            rounds.append(chunk_rounds)
+            lanes.append(int(c.shape[0]))
+            still, still_local = state.absorb(idx, c, it, ev, v, k,
                                               CONV_MAX_ITERATIONS)
         REGISTRY.histogram("re_chunk_active_lanes").observe(active_lanes)
         SOLVE_STATS["solve_secs"] += time.perf_counter() - t0
@@ -450,7 +483,17 @@ def _fit_blocks_compacted(X, labels, offsets, weights, x0, obj, l1,
         # only bench/tests ever reset, so keep a rolling window
         SOLVE_STATS["lane_counts"] = (
             SOLVE_STATS["lane_counts"][-63:] + [int(len(still))])
-    return state.results()
+    return _with_counts(state.results(), rounds, lanes)
+
+
+def _with_counts(out, rounds: list, lanes: list,
+                 site: str = "re.fit_blocks"):
+    """(coefs, iters, values, codes, evaluations) of one block plus the
+    rounds and lane counts of the programs that solved it -> the 5-tuple
+    :meth:`RandomEffectOptimizationProblem._fit` returns."""
+    return tuple(out[:4]) + (LaneCounts(
+        out[4], jnp.concatenate(rounds), np.asarray(lanes, np.int64),
+        site),)
 
 
 # ---------------------------------------------------------------------------
@@ -481,7 +524,7 @@ def _sharded_fit_fn(mesh, solver, max_iter, tolerance,
 
     fit = _shard_map(impl, mesh,
                      in_specs=(lane, lane, lane, lane, lane, P(), P()),
-                     out_specs=tuple([lane] * (5 if return_carry else 4)))
+                     out_specs=tuple([lane] * (7 if return_carry else 6)))
     return jax.jit(fit)
 
 
@@ -515,7 +558,7 @@ def _sharded_resume_fit_fn(mesh, solver, max_iter, tolerance,
     fit = _shard_map(
         impl, mesh,
         in_specs=(lane, lane, lane, lane, lane, lane, P(), P(), lane),
-        out_specs=tuple([lane] * (5 if return_carry else 4)))
+        out_specs=tuple([lane] * (7 if return_carry else 6)))
     return jax.jit(fit)
 
 
@@ -589,6 +632,7 @@ def _fit_blocks_compacted_sharded(mesh, shards: int, X, labels, offsets,
     cur_idx = None  # ([K, L] local data ids, [K, L] carry positions)
     prev_width = e_shard  # lanes-per-shard width of the previous dispatch
     prev_global = np.arange(e, dtype=np.int32).reshape(K, e_shard)
+    rounds, lanes = [], []  # per chunk, one entry per shard (LaneCounts)
     spent = 0
     chunk_index = 0
     while True:
@@ -613,17 +657,16 @@ def _fit_blocks_compacted_sharded(mesh, shards: int, X, labels, offsets,
                     cur_idx[1], obj, l1, carry, solver, budget, tolerance,
                     boundary_convergence=not final_chunk,
                     return_carry=not final_chunk)
-            if final_chunk:
-                c, it, v, k = out
-                new_carry = None
-            else:
-                c, it, v, k, new_carry = out
+            c, it, v, k, ev, chunk_rounds = out[:6]  # rounds: [K]
+            new_carry = None if final_chunk else out[6]
+            rounds.append(chunk_rounds)
+            lanes.extend([int(c.shape[0]) // K] * K)
             if idx is None:
-                still, still_local = state.absorb(None, c, it, v, k,
+                still, still_local = state.absorb(None, c, it, ev, v, k,
                                                   CONV_MAX_ITERATIONS)
             else:
                 still, still_local = state.absorb_padded(
-                    idx, mask, c, it, v, k, CONV_MAX_ITERATIONS)
+                    idx, mask, c, it, ev, v, k, CONV_MAX_ITERATIONS)
         REGISTRY.histogram("re_chunk_active_lanes").observe(active_lanes)
         SOLVE_STATS["solve_secs"] += time.perf_counter() - t0
         SOLVE_STATS["chunks"] += 1
@@ -667,7 +710,8 @@ def _fit_blocks_compacted_sharded(mesh, shards: int, X, labels, offsets,
             SOLVE_STATS["shard_lane_counts"][-15:] + [counts.tolist()])
         SOLVE_STATS["lane_counts"] = (
             SOLVE_STATS["lane_counts"][-63:] + [int(len(still))])
-    return state.results()
+    return _with_counts(state.results(), rounds, lanes,
+                        "re.shard_fit_blocks")
 
 
 #: fallback reasons already logged (one warning per distinct cause, not
@@ -819,6 +863,9 @@ class RandomEffectOptimizationProblem:
                         mesh, X, labels, offsets, weights, x0, obj,
                         l1_arr, solver, cfg.max_iterations,
                         float(cfg.tolerance))
+                    out = _with_counts(out[:5], [out[5]],
+                                       [e // shards] * shards,
+                                       "re.shard_fit_blocks")
             # host-level chaos site (never traced): a drill here proves a
             # fault INSIDE a sharded solve rides the existing CD recovery
             # ladder — see utils/faults.FAULT_POINTS["re.shard_dispatch"]
@@ -835,9 +882,10 @@ class RandomEffectOptimizationProblem:
                 self.chunk_tuner.update(solver, cfg.max_iterations,
                                         lane_seq)
             return out
-        return _dispatch_fit(
+        out = _dispatch_fit(
             X, labels, offsets, weights, x0, obj, l1_arr, solver,
             cfg.max_iterations, float(cfg.tolerance), donate)
+        return _with_counts(out[:5], [out[5]], [int(X.shape[0])])
 
     def run(
         self,
@@ -845,9 +893,10 @@ class RandomEffectOptimizationProblem:
         offsets: Array,
         initial: Optional[Array] = None,
         donate: bool = False,
-    ) -> tuple[Array, Array, Array, Array]:
+    ) -> tuple[Array, Array, Array, Array, LaneCounts]:
         """Fit all entities; returns (coefficients [E, D_red], iterations [E],
-        final losses [E], convergence codes [E] — CONVERGENCE_CODE_NAMES).
+        final losses [E], convergence codes [E] — CONVERGENCE_CODE_NAMES —
+        and the evaluation counts, :class:`LaneCounts`).
 
         ``offsets`` is the entity-major offset block (base offsets + other
         coordinates' scores). All three solvers run batched under ``vmap``:
@@ -941,17 +990,25 @@ class RandomEffectOptimizationProblem:
         coefs = jnp.concatenate([
             jnp.pad(c[:b.num_real],
                     ((0, 0), (0, d_red - int(c.shape[1])))).astype(acc)
-            for b, (c, _, _, _) in zip(dataset.buckets, outs)])
+            for b, (c, _, _, _, _) in zip(dataset.buckets, outs)])
         iters = jnp.concatenate([
             it[:b.num_real]
-            for b, (_, it, _, _) in zip(dataset.buckets, outs)])
+            for b, (_, it, _, _, _) in zip(dataset.buckets, outs)])
         values = jnp.concatenate([
             v[:b.num_real].astype(acc)
-            for b, (_, _, v, _) in zip(dataset.buckets, outs)])
+            for b, (_, _, v, _, _) in zip(dataset.buckets, outs)])
         codes = jnp.concatenate([
             k[:b.num_real]
-            for b, (_, _, _, k) in zip(dataset.buckets, outs)])
-        return coefs, iters, values, codes
+            for b, (_, _, _, k, _) in zip(dataset.buckets, outs)])
+        counts = LaneCounts(
+            jnp.concatenate([n.evaluations[:b.num_real]
+                             for b, (*_, n) in zip(dataset.buckets, outs)]),
+            jnp.concatenate([n.evaluation_rounds for *_, n in outs]),
+            np.concatenate([n.bucket_lanes for *_, n in outs]),
+            # one label a coordinate: a bucket too ragged for the mesh
+            # falls back alone, and is booked with its coordinate's rest
+            outs[0][4].site)
+        return coefs, iters, values, codes, counts
 
     def regularization_value_device(self, coefs: Array):
         """Σ over entities of the per-entity penalty as a device scalar
@@ -984,13 +1041,14 @@ def score_active(dataset_X: Array, coefs: Array, row_ids: Array,
     discard slot ``num_samples``. This is the entity→sample resharding half of
     the score exchange (RandomEffectCoordinate.score :137-151 analog).
     """
-    margins = jnp.einsum("end,ed->en", dataset_X, coefs,
-                         preferred_element_type=jnp.float32)
-    margins = jnp.where(weights > 0, margins, 0.0)
-    flat = jax.ops.segment_sum(
-        margins.reshape(-1), row_ids.reshape(-1).astype(jnp.int32),
-        num_segments=num_samples + 1)
-    return flat[:num_samples]
+    with jax.named_scope("re.score"):
+        margins = jnp.einsum("end,ed->en", dataset_X, coefs,
+                             preferred_element_type=jnp.float32)
+        margins = jnp.where(weights > 0, margins, 0.0)
+        flat = jax.ops.segment_sum(
+            margins.reshape(-1), row_ids.reshape(-1).astype(jnp.int32),
+            num_segments=num_samples + 1)
+        return flat[:num_samples]
 
 
 @partial(jax.jit, static_argnames=("num_samples",))
@@ -1001,11 +1059,12 @@ def score_passive(passive_X: Array, passive_entity: Array, coefs: Array,
     Reference: RandomEffectCoordinate.scala:153-199 collects the relevant
     models into a broadcast map; here it is a gather of coefficient rows.
     """
-    w = coefs[passive_entity]  # [P, D_red]
-    margins = jnp.sum(passive_X * w, axis=-1)
-    return jax.ops.segment_sum(
-        margins, passive_row_ids.astype(jnp.int32),
-        num_segments=num_samples + 1)[:num_samples]
+    with jax.named_scope("re.score"):
+        w = coefs[passive_entity]  # [P, D_red]
+        margins = jnp.sum(passive_X * w, axis=-1)
+        return jax.ops.segment_sum(
+            margins, passive_row_ids.astype(jnp.int32),
+            num_segments=num_samples + 1)[:num_samples]
 
 
 def score_random_effect(dataset: RandomEffectDataset, coefs: Array,

@@ -12,17 +12,19 @@ when armed via ``--device-telemetry``:
   dtypes / weak types, pytree structure, static values, function
   identities — the same things jax's dispatch cache keys on),
 - on a signature never seen at that site, runs the compile explicitly
-  via the AOT API (``fn.lower(*args).compile()``) inside an
-  ``xla.compile`` span, records ``compiles{site}`` and
-  ``compile_secs{site}``, and captures the executable's
+  via the AOT API (``fn.lower(*args)``, then ``.compile()``) inside an
+  ``xla.compile`` span with an ``xla.lower`` child, records
+  ``compiles{site}``, ``compile_secs{site}`` (the whole interval) and
+  ``lower_secs{site}`` (Python tracing and lowering, which a persistent
+  cache never saves), and captures the executable's
   ``cost_analysis()`` flops / bytes-accessed and the donated bytes it
   aliases (``alias_bytes``) into the span labels (and
   the ``xla_flops{site}`` / ``xla_bytes_accessed{site}`` gauges, which
   ``tools/trace_report.py --device`` joins with span self-time),
 - diffs every *retrace* (a new signature at a site that already
   compiled one) against the site's previous signature and emits a
-  zero-duration ``xla.retrace`` span naming the argument that changed
-  and how (shape / dtype / static value / structure) — the record
+  zero-duration ``xla.retrace`` mark inside that compile's span, naming
+  the argument that changed and how (shape / dtype / static value / structure) — the record
   rides the normal span spill into ``spans.jsonl`` and the live
   telemetry stream,
 - answers subsequent calls with the cached compiled executable
@@ -75,11 +77,15 @@ def arm(registry: Optional[MetricsRegistry] = None) -> None:
     global _ARMED, _REGISTRY
     _REGISTRY = registry or REGISTRY
     _ARMED = True
+    # the device plane's one switch: armed spans also land in any
+    # jax.profiler capture, on the device trace's clock
+    trace.mirror_to_profiler(True)
 
 
 def disarm() -> None:
     global _ARMED
     _ARMED = False
+    trace.mirror_to_profiler(False)
 
 
 def is_armed() -> bool:
@@ -217,49 +223,60 @@ def _compile_here(site: "_Site", fn, args, static_argnums, signature):
     """Signature miss: run the compile EXPLICITLY (AOT), attribute it,
     cache the executable. Returns the call's result."""
     registry = _REGISTRY
-    is_retrace = site.last_sig is not None
-    # photonlint: allow-W201(host-side compile timing: call() bypasses this whole path when a jax trace is active)
-    t0 = time.perf_counter()
-    try:
-        compiled = fn.lower(*args).compile()
-    except Exception:
-        # not AOT-lowerable (or convention mismatch): the plain call
-        # still compiles through jit's own cache — time THAT as the
-        # compile cost (first call = trace+compile+run) and pin this
-        # signature to the plain path.
-        result = fn(*args)
+    result = None
+    # the span covers the AOT interval where it happened, so a trace shows
+    # WHICH step recompiled; the labels only the finished compile knows
+    # are added before it closes
+    with trace.span("xla.compile", site=site.name) as compile_span:
+        if site.last_sig is not None:
+            arg, field, old, new = _retrace_cause(
+                site.last_sig, signature, site.last_arg_names)
+            registry.counter("retrace_causes").inc(site=site.name,
+                                                   field=field)
+            with trace.span("xla.retrace", site=site.name, arg=str(arg),
+                            field=field, old=old, new=new):
+                pass
+        lower_secs = None
+        # photonlint: allow-W201(host-side compile timing: call() bypasses this whole path when a jax trace is active)
+        t0 = time.perf_counter()
+        try:
+            with trace.span("xla.lower", site=site.name):
+                lowered = fn.lower(*args)  # Python tracing + lowering
+            # photonlint: allow-W201(host-side compile timing: call() bypasses this whole path when a jax trace is active)
+            lower_secs = time.perf_counter() - t0
+            compiled = lowered.compile()
+        except Exception:
+            # not AOT-lowerable (or convention mismatch): the plain call
+            # still compiles through jit's own cache — time THAT as the
+            # compile cost (first call = trace+compile+run) and pin this
+            # signature to the plain path.
+            result = fn(*args)
+            compiled = None
+            site.cache[signature] = _FALLBACK
         # photonlint: allow-W201(host-side compile timing: call() bypasses this whole path when a jax trace is active)
         secs = time.perf_counter() - t0
-        site.cache[signature] = _FALLBACK
-        flops = nbytes = alias = None
-    else:
-        # photonlint: allow-W201(host-side compile timing: call() bypasses this whole path when a jax trace is active)
-        secs = time.perf_counter() - t0
-        site.cache[signature] = compiled
-        flops, nbytes = _cost_analysis(compiled)
-        alias = _alias_bytes(compiled)
+        labels = {"secs": round(secs, 6)}
+        if compiled is not None:
+            site.cache[signature] = compiled
+            flops, nbytes = _cost_analysis(compiled)
+            alias = _alias_bytes(compiled)
+            if flops is not None:
+                labels["flops"] = flops
+                registry.gauge("xla_flops").set(flops, site=site.name)
+            if nbytes is not None:
+                labels["bytes_accessed"] = nbytes
+                registry.gauge("xla_bytes_accessed").set(nbytes,
+                                                         site=site.name)
+            if alias is not None:
+                labels["alias_bytes"] = alias
+        registry.counter("compiles").inc(site=site.name)
+        registry.counter("compile_secs").inc(secs, site=site.name)
+        if lower_secs is not None:
+            registry.counter("lower_secs").inc(lower_secs, site=site.name)
+        compile_span.label(**labels)
+    if compiled is not None:
         result = _call_compiled(site, fn, compiled, args, static_argnums,
                                 signature)
-    labels = {"site": site.name, "secs": round(secs, 6)}
-    if flops is not None:
-        labels["flops"] = flops
-        registry.gauge("xla_flops").set(flops, site=site.name)
-    if nbytes is not None:
-        labels["bytes_accessed"] = nbytes
-        registry.gauge("xla_bytes_accessed").set(nbytes, site=site.name)
-    if alias is not None:
-        labels["alias_bytes"] = alias
-    registry.counter("compiles").inc(site=site.name)
-    registry.counter("compile_secs").inc(secs, site=site.name)
-    with trace.span("xla.compile", **labels):
-        pass
-    if is_retrace:
-        arg, field, old, new = _retrace_cause(
-            site.last_sig, signature, site.last_arg_names)
-        registry.counter("retrace_causes").inc(site=site.name, field=field)
-        with trace.span("xla.retrace", site=site.name, arg=str(arg),
-                        field=field, old=old, new=new):
-            pass
     site.last_sig = signature
     return result
 

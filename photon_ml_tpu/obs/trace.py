@@ -27,6 +27,15 @@ Export formats:
 Per-thread nesting depth comes from a ``threading.local`` span stack; the
 stack snapshots also feed the heartbeat's stall report (which spans are
 currently open when nothing has closed for too long).
+
+**Mirroring into the profiler's trace** (:func:`mirror_to_profiler`,
+switched by ``obs.compile.arm()`` / ``--device-telemetry``): a span also
+enters a ``jax.profiler.TraceAnnotation(name, **labels)``, so it lands on
+the host plane of any ``jax.profiler`` capture, on the profiler's own
+clock beside the device's operations, its labels as the event's stats —
+whether or not a tracer is installed. Outside a capture the annotation is
+a microsecond of native code. Hand-timed :func:`record_span` spans are not
+mirrored (an annotation cannot be back-dated).
 """
 
 from __future__ import annotations
@@ -49,32 +58,60 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def label(self, **labels) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
+#: ``jax.profiler.TraceAnnotation`` while mirroring is on, else None (this
+#: module imports no jax until something asks for the mirror).
+_annotation = None
+
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_labels", "_start_ns", "_depth")
+    """A live span: recorded by ``tracer`` (None: mirror only) and, while
+    mirroring is on, entered as a profiler annotation too."""
 
-    def __init__(self, tracer: "Tracer", name: str, labels: Optional[dict]):
+    __slots__ = ("_tracer", "_name", "_labels", "_start_ns", "_depth",
+                 "_mirror")
+
+    def __init__(self, tracer: Optional["Tracer"], name: str,
+                 labels: Optional[dict]):
         self._tracer = tracer
         self._name = name
         self._labels = labels or None
+        self._mirror = None
+
+    def label(self, **labels) -> None:
+        """Add labels known only once the span's work is done (a compile's
+        seconds and cost). They reach the tracer's record; the profiler's
+        event keeps the labels the span was opened with."""
+        self._labels = dict(self._labels or {}, **labels)
 
     def __enter__(self):
-        stack = self._tracer._stack()
-        self._depth = len(stack)
-        self._start_ns = time.perf_counter_ns()
-        # (name, start_ns): the open-span report needs per-span ages to
-        # make a stalled run diagnosable from the log alone
-        stack.append((self._name, self._start_ns))
+        if self._tracer is not None:
+            stack = self._tracer._stack()
+            self._depth = len(stack)
+            self._start_ns = time.perf_counter_ns()
+            # (name, start_ns): the open-span report needs per-span ages
+            # to make a stalled run diagnosable from the log alone
+            stack.append((self._name, self._start_ns))
+        ann = _annotation
+        if ann is not None:
+            # the native event starts when the annotation is built
+            self._mirror = ann(self._name, **(self._labels or {}))
+            self._mirror.__enter__()
         return self
 
     def __exit__(self, *exc):
-        end_ns = time.perf_counter_ns()
-        self._tracer._stack().pop()
-        self._tracer._record(self._name, self._start_ns, end_ns,
-                             self._depth, self._labels)
+        if self._mirror is not None:
+            self._mirror.__exit__(*exc)
+        if self._tracer is not None:
+            end_ns = time.perf_counter_ns()
+            self._tracer._stack().pop()
+            self._tracer._record(self._name, self._start_ns, end_ns,
+                                 self._depth, self._labels)
         return False
 
 
@@ -274,11 +311,24 @@ def get_tracer() -> Optional[Tracer]:
     return _tracer
 
 
+def mirror_to_profiler(on: bool) -> None:
+    """Switch the mirroring of spans into ``jax.profiler`` captures (see
+    the module docstring). ``obs.compile.arm()`` / ``disarm()`` call this:
+    there is one switch for the device plane, not two."""
+    global _annotation
+    if on:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    else:
+        _annotation = None
+
+
 def span(name: str, **labels):
     """A span on the global tracer — or the shared no-op when tracing is
-    off, so call sites never branch."""
+    off and nothing mirrors, so call sites never branch."""
     t = _tracer
-    if t is None:
+    if t is None and _annotation is None:
         return _NULL_SPAN
     return _Span(t, name, labels)
 

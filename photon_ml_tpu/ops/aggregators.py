@@ -78,21 +78,25 @@ def value_and_gradient(
       prefactorSum = sum_i w_i l'(z_i)
       grad_j       = factors_j (vectorSum_j - shifts_j prefactorSum)
     """
-    w_eff, margin_shift = norm.effective_coefficients(coef)
-    sums = _pallas_sums(loss, w_eff, margin_shift, batch, axis_name)
-    if sums is not None:
-        value, vector_sum, prefactor_sum = sums
-    else:
-        z = batch.margins(w_eff, margin_shift)
-        l, d1 = loss.loss_and_d1(z, batch.labels)
-        value = jnp.sum(batch.weights * l)
-        r = batch.weights * d1
-        vector_sum = batch.weighted_feature_sum(r)
-        prefactor_sum = jnp.sum(r)
-    value = _maybe_psum(value, axis_name, collective_quant)
-    vector_sum = _maybe_psum(vector_sum, axis_name, collective_quant)
-    prefactor_sum = _maybe_psum(prefactor_sum, axis_name, collective_quant)
-    return value, norm.reconstruct_gradient(vector_sum, prefactor_sum)
+    # the scope names one pass over the rows in a device trace, whichever
+    # form (fused kernel, two-pass XLA) makes it
+    with jax.named_scope("objective.value_and_grad"):
+        w_eff, margin_shift = norm.effective_coefficients(coef)
+        sums = _pallas_sums(loss, w_eff, margin_shift, batch, axis_name)
+        if sums is not None:
+            value, vector_sum, prefactor_sum = sums
+        else:
+            z = batch.margins(w_eff, margin_shift)
+            l, d1 = loss.loss_and_d1(z, batch.labels)
+            value = jnp.sum(batch.weights * l)
+            r = batch.weights * d1
+            vector_sum = batch.weighted_feature_sum(r)
+            prefactor_sum = jnp.sum(r)
+        value = _maybe_psum(value, axis_name, collective_quant)
+        vector_sum = _maybe_psum(vector_sum, axis_name, collective_quant)
+        prefactor_sum = _maybe_psum(prefactor_sum, axis_name,
+                                    collective_quant)
+        return value, norm.reconstruct_gradient(vector_sum, prefactor_sum)
 
 
 def hessian_vector(
@@ -111,16 +115,17 @@ def hessian_vector(
       (Hv)_j = factors_j (sum_i w_i l''(z_i) zv_i x_ij
                           - shifts_j sum_i w_i l''(z_i) zv_i)
     """
-    w_eff, margin_shift = norm.effective_coefficients(coef)
-    v_eff, v_shift = norm.effective_coefficients(vector)
-    z = batch.margins(w_eff, margin_shift)
-    # zv: margin of v without data offsets (offsets are constant in w).
-    zv = batch.margins(v_eff, v_shift) - batch.offsets
-    r = batch.weights * loss.d2(z, batch.labels) * zv
-    vector_sum = _maybe_psum(batch.weighted_feature_sum(r), axis_name,
-                             collective_quant)
-    prefactor_sum = _maybe_psum(jnp.sum(r), axis_name, collective_quant)
-    return norm.reconstruct_gradient(vector_sum, prefactor_sum)
+    with jax.named_scope("objective.hvp"):
+        w_eff, margin_shift = norm.effective_coefficients(coef)
+        v_eff, v_shift = norm.effective_coefficients(vector)
+        z = batch.margins(w_eff, margin_shift)
+        # zv: margin of v without data offsets (offsets are constant in w).
+        zv = batch.margins(v_eff, v_shift) - batch.offsets
+        r = batch.weights * loss.d2(z, batch.labels) * zv
+        vector_sum = _maybe_psum(batch.weighted_feature_sum(r), axis_name,
+                                 collective_quant)
+        prefactor_sum = _maybe_psum(jnp.sum(r), axis_name, collective_quant)
+        return norm.reconstruct_gradient(vector_sum, prefactor_sum)
 
 
 def hessian_diagonal(

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from photon_ml_tpu.obs.metrics import REGISTRY
+
 Array = jnp.ndarray
 
 
@@ -113,6 +115,17 @@ class RunHistory(NamedTuple):
     # ModelTracker.models, Optimizer.scala state tracking) — None otherwise
     # so the untracked compile carries no [k, d] buffer.
     iterates: Optional[Array] = None
+    # int32 [max_iter + 1]: calls of ``value_and_grad_fn`` (each one pass
+    # over the rows). Slot 0 holds those made before the first iteration
+    # (1 from a fresh start, 0 on ``resume``), slot k those spent on
+    # iteration k: the line search's trials plus the box projection's
+    # re-evaluation. TRON's rejected trial steps are booked to the slot of
+    # the iteration they were tried for, so the total is the sum over ALL
+    # slots, not only the first ``num_iterations + 1``.
+    evaluations: Optional[Array] = None
+    # TRON only: Hessian-vector products per iteration (one more pass over
+    # the rows each), booked like ``evaluations``.
+    hvps: Optional[Array] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -127,6 +140,10 @@ class OptimizationResult:
     values: np.ndarray  # trajectory f_0..f_k
     grad_norms: np.ndarray  # trajectory ||g_0||..||g_k||
     iterates: Optional[np.ndarray] = None  # [k+1, d] when tracked
+    # totals of RunHistory.evaluations / .hvps (None where the history
+    # carries none, e.g. one assembled from per-shard solves)
+    evaluations: Optional[int] = None
+    hvps: Optional[int] = None
 
     @staticmethod
     def from_history(
@@ -135,8 +152,26 @@ class OptimizationResult:
         max_iter: int,
         tolerance: float,
         made_progress_last_iter: bool = True,
+        site: Optional[str] = None,
     ) -> "OptimizationResult":
+        """``site`` (an ``obs/compile.py`` site name, e.g.
+        ``optimizer.lbfgs``) books the solve on the ``solver_*`` counters:
+        pass it where a solve's history reaches the host ONCE. A history
+        still on the device comes over in one explicit fetch of the whole
+        pytree (it used to cost one blocking read per field)."""
+        if not isinstance(history.values, np.ndarray):
+            import jax
+
+            from photon_ml_tpu.utils.sync_telemetry import record_host_fetch
+
+            history = jax.device_get(history)
+            record_host_fetch(site="optimizer.history")
         k = int(history.num_iterations)
+        evaluations = (None if history.evaluations is None
+                       else int(np.sum(history.evaluations)))
+        hvps = None if history.hvps is None else int(np.sum(history.hvps))
+        if site is not None:
+            record_solve(site, k, evaluations, hvps)
         values = np.asarray(history.values)[: k + 1]
         grad_norms = np.asarray(history.grad_norms)[: k + 1]
         reason = _convergence_reason(
@@ -152,7 +187,28 @@ class OptimizationResult:
             grad_norms=grad_norms,
             iterates=(None if history.iterates is None
                       else np.asarray(history.iterates)[: k + 1]),
+            evaluations=evaluations,
+            hvps=hvps,
         )
+
+
+def record_solve(site: str, iterations: int, evaluations: Optional[int],
+                 hvps: Optional[int] = None,
+                 lane_evaluations: Optional[int] = None) -> None:
+    """Book solves whose counts just reached the host on the
+    ``solver_*{site}`` counters. A solve with no evaluation count books
+    nothing: a ratio of the counters must never mix counted and uncounted
+    solves. ``lane_evaluations`` is what a batched loop executed (lanes x
+    rounds, pad lanes included); a single solve executes what it needs."""
+    if evaluations is None:
+        return
+    REGISTRY.counter("solver_iterations").inc(iterations, site=site)
+    REGISTRY.counter("solver_evaluations").inc(evaluations, site=site)
+    REGISTRY.counter("solver_lane_evaluations").inc(
+        evaluations if lane_evaluations is None else lane_evaluations,
+        site=site)
+    if hvps is not None:
+        REGISTRY.counter("solver_hvps").inc(hvps, site=site)
 
 
 class DeferredOptimizationResult:
@@ -169,12 +225,14 @@ class DeferredOptimizationResult:
     """
 
     def __init__(self, coefficients: Array, history: RunHistory,
-                 progressed, max_iter: int, tolerance: float):
+                 progressed, max_iter: int, tolerance: float,
+                 site: Optional[str] = None):
         self.coefficients = coefficients
         self._history = history
         self._progressed = progressed
         self._max_iter = max_iter
         self._tolerance = tolerance
+        self._site = site
         self._result: Optional[OptimizationResult] = None
 
     def _force(self) -> OptimizationResult:
@@ -188,7 +246,8 @@ class DeferredOptimizationResult:
             record_host_fetch(site="optimizer.history")
             self._result = OptimizationResult.from_history(
                 self.coefficients, history,
-                self._max_iter, self._tolerance, bool(progressed))
+                self._max_iter, self._tolerance, bool(progressed),
+                site=self._site)
             self._history = self._progressed = None
         return self._result
 
@@ -220,6 +279,14 @@ class DeferredOptimizationResult:
     def iterates(self) -> Optional[np.ndarray]:
         return self._force().iterates
 
+    @property
+    def evaluations(self) -> Optional[int]:
+        return self._force().evaluations
+
+    @property
+    def hvps(self) -> Optional[int]:
+        return self._force().hvps
+
 
 @dataclasses.dataclass
 class LaneCompactionState:
@@ -247,6 +314,7 @@ class LaneCompactionState:
 
     coefs: Array  # [E, D] device
     iterations: Array  # [E] int32 device (accumulated across chunks)
+    evaluations: Array  # [E] int32 device (accumulated like iterations)
     values: Array  # [E] device (last chunk's final value per lane)
     codes: Array  # [E] int8 device (last chunk's convergence code)
     active: np.ndarray  # host int32 global lane ids still unconverged
@@ -257,13 +325,14 @@ class LaneCompactionState:
         return LaneCompactionState(
             coefs=x0,
             iterations=jnp.zeros(e, jnp.int32),
+            evaluations=jnp.zeros(e, jnp.int32),
             values=jnp.zeros(e, value_dtype),
             codes=jnp.zeros(e, jnp.int8),
             active=np.arange(e, dtype=np.int32),
         )
 
-    def absorb(self, idx, c: Array, it: Array, v: Array, k: Array,
-               max_iterations_code: int) -> tuple[np.ndarray, np.ndarray]:
+    def absorb(self, idx, c: Array, it: Array, ev: Array, v: Array,
+               k: Array, max_iterations_code: int) -> tuple[np.ndarray, np.ndarray]:
         """Fold one chunk's output (lane-compacted when ``idx`` is not
         None) into the global buffers; returns ``(global_ids,
         local_positions)`` of lanes the chunk did NOT converge (they hit
@@ -278,7 +347,7 @@ class LaneCompactionState:
 
         if idx is None:  # first chunk: all lanes ran, in global order
             self.coefs, self.values, self.codes = c, v, k
-            self.iterations = it
+            self.iterations, self.evaluations = it, ev
             unconverged = np.asarray(
                 jax.device_get(k == max_iterations_code))
             record_host_fetch(site="re.compact_mask")
@@ -288,6 +357,7 @@ class LaneCompactionState:
         idx_dev = jax.device_put(idx)
         self.coefs = self.coefs.at[idx_dev].set(c[:n_real])
         self.iterations = self.iterations.at[idx_dev].add(it[:n_real])
+        self.evaluations = self.evaluations.at[idx_dev].add(ev[:n_real])
         self.values = self.values.at[idx_dev].set(v[:n_real])
         self.codes = self.codes.at[idx_dev].set(k[:n_real])
         unconverged = np.asarray(
@@ -297,7 +367,7 @@ class LaneCompactionState:
         return idx[unconverged], local
 
     def absorb_padded(self, idx: np.ndarray, mask: np.ndarray, c: Array,
-                      it: Array, v: Array, k: Array,
+                      it: Array, ev: Array, v: Array, k: Array,
                       max_iterations_code: int
                       ) -> tuple[np.ndarray, np.ndarray]:
         """Mesh-sharded-chunk variant of :meth:`absorb`: the dispatch lanes
@@ -306,7 +376,7 @@ class LaneCompactionState:
         carry and anchors mean an identical solve, so the duplicate
         ``.set`` writes are value-equal and benign. ``idx`` maps every
         flat slot to its global lane id and ``mask`` flags the real
-        slots; iteration counts from pad slots are zeroed before the
+        slots; iteration and evaluation counts from pad slots are zeroed before the
         scatter-add so duplicates never double-count. Returns
         ``(global_ids, flat_positions)`` of the real lanes that hit the
         budget, exactly like :meth:`absorb`. Still exactly ONE blocking
@@ -320,6 +390,8 @@ class LaneCompactionState:
         self.coefs = self.coefs.at[idx_dev].set(c)
         self.iterations = self.iterations.at[idx_dev].add(
             jnp.where(mask_dev, it, 0))
+        self.evaluations = self.evaluations.at[idx_dev].add(
+            jnp.where(mask_dev, ev, 0))
         self.values = self.values.at[idx_dev].set(v)
         self.codes = self.codes.at[idx_dev].set(k)
         unconverged = np.asarray(
@@ -329,8 +401,9 @@ class LaneCompactionState:
         local = np.nonzero(real)[0].astype(np.int32)
         return idx[real], local
 
-    def results(self) -> tuple[Array, Array, Array, Array]:
-        return self.coefs, self.iterations, self.values, self.codes
+    def results(self) -> tuple[Array, Array, Array, Array, Array]:
+        return (self.coefs, self.iterations, self.values, self.codes,
+                self.evaluations)
 
 
 def padded_lane_count(n: int, floor: int = 8) -> int:

@@ -54,6 +54,7 @@ class _LBFGSCarry(NamedTuple):
     made_progress: Array  # bool: last line search succeeded
     values: Array
     grad_norms: Array
+    evaluations: Array  # [max_iter+1] int32 (RunHistory.evaluations)
     iterates: Optional[Array]  # [max_iter+1, d] when tracking, else None
 
 
@@ -208,6 +209,9 @@ def _minimize_lbfgs_impl(
     grad_norms = jnp.full(max_iter + 1, jnp.nan, dtype)
     values = values.at[0].set(f_start)
     grad_norms = grad_norms.at[0].set(vnorm(g_start))
+    # the start's evaluation above; a resumed chunk made none
+    evaluations = jnp.zeros(max_iter + 1, jnp.int32).at[0].set(
+        1 if resume is None else 0)
     iterates0 = (jnp.zeros((max_iter + 1, d), dtype).at[0].set(x_start)
                  if track_iterates else None)
 
@@ -216,7 +220,8 @@ def _minimize_lbfgs_impl(
         prev_f=prev_f0,
         S=S0, Y=Y0, rho=rho0, valid=valid0,
         head=head0, made_progress=jnp.bool_(True),
-        values=values, grad_norms=grad_norms, iterates=iterates0,
+        values=values, grad_norms=grad_norms, evaluations=evaluations,
+        iterates=iterates0,
     )
 
     def cond(c: _LBFGSCarry) -> Array:
@@ -228,13 +233,16 @@ def _minimize_lbfgs_impl(
         )
 
     def body(c: _LBFGSCarry) -> _LBFGSCarry:
-        direction = two_loop_direction(c.g, c.S, c.Y, c.rho, c.valid, c.head,
-                                       update_axis_name, collective_quant)
-        dphi0 = vdot(c.g, direction)
-        # Safeguard: fall back to steepest descent if not a descent direction.
-        bad = dphi0 >= 0.0
-        direction = jnp.where(bad, -c.g, direction)
-        dphi0 = jnp.where(bad, -vdot(c.g, c.g), dphi0)
+        with jax.named_scope("lbfgs.direction"):
+            direction = two_loop_direction(
+                c.g, c.S, c.Y, c.rho, c.valid, c.head,
+                update_axis_name, collective_quant)
+            dphi0 = vdot(c.g, direction)
+            # Safeguard: fall back to steepest descent if not a descent
+            # direction.
+            bad = dphi0 >= 0.0
+            direction = jnp.where(bad, -c.g, direction)
+            dphi0 = jnp.where(bad, -vdot(c.g, c.g), dphi0)
 
         def phi(a):
             x_a = c.x + a * direction
@@ -252,10 +260,12 @@ def _minimize_lbfgs_impl(
             )
         else:
             init_alpha = jnp.asarray(1.0, dtype)
-        ls = strong_wolfe(phi, c.f, dphi0, c.g, init_alpha=init_alpha)
+        with jax.named_scope("lbfgs.linesearch"):
+            ls = strong_wolfe(phi, c.f, dphi0, c.g, init_alpha=init_alpha)
 
         x_new = c.x + ls.alpha * direction
         f_new, g_new = ls.value, ls.grad
+        evals = ls.num_evals
         if box is not None:
             x_proj = project_box(x_new, box)
             changed = jnp.any(x_proj != x_new)
@@ -264,30 +274,37 @@ def _minimize_lbfgs_impl(
                 lambda: (f_new, g_new)
             )
             x_new = x_proj
+            evals = evals + changed.astype(jnp.int32)
 
         # A step into a non-finite region is never accepted: the solver
         # stops at the last good iterate (ObjectiveNotImproving).
         ok = finite_step(ls.ok, f_new, g_new, update_axis_name)
 
-        s = x_new - c.x
-        y = g_new - c.g
-        sy = vdot(s, y)
-        store = ok & (sy > 1e-10)
+        with jax.named_scope("lbfgs.update"):
+            s = x_new - c.x
+            y = g_new - c.g
+            sy = vdot(s, y)
+            store = ok & (sy > 1e-10)
 
-        S = jnp.where(store, c.S.at[c.head].set(s), c.S)
-        Y = jnp.where(store, c.Y.at[c.head].set(y), c.Y)
-        rho = jnp.where(store, c.rho.at[c.head].set(1.0 / jnp.maximum(sy, 1e-300)),
-                        c.rho)
-        valid = jnp.where(store, c.valid.at[c.head].set(True), c.valid)
-        head = jnp.where(store, (c.head + 1) % m, c.head)
+            S = jnp.where(store, c.S.at[c.head].set(s), c.S)
+            Y = jnp.where(store, c.Y.at[c.head].set(y), c.Y)
+            rho = jnp.where(
+                store, c.rho.at[c.head].set(1.0 / jnp.maximum(sy, 1e-300)),
+                c.rho)
+            valid = jnp.where(store, c.valid.at[c.head].set(True), c.valid)
+            head = jnp.where(store, (c.head + 1) % m, c.head)
 
-        it_new = c.it + 1
-        values = c.values.at[it_new].set(jnp.where(ok, f_new, c.f))
-        grad_norms = c.grad_norms.at[it_new].set(
-            vnorm(jnp.where(ok, g_new, c.g)))
-        x_acc = jnp.where(ok, x_new, c.x)
-        iterates = (c.iterates.at[it_new].set(x_acc)
-                    if track_iterates else None)
+            it_new = c.it + 1
+            values = c.values.at[it_new].set(jnp.where(ok, f_new, c.f))
+            grad_norms = c.grad_norms.at[it_new].set(
+                vnorm(jnp.where(ok, g_new, c.g)))
+            # a select, not ``.at[].set``: under ``vmap`` that is a scatter
+            # over every lane, and this array is here to be cheap
+            evaluations = jnp.where(
+                jnp.arange(max_iter + 1) == it_new, evals, c.evaluations)
+            x_acc = jnp.where(ok, x_new, c.x)
+            iterates = (c.iterates.at[it_new].set(x_acc)
+                        if track_iterates else None)
 
         return _LBFGSCarry(
             it=it_new,
@@ -297,12 +314,14 @@ def _minimize_lbfgs_impl(
             prev_f=c.f,
             S=S, Y=Y, rho=rho, valid=valid, head=head,
             made_progress=ok,
-            values=values, grad_norms=grad_norms, iterates=iterates,
+            values=values, grad_norms=grad_norms, evaluations=evaluations,
+            iterates=iterates,
         )
 
     final = lax.while_loop(cond, body, init)
     history = RunHistory(values=final.values, grad_norms=final.grad_norms,
-                         num_iterations=final.it, iterates=final.iterates)
+                         num_iterations=final.it, iterates=final.iterates,
+                         evaluations=final.evaluations)
     if return_carry:
         carry = LBFGSResume(
             x=final.x, f=final.f, g=final.g, prev_f=final.prev_f,

@@ -73,6 +73,7 @@ class _OWLQNCarry(NamedTuple):
     made_progress: Array
     values: Array
     grad_norms: Array  # pseudo-gradient norms
+    evaluations: Array  # [max_iter+1] int32 (RunHistory.evaluations)
     iterates: Optional[Array]  # [max_iter+1, d] when tracking, else None
 
 
@@ -142,6 +143,9 @@ def _minimize_owlqn_impl(
     values = jnp.full(max_iter + 1, jnp.nan, dtype).at[0].set(f_start)
     grad_norms = jnp.full(max_iter + 1, jnp.nan, dtype).at[0].set(
         vnorm(pg_start))
+    # the start's evaluation above; a resumed chunk made none
+    evaluations = jnp.zeros(max_iter + 1, jnp.int32).at[0].set(
+        1 if resume is None else 0)
     iterates0 = (jnp.zeros((max_iter + 1, d), dtype).at[0].set(x_start)
                  if track_iterates else None)
 
@@ -150,7 +154,8 @@ def _minimize_owlqn_impl(
         prev_f=prev_f0,
         S=S0, Y=Y0, rho=rho0, valid=valid0,
         head=head0, made_progress=jnp.bool_(True),
-        values=values, grad_norms=grad_norms, iterates=iterates0,
+        values=values, grad_norms=grad_norms, evaluations=evaluations,
+        iterates=iterates0,
     )
 
     def cond(c: _OWLQNCarry) -> Array:
@@ -164,11 +169,13 @@ def _minimize_owlqn_impl(
 
     def body(c: _OWLQNCarry) -> _OWLQNCarry:
         pg = pseudo_gradient(c.x, c.g, l1)
-        direction = two_loop_direction(pg, c.S, c.Y, c.rho, c.valid, c.head,
-                                       update_axis_name, collective_quant)
-        # Project direction onto the orthant of -pg (keep only components
-        # that actually descend along the pseudo-gradient).
-        direction = jnp.where(direction * pg < 0.0, direction, 0.0)
+        with jax.named_scope("owlqn.direction"):
+            direction = two_loop_direction(
+                pg, c.S, c.Y, c.rho, c.valid, c.head,
+                update_axis_name, collective_quant)
+            # Project direction onto the orthant of -pg (keep only
+            # components that actually descend along the pseudo-gradient).
+            direction = jnp.where(direction * pg < 0.0, direction, 0.0)
 
         # Orthant for this step: sign(x_j), or sign(-pg_j) where x_j == 0.
         xi = jnp.where(c.x != 0.0, jnp.sign(c.x), jnp.sign(-pg))
@@ -206,33 +213,44 @@ def _minimize_owlqn_impl(
             a_next = jnp.where(accepted, a, a * 0.5)
             return a_next, f_a, g_a, x_a, k + 1, accepted
 
-        a, f_new, g_new, x_new, _, accepted = lax.while_loop(
-            ls_cond, ls_body,
-            (init_alpha, c.f, c.g, c.x, jnp.int32(0), jnp.bool_(False)),
-        )
+        # one evaluation per trial: the trial count IS the iteration's
+        # evaluation count
+        with jax.named_scope("owlqn.linesearch"):
+            a, f_new, g_new, x_new, evals, accepted = lax.while_loop(
+                ls_cond, ls_body,
+                (init_alpha, c.f, c.g, c.x, jnp.int32(0),
+                 jnp.bool_(False)),
+            )
         # Non-finite trial values never enter the carry (divergence guard).
         accepted = finite_step(accepted, f_new, g_new, update_axis_name)
 
-        s = x_new - c.x
-        y = g_new - c.g  # smooth gradient difference
-        sy = vdot(s, y)
-        store = accepted & (sy > 1e-10)
+        with jax.named_scope("owlqn.update"):
+            s = x_new - c.x
+            y = g_new - c.g  # smooth gradient difference
+            sy = vdot(s, y)
+            store = accepted & (sy > 1e-10)
 
-        S = jnp.where(store, c.S.at[c.head].set(s), c.S)
-        Y = jnp.where(store, c.Y.at[c.head].set(y), c.Y)
-        rho = jnp.where(store, c.rho.at[c.head].set(1.0 / jnp.maximum(sy, 1e-300)),
-                        c.rho)
-        valid = jnp.where(store, c.valid.at[c.head].set(True), c.valid)
-        head = jnp.where(store, (c.head + 1) % m, c.head)
+            S = jnp.where(store, c.S.at[c.head].set(s), c.S)
+            Y = jnp.where(store, c.Y.at[c.head].set(y), c.Y)
+            rho = jnp.where(
+                store, c.rho.at[c.head].set(1.0 / jnp.maximum(sy, 1e-300)),
+                c.rho)
+            valid = jnp.where(store, c.valid.at[c.head].set(True), c.valid)
+            head = jnp.where(store, (c.head + 1) % m, c.head)
 
-        it_new = c.it + 1
-        pg_new = pseudo_gradient(x_new, g_new, l1)
-        values = c.values.at[it_new].set(jnp.where(accepted, f_new, c.f))
-        grad_norms = c.grad_norms.at[it_new].set(vnorm(
-            jnp.where(accepted, pg_new, pg)))
-        x_acc = jnp.where(accepted, x_new, c.x)
-        iterates = (c.iterates.at[it_new].set(x_acc)
-                    if track_iterates else None)
+            it_new = c.it + 1
+            pg_new = pseudo_gradient(x_new, g_new, l1)
+            values = c.values.at[it_new].set(
+                jnp.where(accepted, f_new, c.f))
+            grad_norms = c.grad_norms.at[it_new].set(vnorm(
+                jnp.where(accepted, pg_new, pg)))
+            # a select, not ``.at[].set``: under ``vmap`` that is a scatter
+            # over every lane, and this array is here to be cheap
+            evaluations = jnp.where(
+                jnp.arange(max_iter + 1) == it_new, evals, c.evaluations)
+            x_acc = jnp.where(accepted, x_new, c.x)
+            iterates = (c.iterates.at[it_new].set(x_acc)
+                        if track_iterates else None)
 
         return _OWLQNCarry(
             it=it_new,
@@ -242,12 +260,14 @@ def _minimize_owlqn_impl(
             prev_f=c.f,
             S=S, Y=Y, rho=rho, valid=valid, head=head,
             made_progress=accepted,
-            values=values, grad_norms=grad_norms, iterates=iterates,
+            values=values, grad_norms=grad_norms, evaluations=evaluations,
+            iterates=iterates,
         )
 
     final = lax.while_loop(cond, body, init)
     history = RunHistory(values=final.values, grad_norms=final.grad_norms,
-                         num_iterations=final.it, iterates=final.iterates)
+                         num_iterations=final.it, iterates=final.iterates,
+                         evaluations=final.evaluations)
     if return_carry:
         carry = LBFGSResume(
             x=final.x, f=final.f, g=final.g, prev_f=final.prev_f,

@@ -120,6 +120,18 @@ class GLMOptimizationProblem:
 
     # -- solve ---------------------------------------------------------------
 
+    def solver_site(self) -> str:
+        """The solver this configuration dispatches to, by the name its
+        ``obs/compile.py`` site and its ``solver_*`` counters carry."""
+        cfg = self.config
+        if cfg.optimizer_type == OptimizerType.LBFGS:
+            l1 = cfg.regularization_context.l1_weight(
+                cfg.regularization_weight)
+            return "optimizer.owlqn" if l1 > 0.0 else "optimizer.lbfgs"
+        if cfg.optimizer_type == OptimizerType.TRON:
+            return "optimizer.tron"
+        raise ValueError(f"unknown optimizer {cfg.optimizer_type}")
+
     def solve(self, obj: GLMObjective, batch: Batch, x0: Array,
               update_axis_name: Optional[str] = None,
               vg_fn=None, hvp_fn=None, l1_mask: Optional[Array] = None):
@@ -138,10 +150,11 @@ class GLMOptimizationProblem:
         hvp = _objective_hvp if hvp_fn is None else hvp_fn
         mask = self.l1_mask if l1_mask is None else l1_mask
         dim = x0.shape[-1]
-        l1 = cfg.regularization_context.l1_weight(cfg.regularization_weight)
-        use_owlqn = (cfg.optimizer_type == OptimizerType.LBFGS and l1 > 0.0)
+        site = self.solver_site()
 
-        if use_owlqn:
+        if site == "optimizer.owlqn":
+            l1 = cfg.regularization_context.l1_weight(
+                cfg.regularization_weight)
             l1_arr = jnp.full(dim, l1, x0.dtype)
             if mask is not None:
                 l1_arr = l1_arr * mask.astype(x0.dtype)
@@ -151,21 +164,19 @@ class GLMOptimizationProblem:
                 box=self.box, track_iterates=self.track_iterates,
                 update_axis_name=update_axis_name,
                 collective_quant=self.collective_quant)
-        if cfg.optimizer_type == OptimizerType.LBFGS:
+        if site == "optimizer.lbfgs":
             return minimize_lbfgs(
                 vg, x0, payload,
                 max_iter=cfg.max_iterations, tolerance=cfg.tolerance,
                 box=self.box, track_iterates=self.track_iterates,
                 update_axis_name=update_axis_name,
                 collective_quant=self.collective_quant)
-        if cfg.optimizer_type == OptimizerType.TRON:
-            return minimize_tron(
-                vg, hvp, x0, payload,
-                max_iter=cfg.max_iterations, tolerance=cfg.tolerance,
-                box=self.box, track_iterates=self.track_iterates,
-                update_axis_name=update_axis_name,
-                collective_quant=self.collective_quant)
-        raise ValueError(f"unknown optimizer {cfg.optimizer_type}")
+        return minimize_tron(
+            vg, hvp, x0, payload,
+            max_iter=cfg.max_iterations, tolerance=cfg.tolerance,
+            box=self.box, track_iterates=self.track_iterates,
+            update_axis_name=update_axis_name,
+            collective_quant=self.collective_quant)
 
     def publish(self, x: Array, history, progressed,
                 obj: Optional[GLMObjective] = None,
@@ -176,7 +187,8 @@ class GLMOptimizationProblem:
         (createModel analog)."""
         cfg = self.config
         result = OptimizationResult.from_history(
-            x, history, cfg.max_iterations, cfg.tolerance, bool(progressed))
+            x, history, cfg.max_iterations, cfg.tolerance, bool(progressed),
+            site=self.solver_site())
 
         variances = None
         if self.compute_variances and obj is not None and batch is not None:
@@ -221,7 +233,13 @@ class GLMOptimizationProblem:
             with trace.span("optimizer.solve", backend="local",
                             optimizer=self.config.optimizer_type.name):
                 x, history, progressed = self.solve(obj, batch, x0)
-            model, result = self.publish(x, history, progressed, obj, batch)
+            # the solve above only DISPATCHED: this span holds the wait
+            # for it and the history's fetch, i.e. where the device runs
+            # dry between two solves of a grid
+            with trace.span("optimizer.publish",
+                            optimizer=self.config.optimizer_type.name):
+                model, result = self.publish(x, history, progressed, obj,
+                                             batch)
         # Host-level fault site (never inside the jitted solve, where an
         # injection would bake into the compile cache): a nan-mode fault
         # here simulates a diverged solve for the recovery-policy tests.
@@ -272,7 +290,8 @@ class GLMOptimizationProblem:
         x = fault_point("optimizer.gradient", arrays=x)
         cfg = self.config
         return DeferredOptimizationResult(
-            x, history, progressed, cfg.max_iterations, cfg.tolerance)
+            x, history, progressed, cfg.max_iterations, cfg.tolerance,
+            site=self.solver_site())
 
     def regularization_value_device(self, coef_normalized: Array):
         """lambda-weighted penalty as a device scalar (no host sync) —

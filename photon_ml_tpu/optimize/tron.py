@@ -135,6 +135,8 @@ class _TRONCarry(NamedTuple):
     made_progress: Array
     values: Array
     grad_norms: Array
+    evaluations: Array  # [max_iter+1] int32 (RunHistory.evaluations)
+    hvps: Array  # [max_iter+1] int32 (RunHistory.hvps)
     iterates: Optional[Array]  # [max_iter+1, d] when tracking, else None
 
 
@@ -197,6 +199,9 @@ def _minimize_tron_impl(
     values = jnp.full(max_iter + 1, jnp.nan, dtype).at[0].set(f_start)
     grad_norms = jnp.full(max_iter + 1, jnp.nan, dtype).at[0].set(
         vnorm(g_start))
+    # the start's evaluation above; a resumed chunk made none
+    evaluations = jnp.zeros(max_iter + 1, jnp.int32).at[0].set(
+        1 if resume is None else 0)
     iterates0 = (jnp.zeros((max_iter + 1,) + x_start.shape, dtype)
                  .at[0].set(x_start) if track_iterates else None)
 
@@ -204,7 +209,8 @@ def _minimize_tron_impl(
         it=jnp.int32(0), x=x_start, f=f_start, g=g_start,
         prev_f=prev_f0,
         delta=delta0, failures=failures0, made_progress=jnp.bool_(True),
-        values=values, grad_norms=grad_norms, iterates=iterates0,
+        values=values, grad_norms=grad_norms, evaluations=evaluations,
+        hvps=jnp.zeros(max_iter + 1, jnp.int32), iterates=iterates0,
     )
 
     def cond(c: _TRONCarry) -> Array:
@@ -216,9 +222,12 @@ def _minimize_tron_impl(
         ) & (c.failures < max_failures)
 
     def body(c: _TRONCarry) -> _TRONCarry:
-        _, step, residual = _truncated_cg(
-            lambda v: hvp_fn(c.x, v, data), c.g, c.delta, update_axis_name,
-            collective_quant)
+        # CG's iteration count IS its Hessian-vector count: only the
+        # ``advance`` branch, which makes the product, advances it
+        with jax.named_scope("tron.cg"):
+            cg_iters, step, residual = _truncated_cg(
+                lambda v: hvp_fn(c.x, v, data), c.g, c.delta,
+                update_axis_name, collective_quant)
 
         x_try = c.x + step
         gs = vdot(c.g, step)
@@ -273,12 +282,14 @@ def _minimize_tron_impl(
                                update_axis_name)
         x_new = jnp.where(improved, project_box(x_try, box) if box is not None
                           else x_try, c.x)
+        evals = jnp.int32(1)  # the trial point
         if box is not None:
             # Projected point may differ from x_try; refresh (f, g) there.
             changed = improved & jnp.any(x_new != x_try)
             f_try, g_try = lax.cond(
                 changed, lambda: value_and_grad_fn(x_new, data),
                 lambda: (f_try, g_try))
+            evals = evals + changed.astype(jnp.int32)
 
         it_new = jnp.where(improved, c.it + 1, c.it)
         f_new = jnp.where(improved, f_try, c.f)
@@ -294,6 +305,13 @@ def _minimize_tron_impl(
         # or sliced off by from_history — no whole-buffer select needed
         iterates = (c.iterates.at[c.it + 1].set(x_new)
                     if track_iterates else None)
+        # a rejected trial leaves ``it`` where it was: its passes add up in
+        # the slot of the iteration being tried for
+        # (selects, not ``.at[].add``: under ``vmap`` that is a scatter over
+        # every lane, and these arrays are here to be cheap)
+        trying = jnp.arange(max_iter + 1) == c.it + 1
+        evaluations = c.evaluations + jnp.where(trying, evals, 0)
+        hvps = c.hvps + jnp.where(trying, cg_iters, 0)
 
         return _TRONCarry(
             it=it_new, x=x_new, f=f_new, g=g_new,
@@ -301,12 +319,14 @@ def _minimize_tron_impl(
             delta=delta,
             failures=jnp.where(improved, 0, c.failures + 1),
             made_progress=improved | (c.failures + 1 < max_failures),
-            values=values, grad_norms=grad_norms, iterates=iterates,
+            values=values, grad_norms=grad_norms, evaluations=evaluations,
+            hvps=hvps, iterates=iterates,
         )
 
     final = lax.while_loop(cond, body, init)
     history = RunHistory(values=final.values, grad_norms=final.grad_norms,
-                         num_iterations=final.it, iterates=final.iterates)
+                         num_iterations=final.it, iterates=final.iterates,
+                         evaluations=final.evaluations, hvps=final.hvps)
     if return_carry:
         carry = TRONResume(
             x=final.x, f=final.f, g=final.g, prev_f=final.prev_f,

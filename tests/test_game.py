@@ -311,7 +311,7 @@ class TestEntityBucketing:
         offs = ds.offsets_with(jnp.zeros(data.num_samples))
         c1, *_ = prob.run(ds, offs)
         # restarting AT the optimum must stay there (few extra iterations)
-        c2, iters, _, _ = prob.run(ds, offs, initial=c1)
+        c2, iters, *_ = prob.run(ds, offs, initial=c1)
         np.testing.assert_allclose(np.asarray(c2), np.asarray(c1),
                                    rtol=1e-3, atol=1e-4)
 
@@ -655,7 +655,7 @@ class TestRandomEffectSolver:
             data, RandomEffectDataConfiguration("u", "s", 1))
         prob = RandomEffectOptimizationProblem(
             config=l2_config(lam=1e-4), task=TaskType.LINEAR_REGRESSION)
-        coefs, iters, values, codes = prob.run(ds, ds.base_offsets)
+        coefs, iters, values, codes, _ = prob.run(ds, ds.base_offsets)
         # scatter back to raw space and compare per entity
         raw = ds.projectors.scatter_coefficients(np.asarray(coefs)).dense()
         for e_i, code in enumerate(ds.entity_codes):
@@ -726,10 +726,10 @@ class TestRandomEffectSolver:
                     RegularizationType.L2))
 
         task = TaskType.LOGISTIC_REGRESSION
-        c_tron, it_tron, v_tron, _ = RandomEffectOptimizationProblem(
+        c_tron, it_tron, v_tron, *_ = RandomEffectOptimizationProblem(
             config=cfg(OptimizerType.TRON), task=task).run(
                 ds, ds.base_offsets)
-        c_lbfgs, _, v_lbfgs, _ = RandomEffectOptimizationProblem(
+        c_lbfgs, _, v_lbfgs, *_ = RandomEffectOptimizationProblem(
             config=cfg(OptimizerType.LBFGS), task=task).run(
                 ds, ds.base_offsets)
         assert int(np.min(np.asarray(it_tron))) > 0  # TRON actually iterated
@@ -1048,3 +1048,150 @@ class TestCheckpointedCoordinateDescent:
         full_obj = res_full.states[-1].objective
         resumed_obj = res_resumed.states[-1].objective
         assert resumed_obj == pytest.approx(full_obj, rel=1e-4)
+
+
+class TestLaneEvaluationCounts:
+    """The vmapped path's counts: each lane's own evaluations, the rounds
+    the batched loop ran, and the fill they give (LaneCounts, the tracker
+    and the ``solver_*`` counters)."""
+
+    @staticmethod
+    def _blocks(rng, e=5, n=40, d=4):
+        X = rng.normal(size=(e, n, d))
+        # unequal scales: the lanes need unequal numbers of trials
+        X *= np.logspace(0, 1.5, e)[:, None, None]
+        w = rng.normal(size=(e, d))
+        z = np.einsum("end,ed->en", X, w)
+        y = (rng.random((e, n)) < 1 / (1 + np.exp(-z))).astype(float)
+        return (jnp.asarray(X), jnp.asarray(y), jnp.zeros((e, n)),
+                jnp.ones((e, n)), jnp.zeros((e, d)))
+
+    @staticmethod
+    def _problem(optimizer, l1=False, **kw):
+        reg = RegularizationContext(
+            RegularizationType.ELASTIC_NET, alpha=0.5) if l1 \
+            else RegularizationContext(RegularizationType.L2)
+        return RandomEffectOptimizationProblem(
+            config=GLMOptimizationConfiguration(
+                optimizer_type=optimizer, max_iterations=12, tolerance=1e-9,
+                regularization_weight=0.5, regularization_context=reg),
+            task=TaskType.LOGISTIC_REGRESSION, **kw)
+
+    SOLVERS = {"lbfgs": (OptimizerType.LBFGS, False),
+               "owlqn": (OptimizerType.LBFGS, True),
+               "tron": (OptimizerType.TRON, False)}
+
+    @pytest.mark.parametrize("solver", sorted(SOLVERS))
+    def test_each_lane_counts_its_solo_solve(self, rng, solver):
+        from photon_ml_tpu.data.batch import DenseBatch
+        from photon_ml_tpu.game import random_effect as re_mod
+        from photon_ml_tpu.optimize.lbfgs import minimize_lbfgs
+        from photon_ml_tpu.optimize.owlqn import minimize_owlqn
+        from photon_ml_tpu.optimize.tron import minimize_tron
+
+        X, y, off, wts, x0 = self._blocks(rng)
+        prob = self._problem(*self.SOLVERS[solver])
+        obj = prob.objective()
+        l1 = jnp.full(X.shape[2], 0.25 if solver == "owlqn" else 0.0)
+        _, iters, _, _, evals, rounds = re_mod._fit_blocks(
+            X, y, off, wts, x0, obj, l1, solver, 12, 1e-9)
+        solo = []
+        for e in range(X.shape[0]):
+            payload = (obj, DenseBatch(X=X[e], labels=y[e], offsets=off[e],
+                                       weights=wts[e]))
+            kw = dict(max_iter=12, tolerance=1e-9)
+            if solver == "owlqn":
+                out = minimize_owlqn(re_mod._vg, x0[e], payload, l1=l1, **kw)
+            elif solver == "tron":
+                out = minimize_tron(re_mod._vg, re_mod._hvp, x0[e], payload,
+                                    **kw)
+            else:
+                out = minimize_lbfgs(re_mod._vg, x0[e], payload, **kw)
+            assert int(out[1].num_iterations) == int(iters[e])
+            solo.append(int(np.asarray(out[1].evaluations).sum()))
+        assert list(np.asarray(evals)) == solo
+        assert len(set(solo)) > 1  # the lanes do differ
+        # the batched loop ran at least what its slowest lane needed, and
+        # no lane can need more in a round than the round's largest
+        assert rounds.shape == (1,)
+        assert max(solo) <= int(rounds[0]) <= sum(solo)
+
+    @pytest.mark.parametrize("solver", sorted(SOLVERS))
+    def test_compacted_chunks_count_what_one_dispatch_counts(self, rng,
+                                                             solver):
+        data, *_ = make_game_data(rng, n=500, n_entities=16)
+        ds = build_random_effect_dataset(
+            data, RandomEffectDataConfiguration("userId", "per_user", 1))
+        *_, whole = self._problem(*self.SOLVERS[solver]).run(
+            ds, ds.base_offsets)
+        *_, chunked = self._problem(
+            *self.SOLVERS[solver], lane_compaction_chunk=4).run(
+            ds, ds.base_offsets)
+        nr = len(ds.entity_codes)
+        np.testing.assert_array_equal(
+            np.asarray(chunked.evaluations)[:nr],
+            np.asarray(whole.evaluations)[:nr])
+        # one program a chunk, each over fewer (padded) lanes; together
+        # they run no more lane-evaluations than the one dispatch did
+        assert len(chunked.bucket_lanes) == len(chunked.evaluation_rounds) > 1
+        assert len(whole.bucket_lanes) == 1
+
+        def ran(counts):
+            return int(np.dot(counts.bucket_lanes,
+                              np.asarray(counts.evaluation_rounds)))
+
+        assert ran(chunked) <= ran(whole)
+
+    def test_lane_fill_of_a_two_lane_bucket(self, rng):
+        """One lane done at once (no weight: its gradient at the start is
+        zero, so it makes the start's evaluation and stops), one slow: the
+        slow lane sets every round, and the fill is what arithmetic says."""
+        from photon_ml_tpu.game import random_effect as re_mod
+        from photon_ml_tpu.game.coordinate import RandomEffectTracker
+        from photon_ml_tpu.obs.metrics import REGISTRY
+
+        X, y, off, wts, x0 = self._blocks(rng, e=2)
+        wts = wts.at[0].set(0.0)
+        prob = self._problem(OptimizerType.LBFGS)
+        out = re_mod._fit_blocks(X, y, off, wts, x0, prob.objective(),
+                                 jnp.zeros(X.shape[2]), "lbfgs", 12, 1e-9)
+        coefs, iters, values, codes, evals, rounds = out
+        slow = int(evals[1])
+        assert int(iters[0]) == 0 and int(evals[0]) == 1 and slow > 3
+        assert int(rounds[0]) == slow
+        tracker = RandomEffectTracker(
+            iters, values, codes, evaluations=evals,
+            evaluation_rounds=rounds, bucket_lanes=np.asarray([2]),
+            site="t.two_lanes")
+
+        def booked(name):
+            return REGISTRY.counter(name).value(site="t.two_lanes")
+
+        before = {n: booked(n) for n in (
+            "solver_iterations", "solver_evaluations",
+            "solver_lane_evaluations")}
+        assert tracker.lane_fill() == pytest.approx((1 + slow) / (2 * slow))
+        tracker.materialize().materialize()  # booked once
+        assert booked("solver_iterations") \
+            - before["solver_iterations"] == int(iters[1])
+        assert booked("solver_evaluations") \
+            - before["solver_evaluations"] == 1 + slow
+        assert booked("solver_lane_evaluations") \
+            - before["solver_lane_evaluations"] == 2 * slow
+
+    def test_bucketed_update_fills_the_tracker(self, rng):
+        data, *_ = make_game_data(rng, n=600, n_entities=24)
+        ds = build_random_effect_dataset(
+            data, RandomEffectDataConfiguration("userId", "per_user", 1),
+            num_buckets=3)
+        coord = RandomEffectCoordinate(ds, self._problem(OptimizerType.LBFGS))
+        _, tracker = coord.update(None, jnp.zeros(data.num_samples))
+        tracker.materialize()
+        assert tracker.site == "re.fit_blocks"
+        assert tracker.evaluations.shape == tracker.iterations.shape
+        assert (tracker.evaluations >= tracker.iterations + 1).all()
+        assert len(tracker.bucket_lanes) == len(ds.buckets) \
+            == len(tracker.evaluation_rounds)
+        assert list(tracker.bucket_lanes) == [
+            int(b.X.shape[0]) for b in ds.buckets]
+        assert 0.0 < tracker.lane_fill() <= 1.0

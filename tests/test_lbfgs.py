@@ -37,6 +37,9 @@ def test_converges_on_known_convex_function():
     assert res.convergence_reason in (ConvergenceReason.FUNCTION_VALUES_CONVERGED,
                                       ConvergenceReason.GRADIENT_CONVERGED)
     assert res.iterations <= 3
+    # every iteration evaluates at least once, beside the start
+    assert res.evaluations >= res.iterations + 1
+    assert res.evaluations == int(np.asarray(hist.evaluations).sum())
 
 
 def test_start_at_optimum_reports_gradient_converged():
@@ -47,6 +50,9 @@ def test_start_at_optimum_reports_gradient_converged():
     res = OptimizationResult.from_history(x, hist, 100, 1e-7, bool(ok))
     assert res.convergence_reason == ConvergenceReason.GRADIENT_CONVERGED
     np.testing.assert_allclose(np.asarray(x), np.asarray(center))
+    # the start is the one evaluation a solve that never iterates makes
+    assert res.evaluations == 1 and res.hvps is None
+    assert list(np.asarray(hist.evaluations)[:3]) == [1, 0, 0]
 
 
 def _logistic_fit_problem(rng, n=300, d=6, l2=0.5):

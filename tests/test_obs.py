@@ -45,7 +45,11 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.fixture(autouse=True)
 def _tracer_isolation():
-    """Tests must not leak an enabled process-global tracer."""
+    """Tests must not leak an enabled process-global tracer, nor inherit
+    an armed device plane (which mirrors spans into the profiler)."""
+    from photon_ml_tpu.obs import compile as obs_compile
+
+    obs_compile.disarm()
     yield
     trace.disable()
 
@@ -80,6 +84,37 @@ class TestSpanTracer:
             assert child["ts_us"] >= outer["ts_us"]
             assert (child["ts_us"] + child["dur_us"]
                     <= outer["ts_us"] + outer["dur_us"] + 1e-3)
+
+    def test_late_labels_reach_the_record(self):
+        """``label()`` adds what only the finished work knows (a compile's
+        seconds); on the no-op span it is a no-op too."""
+        trace.span("off").label(secs=1.0)  # tracing off: nothing to keep
+        t = trace.enable()
+        with trace.span("xla.compile", site="s") as span:
+            span.label(secs=0.5, flops=8.0)
+        (event,) = t.events()
+        assert event["labels"] == {"site": "s", "secs": 0.5, "flops": 8.0}
+
+    def test_mirrored_spans_record_as_before(self):
+        """With the mirror into the profiler on (no capture running), a
+        span records into the tracer exactly as without it, and without a
+        tracer it is still a context manager that nests."""
+        try:
+            trace.mirror_to_profiler(True)
+            with trace.span("outer", sweep=1) as outer:
+                with trace.span("inner"):
+                    outer.label(late=True)
+            t = trace.enable()
+            with trace.span("outer", sweep=1):
+                with trace.span("inner"):
+                    pass
+        finally:
+            trace.mirror_to_profiler(False)
+        assert [(e["name"], e["depth"], e["labels"]) for e in t.events()] \
+            == [("inner", 1, {}), ("outer", 0, {"sweep": 1})]
+        assert trace.span("outer") is not trace._NULL_SPAN
+        trace.disable()
+        assert trace.span("outer") is trace._NULL_SPAN
 
     def test_thread_safety(self):
         t = trace.enable()

@@ -275,6 +275,139 @@ class TestArmedHotLoopContracts:
             f"disarmed vs {min(armed):.4f}s armed"
 
 
+# -- the program's spans in the profiler's trace -------------------------------
+
+
+def _host_events(trace_dir):
+    """{name: [(start_ns, end_ns, stats)]} of the profiler capture's host
+    plane, as ``jax.profiler.ProfileData`` reads the ``.xplane.pb``."""
+    import glob
+
+    (path,) = glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    profile = jax.profiler.ProfileData.from_file(path)
+    events = {}
+    for plane in profile.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                events.setdefault(e.name, []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns, dict(e.stats)))
+    return events
+
+
+def _inside(inner, outer):
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+class TestSpansInTheProfilerTrace:
+    def test_disarmed_and_untraced_span_is_the_shared_noop(self):
+        assert trace.span("cd.sweep", sweep=0) is trace._NULL_SPAN
+        obs_compile.arm()
+        armed = trace.span("cd.sweep", sweep=0)
+        assert armed is not trace._NULL_SPAN
+        with armed:  # no profiler session, no tracer: still a context
+            pass
+        obs_compile.disarm()
+        assert trace.span("cd.sweep", sweep=0) is trace._NULL_SPAN
+
+    def test_armed_cd_run_lands_on_the_host_plane(self, rng, registry,
+                                                  tmp_path):
+        """A CPU profiler capture of a tiny armed run: the sweep loop's
+        spans are on the host plane with their labels as stats, nested as
+        the code nests them, a block's dispatch and its later fetch joined
+        by ``update``, and a compile where it happened, at its real
+        length."""
+        from photon_ml_tpu.game.coordinate_descent import (
+            run_coordinate_descent,
+        )
+        from photon_ml_tpu.optimize.config import TaskType
+
+        coords, labels, weights, offsets = _cd_inputs(
+            rng, n=240, n_entities=6)
+        obs_compile.arm(registry=registry)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            run_coordinate_descent(coords, 1, TaskType.LOGISTIC_REGRESSION,
+                                   labels, weights, offsets)
+        finally:
+            jax.profiler.stop_trace()
+        events = _host_events(str(tmp_path))
+
+        (sweep,) = events["cd.sweep"]
+        assert sweep[2] == {"sweep": 0}
+        dispatches = events["cd.dispatch"]
+        fetches = events["cd.epilogue_fetch"]
+        assert len(dispatches) == len(fetches) == len(coords)
+        for span in dispatches + fetches:
+            assert _inside(span, sweep)
+        # dispatch -> fetch, joined by the update serial
+        by_update = {d[2]["update"]: d for d in dispatches}
+        assert sorted(by_update) == list(range(len(coords)))
+        for fetch in fetches:
+            dispatch = by_update[fetch[2]["update"]]
+            assert dispatch[1] <= fetch[0]
+            assert fetch[2]["coordinate"] == dispatch[2]["coordinates"]
+        for wait in events.get("cd.pipeline_wait", []):
+            assert any(_inside(f, wait) and f[2]["update"]
+                       == wait[2]["update"] for f in fetches)
+        # the solves sit inside their coordinate's dispatch
+        (solve,) = events["optimizer.solve"]
+        assert solve[2]["optimizer"] == "LBFGS"
+        fixed = next(d for d in dispatches
+                     if d[2]["coordinates"] == "fixed")
+        assert _inside(solve, fixed)
+        (re_solve,) = events["re.solve"]
+        assert re_solve[2]["solver"] == "lbfgs"
+        assert any(_inside(re_solve, d) for d in dispatches)
+        # the solver's compile: inside optimizer.solve, as long as the
+        # counter says, its lowering a child of it
+        compiles = [c for c in events["xla.compile"]
+                    if c[2]["site"] == "optimizer.lbfgs"]
+        (compiled,) = compiles
+        assert _inside(compiled, solve)
+        secs = registry.counter("compile_secs").value(
+            site="optimizer.lbfgs")
+        # (the span also holds the cost analysis the labels wait for)
+        assert (compiled[1] - compiled[0]) / 1e9 >= secs > 0
+        (lowered,) = [e for e in events["xla.lower"]
+                      if e[2]["site"] == "optimizer.lbfgs"]
+        assert _inside(lowered, compiled)
+        lower_secs = registry.counter("lower_secs").value(
+            site="optimizer.lbfgs")
+        assert 0 < lower_secs <= secs
+        assert (lowered[1] - lowered[0]) / 1e9 == pytest.approx(
+            lower_secs, rel=0.05, abs=2e-4)
+
+    def test_compile_span_is_real_in_the_tracer_too(self, registry):
+        """The program's own trace: ``xla.compile`` has a duration, its
+        late labels, and ``xla.lower`` / ``xla.retrace`` inside it."""
+        obs_compile.arm(registry=registry)
+        tracer = trace.enable()
+        f = jax.jit(lambda x: (x * 2.0).sum())
+        obs_compile.call("t.span", f, (jnp.ones((8,), jnp.float32),),
+                         arg_names=("x",))
+        obs_compile.call("t.span", f, (jnp.ones((9,), jnp.float32),),
+                         arg_names=("x",))
+        spans = tracer.events()
+        compiles = [e for e in spans if e["name"] == "xla.compile"]
+        lowers = [e for e in spans if e["name"] == "xla.lower"]
+        (retrace,) = [e for e in spans if e["name"] == "xla.retrace"]
+        assert len(compiles) == len(lowers) == 2
+        for compiled, lowered in zip(compiles, lowers):
+            assert compiled["dur_us"] / 1e6 >= compiled["labels"]["secs"] > 0
+            assert lowered["depth"] == compiled["depth"] + 1
+            assert compiled["ts_us"] <= lowered["ts_us"]
+            assert lowered["ts_us"] + lowered["dur_us"] \
+                <= compiled["ts_us"] + compiled["dur_us"]
+        assert retrace["labels"]["field"] == "shape"
+        assert compiles[1]["ts_us"] <= retrace["ts_us"] \
+            <= compiles[1]["ts_us"] + compiles[1]["dur_us"]
+        assert registry.counter("lower_secs").value(site="t.span") \
+            <= registry.counter("compile_secs").value(site="t.span")
+
+
 # -- HBM accounting ----------------------------------------------------------
 
 

@@ -5,6 +5,9 @@ integration tests (agreement with L-BFGS solutions on twice-differentiable
 objectives, BaseGLMIntegTest's max-difference check between TRON and LBFGS).
 """
 
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -12,6 +15,7 @@ import pytest
 from photon_ml_tpu.data.batch import dense_batch
 from photon_ml_tpu.ops.aggregators import GLMObjective
 from photon_ml_tpu.ops.losses import get_loss
+from photon_ml_tpu.optimize.common import BoxConstraints, OptimizationResult
 from photon_ml_tpu.optimize.lbfgs import minimize_lbfgs
 from photon_ml_tpu.optimize.owlqn import minimize_owlqn, pseudo_gradient
 from photon_ml_tpu.optimize.tron import minimize_tron
@@ -189,3 +193,134 @@ def test_all_optimizers_agree_from_random_starts(rng):
     ref = optima[0]
     for w in optima[1:]:
         np.testing.assert_allclose(w, ref, rtol=1e-4, atol=1e-6)
+
+
+# --- the solvers count their own evaluations -------------------------------
+
+_RAN = {"vg": 0, "hvp": 0}
+
+
+def _ran(which):
+    _RAN[which] += 1
+
+
+def _counted_vg(w, payload):
+    jax.debug.callback(functools.partial(_ran, "vg"))
+    return _obj_vg(w, payload)
+
+
+def _counted_hvp(w, v, payload):
+    jax.debug.callback(functools.partial(_ran, "hvp"))
+    return _obj_hvp(w, v, payload)
+
+
+def _minimize(solver, vg, hvp, x0, payload, **kw):
+    if solver == "lbfgs":
+        return minimize_lbfgs(vg, x0, payload, **kw)
+    if solver == "owlqn":
+        return minimize_owlqn(vg, x0, payload, l1=0.05, **kw)
+    return minimize_tron(vg, hvp, x0, payload, **kw)
+
+
+SOLVERS = ("lbfgs", "owlqn", "tron")
+
+
+@pytest.mark.parametrize("boxed", (False, True), ids=("free", "boxed"))
+@pytest.mark.parametrize("solver", SOLVERS)
+def test_evaluations_count_every_run_of_the_objective(rng, solver, boxed):
+    """``RunHistory.evaluations`` (and TRON's ``hvps``) against a count the
+    solver has no hand in: a host counter that the test's own objective
+    bumps through a callback every time it really runs. Un-vmapped, so a
+    ``cond``'s branch that is not taken does not run. The box is tight
+    enough to clip iterates, which costs L-BFGS and TRON a re-evaluation."""
+    batch, obj = _problem(rng, l2=0.1)
+    d = batch.num_features
+    box = BoxConstraints(jnp.full(d, -0.3), jnp.full(d, 0.3)) if boxed \
+        else None
+    _RAN.update(vg=0, hvp=0)
+    x, hist, _ = _minimize(solver, _counted_vg, _counted_hvp,
+                           jnp.zeros(d, jnp.float64), (obj, batch),
+                           max_iter=25, tolerance=1e-9, box=box)
+    jax.effects_barrier()
+    k = int(hist.num_iterations)
+    evaluations = np.asarray(hist.evaluations)
+    assert k >= 3
+    assert evaluations.dtype == np.int32 and evaluations.shape == (26,)
+    assert evaluations[0] == 1  # the start
+    assert int(evaluations.sum()) == _RAN["vg"]
+    assert (evaluations[1:k + 1] >= 1).all()  # an iteration evaluates
+    if boxed:
+        assert float(jnp.max(jnp.abs(x))) <= 0.3
+    if solver == "tron":
+        assert int(np.asarray(hist.hvps).sum()) == _RAN["hvp"] > 0
+        assert np.asarray(hist.hvps)[0] == 0
+    else:
+        assert hist.hvps is None and _RAN["hvp"] == 0
+    # the host-side totals ride the history's own fetch
+    res = OptimizationResult.from_history(x, hist, 25, 1e-9)
+    assert res.evaluations == _RAN["vg"]
+    assert res.hvps == (_RAN["hvp"] if solver == "tron" else None)
+
+
+@pytest.mark.parametrize("solver", SOLVERS)
+def test_resumed_chunks_count_what_one_dispatch_counts(rng, solver):
+    """A solve split into resumed chunks makes the single dispatch's
+    evaluations: a resumed chunk's slot 0 is 0 (its start was the previous
+    chunk's last evaluation), and the rest line up iteration by iteration."""
+    batch, obj = _problem(rng, l2=0.1)
+    x0 = jnp.zeros(batch.num_features, jnp.float64)
+    payload = (obj, batch)
+    _, whole, _ = _minimize(solver, _obj_vg, _obj_hvp, x0, payload,
+                            max_iter=6, tolerance=1e-12)
+    k = int(whole.num_iterations)
+    assert k == 6  # the budget, not convergence, ends it
+
+    by_iteration, hvps, carry, x = [], 0, None, x0
+    for chunk in range(3):
+        x, hist, _, carry = _minimize(
+            solver, _obj_vg, _obj_hvp, x, payload, max_iter=2,
+            tolerance=1e-12, resume=carry, return_carry=True)
+        evaluations = np.asarray(hist.evaluations)
+        assert evaluations[0] == (1 if chunk == 0 else 0)
+        by_iteration += list(evaluations[1:])
+        if solver == "tron":
+            hvps += int(np.asarray(hist.hvps).sum())
+    assert by_iteration == list(np.asarray(whole.evaluations)[1:k + 1])
+    if solver == "tron":
+        assert hvps == int(np.asarray(whole.hvps).sum())
+
+
+def test_counters_book_each_solve_once(rng):
+    """``solver_*{site}`` are incremented where a history reaches the host,
+    once however often the result is read; a history with no counts books
+    nothing."""
+    from photon_ml_tpu.obs.metrics import REGISTRY
+    from photon_ml_tpu.optimize.common import DeferredOptimizationResult
+
+    def totals():
+        return {name: REGISTRY.counter(name).value(site="t.solver")
+                for name in ("solver_iterations", "solver_evaluations",
+                             "solver_lane_evaluations", "solver_hvps")}
+
+    batch, obj = _problem(rng, l2=0.1)
+    x0 = jnp.zeros(batch.num_features, jnp.float64)
+    before = totals()
+    x, hist, ok = minimize_tron(_obj_vg, _obj_hvp, x0, (obj, batch))
+    lazy = DeferredOptimizationResult(x, hist, ok, 15, 1e-5,
+                                      site="t.solver")
+    assert totals() == before  # nothing read, nothing booked
+    for _ in range(3):
+        lazy.iterations, lazy.evaluations, lazy.value
+    after = totals()
+    assert after["solver_iterations"] - before["solver_iterations"] \
+        == lazy.iterations
+    assert after["solver_evaluations"] - before["solver_evaluations"] \
+        == lazy.evaluations == int(np.asarray(hist.evaluations).sum())
+    # a single solve executes what it needs
+    assert after["solver_lane_evaluations"] \
+        - before["solver_lane_evaluations"] == lazy.evaluations
+    assert after["solver_hvps"] - before["solver_hvps"] == lazy.hvps > 0
+    OptimizationResult.from_history(
+        x, hist._replace(evaluations=None, hvps=None), 15, 1e-5,
+        site="t.solver")
+    assert totals() == after

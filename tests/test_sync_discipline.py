@@ -196,8 +196,8 @@ class TestLaneCompactionParity:
             config=l2_config(lam=0.5, max_iter=40),
             task=TaskType.LOGISTIC_REGRESSION, lane_compaction_chunk=5)
         offs = re_ds.base_offsets
-        c0, it0, _, k0 = base.run(re_ds, offs)
-        c1, it1, _, k1 = compacted.run(re_ds, offs)
+        c0, it0, _, k0, n0 = base.run(re_ds, offs)
+        c1, it1, _, k1, n1 = compacted.run(re_ds, offs)
         # chunk restarts resume the FULL solver carry with the ORIGINAL
         # f₀/‖g₀‖ anchors, so the chunked solve runs exactly the
         # iterations the single dispatch would: coefficients AND
@@ -207,6 +207,10 @@ class TestLaneCompactionParity:
         np.testing.assert_array_equal(np.asarray(it1)[:nr],
                                       np.asarray(it0)[:nr])
         assert np.asarray(k1).shape == np.asarray(k0).shape
+        # ... and so are the evaluation counts: a resumed chunk books no
+        # start evaluation, so the chunks add up to the single dispatch
+        np.testing.assert_array_equal(np.asarray(n1.evaluations)[:nr],
+                                      np.asarray(n0.evaluations)[:nr])
 
     def test_compacted_bucketed_matches_single_dispatch(self, rng):
         data, *_ = make_game_data(rng, n=500, n_entities=16)
