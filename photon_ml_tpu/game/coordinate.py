@@ -148,9 +148,10 @@ class RandomEffectTracker:
 
     def lane_fill(self) -> Optional[float]:
         """Evaluations the entities needed over the lane-evaluations the
-        batched loops ran for them (pad lanes included): at most 1, by
-        construction an upper bound on the true fill (``rounds`` is a
-        lower bound, see ``game/random_effect._fit_blocks_impl``)."""
+        batched loops ran for them (pad lanes included): at most 1. The
+        executed fill while every lane is still solving; above it only by
+        what finished lanes ride along for (``rounds`` cannot see that, see
+        ``game/random_effect._fit_blocks_impl``)."""
         self.materialize()
         if self.evaluations is None:
             return None

@@ -228,13 +228,20 @@ def _fit_blocks_impl(
     (the sum of its ``RunHistory.evaluations``: what its solo solve would
     make). ``rounds`` = Σ_k max over lanes of ``evaluations[k]``: the
     rounds of evaluation the batched loop ran for this dispatch, as far as
-    the lanes themselves can tell. It is a LOWER bound on what the device
-    executed: a finished lane still rides every later round, and the trips
-    it makes there are discarded, so they are not visible from inside
-    ``vmap``; nor is a round in which lane A needed its 3rd trial of
-    iteration k while lane B was already at k+1 (the per-iteration maxima
-    assume the lanes' iterations line up, which the batched outer loop
-    enforces). Shape [1], so a sharded dispatch concatenates one per shard.
+    the lanes themselves can tell. The solvers' loops evaluate once a pass
+    and under no conditional (a batched ``lax.switch``/``lax.cond`` runs
+    every branch for every lane, and the two-stage line search paid two
+    evaluations a pass that way: it no longer does), so while every lane is
+    still solving, ``rounds`` IS what the device executed: the batched
+    outer loop lines the lanes' iterations up, and in each the inner loop
+    makes as many passes as its slowest lane needs. What still escapes it:
+    a finished lane rides every later iteration, replaying the step after
+    its last, and the trials it makes there are discarded, so they are not
+    visible from inside ``vmap``; where such a lane needs more trials than
+    any lane still solving, the device ran more than ``rounds``. (And an
+    evaluation that does sit under a ``lax.cond``, the box projection's in
+    ``lbfgs.py``, runs for every lane once any lane needs it.) Shape [1],
+    so a sharded dispatch concatenates one per shard.
 
     ``boundary_convergence`` is set by the lane-compaction driver on
     NON-final chunks: a lane that satisfies a convergence criterion on

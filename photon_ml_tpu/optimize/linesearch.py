@@ -4,20 +4,28 @@ The reference delegates line search to Breeze's ``StrongWolfeLineSearch``
 (via BreezeLBFGS — reference optimization/LBFGS.scala:100-112). Breeze uses a
 bracket-then-zoom scheme (Nocedal & Wright Alg. 3.5/3.6) with cubic
 interpolation; we implement the same scheme as a single ``lax.while_loop``
-with a stage flag (BRACKET -> ZOOM), so it compiles once and runs entirely on
-device. Wolfe constants match Breeze/Nocedal defaults: c1=1e-4, c2=0.9.
+whose carry holds the stage (BRACKET -> ZOOM) and the next trial step, so it
+compiles once and runs entirely on device. Wolfe constants match
+Breeze/Nocedal defaults: c1=1e-4, c2=0.9.
 
 The search works on the 1-D restriction phi(a) = f(x + a d): each trial
 evaluates the full (value, gradient) so the accepted point's gradient is
 returned for free — one objective evaluation per trial, exactly like the
 reference's calculate-per-line-search-step.
+
+Every pass of the loop makes exactly one evaluation, at the carried trial
+step and under no ``lax.switch``/``lax.cond``; the stage's rules then judge
+it and pick the next trial by selects on scalars. So a search makes
+``num_evals`` passes, batched or not: under ``jax.vmap`` (the per-entity
+solves) a conditional with a batched index would run every branch for every
+lane, and an evaluation inside one would be paid once per branch. Here the
+batched loop evaluates max-over-lanes(``num_evals``) times and no more.
 """
 
 from __future__ import annotations
 
 from typing import Callable, NamedTuple
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -39,11 +47,11 @@ class LineSearchResult(NamedTuple):
 
 class _LSState(NamedTuple):
     stage: Array
-    it: Array
-    # current trial
+    it: Array  # evaluations made so far
+    # the next trial while searching; the last one evaluated once stopped
     a: Array
+    # value and gradient at the last trial evaluated
     phi_a: Array
-    dphi_a: Array
     g_a: Array
     # previous trial (bracketing) / zoom interval lo and hi
     a_lo: Array
@@ -95,104 +103,78 @@ def strong_wolfe(
     """
     dtype = phi0.dtype
 
-    def evaluate(a):
-        phi, dphi, g = value_and_grad_1d(a)
-        return phi, dphi, g
-
-    def bracket_step(s: _LSState) -> _LSState:
-        armijo_fail = (s.phi_a > phi0 + C1 * s.a * dphi0) | (
-            (s.it > 0) & (s.phi_a >= s.phi_lo)
-        )
-        curv_ok = jnp.abs(s.dphi_a) <= -C2 * dphi0
-        pos_slope = s.dphi_a >= 0.0
-
-        # -> ZOOM with (lo=prev, hi=cur) when Armijo fails; accept when both
-        # Wolfe hold; -> ZOOM with (lo=cur, hi=prev) on positive slope;
-        # otherwise expand.
-        def to_zoom_prev_cur(s):
-            return s._replace(stage=jnp.int32(_ZOOM), a_hi=s.a,
-                              phi_hi=s.phi_a, dphi_hi=s.dphi_a)
-
-        def accept(s):
-            return s._replace(stage=jnp.int32(_DONE))
-
-        def to_zoom_cur_prev(s):
-            return s._replace(stage=jnp.int32(_ZOOM), a_lo=s.a, phi_lo=s.phi_a,
-                              dphi_lo=s.dphi_a, g_lo=s.g_a, a_hi=s.a_lo,
-                              phi_hi=s.phi_lo, dphi_hi=s.dphi_lo)
-
-        def expand(s):
-            new_a = jnp.minimum(2.0 * s.a, jnp.asarray(max_alpha, dtype))
-            phi, dphi, g = evaluate(new_a)
-            return s._replace(
-                a_lo=s.a, phi_lo=s.phi_a, dphi_lo=s.dphi_a, g_lo=s.g_a,
-                a=new_a, phi_a=phi, dphi_a=dphi, g_a=g,
-                it=s.it + 1,
-            )
-
-        branch = jnp.where(
-            armijo_fail, 0, jnp.where(curv_ok, 1, jnp.where(pos_slope, 2, 3))
-        )
-        return lax.switch(branch, [to_zoom_prev_cur, accept, to_zoom_cur_prev,
-                                   expand], s)
-
-    def zoom_step(s: _LSState) -> _LSState:
-        a_j = _cubic_min(s.a_lo, s.phi_lo, s.dphi_lo, s.a_hi, s.phi_hi, s.dphi_hi)
-        phi, dphi, g = evaluate(a_j)
-        s = s._replace(a=a_j, phi_a=phi, dphi_a=dphi, g_a=g, it=s.it + 1)
-
-        armijo_fail = (phi > phi0 + C1 * a_j * dphi0) | (phi >= s.phi_lo)
-
-        def shrink_hi(s):
-            return s._replace(a_hi=s.a, phi_hi=s.phi_a, dphi_hi=s.dphi_a)
-
-        def check_curvature(s):
-            curv_ok = jnp.abs(s.dphi_a) <= -C2 * dphi0
-
-            def accept(s):
-                return s._replace(stage=jnp.int32(_DONE))
-
-            def move_lo(s):
-                flip = s.dphi_a * (s.a_hi - s.a_lo) >= 0.0
-                s = lax.cond(
-                    flip,
-                    lambda s: s._replace(a_hi=s.a_lo, phi_hi=s.phi_lo,
-                                         dphi_hi=s.dphi_lo),
-                    lambda s: s,
-                    s,
-                )
-                return s._replace(a_lo=s.a, phi_lo=s.phi_a, dphi_lo=s.dphi_a,
-                                  g_lo=s.g_a)
-
-            return lax.cond(curv_ok, accept, move_lo, s)
-
-        return lax.cond(armijo_fail, shrink_hi, check_curvature, s)
-
     def body(s: _LSState) -> _LSState:
-        s = lax.switch(s.stage, [bracket_step, zoom_step,
-                                 lambda s: s, lambda s: s], s)
+        a = s.a
+        phi, dphi, g = value_and_grad_1d(a)
+        it = s.it + 1
+        bracketing = s.stage == _BRACKET
+
+        # Both stages put the same three questions to a fresh trial, in
+        # this order. Sufficient decrease fails: the trial becomes hi (which
+        # ends the bracketing: lo is the previous trial). Both Wolfe
+        # conditions hold: accept. Otherwise the trial becomes lo, and hi
+        # takes the old lo when the slope there points away from hi — while
+        # bracketing that is a positive slope (which ends the bracketing),
+        # and no flip means expand. (N&W Alg. 3.5 asks phi >= phi_lo of
+        # the bracketing only from its second trial; at the first, phi_lo
+        # is phi(0), and the two-stage form asked it there too.) The
+        # bracketing stage spends its last evaluation unjudged: the budget
+        # runs out first.
+        judged = ~(bracketing & (it >= MAX_LS_ITER))
+        armijo_fail = (phi > phi0 + C1 * a * dphi0) | (phi >= s.phi_lo)
+        curv_ok = jnp.abs(dphi) <= -C2 * dphi0
+        flip = jnp.where(bracketing, dphi >= 0.0,
+                         dphi * (s.a_hi - s.a_lo) >= 0.0)
+        shrink_hi = judged & armijo_fail
+        accept = judged & ~armijo_fail & curv_ok
+        move_lo = judged & ~armijo_fail & ~curv_ok
+        flip_hi = move_lo & flip
+
+        def pick_hi(cur, lo, hi):
+            return jnp.where(shrink_hi, cur, jnp.where(flip_hi, lo, hi))
+
+        a_hi = pick_hi(a, s.a_lo, s.a_hi)
+        phi_hi = pick_hi(phi, s.phi_lo, s.phi_hi)
+        dphi_hi = pick_hi(dphi, s.dphi_lo, s.dphi_hi)
+        a_lo = jnp.where(move_lo, a, s.a_lo)
+        phi_lo = jnp.where(move_lo, phi, s.phi_lo)
+        dphi_lo = jnp.where(move_lo, dphi, s.dphi_lo)
+        g_lo = jnp.where(move_lo, g, s.g_lo)
+        stage = jnp.where(
+            accept, _DONE,
+            jnp.where(bracketing & ~(shrink_hi | flip_hi), _BRACKET, _ZOOM))
+
         # Give up when the eval budget is exhausted or the zoom interval
         # collapsed; keep the best sufficient-decrease point seen (a_lo).
-        exhausted = (s.it >= MAX_LS_ITER) & (s.stage < _DONE)
-        interval_dead = (s.stage == _ZOOM) & (
-            jnp.abs(s.a_hi - s.a_lo) <= 1e-14 * jnp.maximum(1.0, jnp.abs(s.a_hi))
+        exhausted = (it >= MAX_LS_ITER) & (stage < _DONE)
+        interval_dead = (stage == _ZOOM) & (
+            jnp.abs(a_hi - a_lo) <= 1e-14 * jnp.maximum(1.0, jnp.abs(a_hi))
         )
-        return lax.cond(
-            exhausted | interval_dead,
-            lambda s: s._replace(stage=jnp.int32(_FAIL)),
-            lambda s: s,
-            s,
+        stage = jnp.where(exhausted | interval_dead, _FAIL, stage)
+
+        # The next trial: twice the step while bracketing, the cubic's
+        # minimizer over (lo, hi) once zooming; a stopped search keeps its
+        # last trial, which is the accepted step.
+        a_next = jnp.where(
+            stage == _BRACKET,
+            jnp.minimum(2.0 * a, jnp.asarray(max_alpha, dtype)),
+            jnp.where(
+                stage == _ZOOM,
+                _cubic_min(a_lo, phi_lo, dphi_lo, a_hi, phi_hi, dphi_hi),
+                a))
+        return _LSState(
+            stage=stage.astype(jnp.int32), it=it, a=a_next, phi_a=phi, g_a=g,
+            a_lo=a_lo, phi_lo=phi_lo, dphi_lo=dphi_lo, g_lo=g_lo,
+            a_hi=a_hi, phi_hi=phi_hi, dphi_hi=dphi_hi,
         )
 
     def cond(s: _LSState) -> Array:
         return s.stage < _DONE
 
-    a0 = jnp.asarray(init_alpha, dtype)
-    phi_i, dphi_i, g_i = evaluate(a0)
     init = _LSState(
         stage=jnp.int32(_BRACKET),
-        it=jnp.int32(1),
-        a=a0, phi_a=phi_i, dphi_a=dphi_i, g_a=g_i,
+        it=jnp.int32(0),
+        a=jnp.asarray(init_alpha, dtype), phi_a=phi0, g_a=g0,
         a_lo=jnp.zeros((), dtype), phi_lo=phi0, dphi_lo=dphi0, g_lo=g0,
         a_hi=jnp.zeros((), dtype), phi_hi=phi0, dphi_hi=dphi0,
     )

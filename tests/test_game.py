@@ -1117,6 +1117,39 @@ class TestLaneEvaluationCounts:
         assert max(solo) <= int(rounds[0]) <= sum(solo)
 
     @pytest.mark.parametrize("solver", sorted(SOLVERS))
+    def test_rounds_booked_are_the_passes_the_batched_loop_ran(
+            self, rng, solver, monkeypatch):
+        """``rounds`` against a count the solvers have no hand in: a host
+        counter the objective bumps once per execution, however many lanes
+        that execution carries. With a budget that ends every lane's solve
+        (none finishes early and rides along), rounds x lanes booked is
+        exactly what ran: no evaluation under a batched conditional is
+        paid once per branch."""
+        import jax
+
+        from photon_ml_tpu.game import random_effect as re_mod
+
+        ran = {"vg": 0}
+
+        def counted_vg(w, payload):
+            jax.debug.callback(lambda: ran.__setitem__("vg", ran["vg"] + 1))
+            obj, batch = payload
+            return obj.calculate(w, batch)
+
+        monkeypatch.setattr(re_mod, "_vg", counted_vg)
+        X, y, off, wts, x0 = self._blocks(rng)
+        prob = self._problem(*self.SOLVERS[solver])
+        l1 = jnp.full(X.shape[2], 0.25 if solver == "owlqn" else 0.0)
+        budget = 3
+        _, iters, _, _, evals, rounds = re_mod._fit_blocks_impl(
+            X, y, off, wts, x0, prob.objective(), l1, solver, budget, 1e-30)
+        jax.effects_barrier()
+        assert list(np.asarray(iters)) == [budget] * X.shape[0]
+        if solver != "tron":  # which evaluates once an iteration
+            assert len(set(np.asarray(evals).tolist())) > 1  # unequal lanes
+        assert int(rounds[0]) == ran["vg"]
+
+    @pytest.mark.parametrize("solver", sorted(SOLVERS))
     def test_compacted_chunks_count_what_one_dispatch_counts(self, rng,
                                                              solver):
         data, *_ = make_game_data(rng, n=500, n_entities=16)
