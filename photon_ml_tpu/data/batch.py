@@ -12,15 +12,21 @@ Two layouts:
 - :class:`DenseBatch` — features as a dense ``[N, D]`` matrix. Right for
   narrow-to-medium feature spaces (the reference densifies per-entity blocks
   the same way after projection).
-- :class:`EllBatch`  — padded row-sparse (ELL) layout: ``indices``/``values``
-  of shape ``[N, K]`` with ``K`` = max nnz per row, padded entries pointing at
-  a dummy column with value 0. Margins via gather + row-sum; gradients via
-  scatter-add (segment-sum). Right for wide sparse spaces (reference policy
-  switches representation around 200k features; SURVEY §7 hard-part 5).
+- :class:`EllBatch`  — padded row-sparse (ELL) layout, held **slot-major**
+  on the device: ``indices``/``values`` of shape ``[K, N]`` with ``K`` = max
+  nnz per row, padded entries pointing at a dummy column with value 0.
+  Margins via gather + sum over the slots; gradients via scatter-add
+  (segment-sum). Right for wide sparse spaces (reference policy switches
+  representation around 200k features; SURVEY §7 hard-part 5). Slot-major
+  because a TPU tiles a 32-bit array (8, 128) over its two minor
+  dimensions: a solver loop re-lays row-major ``[N, 39]`` planes with every
+  row padded to 128 lanes, 3.3x their bytes, where ``[39, N]`` pads 39 to
+  40 sublanes (PERF.md, PR 29).
 
 Both carry ``labels``, ``offsets``, ``weights`` (length N) and are registered
 pytrees so they cross ``jit``/``pjit`` boundaries and shard over the mesh data
-axis.
+axis (:func:`row_partition_specs` says which axis of each leaf holds the
+rows).
 """
 
 from __future__ import annotations
@@ -30,8 +36,16 @@ from typing import NamedTuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 Array = jnp.ndarray
+
+
+# ``jax.named_scope``s of the two halves of a pass over the rows, in both
+# layouts' methods: metadata on the device's operations, under
+# ``objective.value_and_grad`` / ``objective.hvp`` (ops/aggregators.py).
+MARGINS_SCOPE = "objective.margins"
+FEATURE_SUM_SCOPE = "objective.feature_sum"
 
 
 class DenseBatch(NamedTuple):
@@ -56,31 +70,48 @@ class DenseBatch(NamedTuple):
 
     def margins(self, w_eff: Array, margin_shift: Array) -> Array:
         """x_i . w_eff + margin_shift + offset_i, batched on the MXU."""
-        return (
-            jnp.einsum(
-                "nd,d->n", self.X, w_eff, preferred_element_type=self.acc_dtype
+        with jax.named_scope(MARGINS_SCOPE):
+            return (
+                jnp.einsum(
+                    "nd,d->n", self.X, w_eff,
+                    preferred_element_type=self.acc_dtype
+                )
+                + margin_shift
+                + self.offsets
             )
-            + margin_shift
-            + self.offsets
-        )
 
     def weighted_feature_sum(self, row_scalars: Array) -> Array:
         """sum_i row_scalars_i * x_i — the gradient's vector sum (X^T r)."""
-        return jnp.einsum(
-            "nd,n->d", self.X, row_scalars, preferred_element_type=self.acc_dtype
-        )
+        with jax.named_scope(FEATURE_SUM_SCOPE):
+            return jnp.einsum(
+                "nd,n->d", self.X, row_scalars,
+                preferred_element_type=self.acc_dtype
+            )
 
     def hadamard_square_sum(self, row_scalars: Array) -> Array:
         """sum_i row_scalars_i * x_i**2 — Hessian-diagonal inner sum."""
-        return jnp.einsum(
-            "nd,n->d", self.X * self.X, row_scalars,
-            preferred_element_type=self.acc_dtype,
-        )
+        with jax.named_scope(FEATURE_SUM_SCOPE):
+            return jnp.einsum(
+                "nd,n->d", self.X * self.X, row_scalars,
+                preferred_element_type=self.acc_dtype,
+            )
 
 
 @jax.tree_util.register_pytree_node_class
 class EllBatch:
-    """Padded row-sparse (ELL) design matrix.
+    """Padded row-sparse (ELL) design matrix, slot-major on the device.
+
+    ``indices`` and ``values`` are ``[K, N]``: slot ``k`` of every row lies
+    contiguous, the rows on the minor (lane) axis. A TPU tiles a 32-bit
+    array (8, 128) over its two minor dimensions. A stored ``[N, K]`` array
+    is not padded, but inside a solver's ``while`` loop XLA re-lays such
+    planes row-major tiled, K = 39 padded to 128 lanes: the L-BFGS program
+    over 11.5M rows asked for 21.5 GB of a 16 GB chip and did not compile,
+    where ``[K, N]`` pads 39 to 40 sublanes, runs at a peak of 3.9 GB, and
+    sums over the slots as K row vectors (the chip's readings: PERF.md,
+    PR 29). Build one from host data with :func:`ell_from_csr` /
+    :func:`ell_from_rows`, or from arrays already in this layout with
+    :func:`ell_batch`.
 
     Padded slots must satisfy ``values == 0`` (their index value is then
     irrelevant for margins; for scatter ops we still route them to a real
@@ -93,8 +124,8 @@ class EllBatch:
 
     def __init__(self, indices: Array, values: Array, labels: Array,
                  offsets: Array, weights: Array, dim: int):
-        self.indices = indices  # [N, K] int32
-        self.values = values  # [N, K]
+        self.indices = indices  # [K, N] int32
+        self.values = values  # [K, N]
         self.labels = labels  # [N]
         self.offsets = offsets  # [N]
         self.weights = weights  # [N]
@@ -125,22 +156,29 @@ class EllBatch:
         return jnp.promote_types(self.values.dtype, jnp.float32)
 
     def margins(self, w_eff: Array, margin_shift: Array) -> Array:
-        gathered = w_eff[self.indices]  # [N, K]
-        return (
-            jnp.sum(gathered * self.values, axis=-1) + margin_shift + self.offsets
-        )
+        with jax.named_scope(MARGINS_SCOPE):
+            gathered = w_eff[self.indices]  # [K, N]
+            return (
+                jnp.sum(gathered * self.values, axis=0)
+                + margin_shift
+                + self.offsets
+            )
+
+    def _column_sums(self, slot_values: Array, row_scalars: Array) -> Array:
+        """sum_i row_scalars_i * slot_values[:, i], each slot into its
+        column: the scatter-add over all K x N stored slots."""
+        with jax.named_scope(FEATURE_SUM_SCOPE):
+            contrib = slot_values * row_scalars[None, :]  # [K, N]
+            return jax.ops.segment_sum(
+                contrib.reshape(-1), self.indices.reshape(-1),
+                num_segments=self.dim,
+            )
 
     def weighted_feature_sum(self, row_scalars: Array) -> Array:
-        contrib = self.values * row_scalars[:, None]  # [N, K]
-        return jax.ops.segment_sum(
-            contrib.reshape(-1), self.indices.reshape(-1), num_segments=self.dim
-        )
+        return self._column_sums(self.values, row_scalars)
 
     def hadamard_square_sum(self, row_scalars: Array) -> Array:
-        contrib = (self.values * self.values) * row_scalars[:, None]
-        return jax.ops.segment_sum(
-            contrib.reshape(-1), self.indices.reshape(-1), num_segments=self.dim
-        )
+        return self._column_sums(self.values * self.values, row_scalars)
 
 
 Batch = Union[DenseBatch, EllBatch]
@@ -168,6 +206,57 @@ def dense_batch(
         if weights is None
         else jnp.asarray(weights, meta),
     )
+
+
+def ell_batch(
+    indices,
+    values,
+    labels,
+    dim: int,
+    offsets=None,
+    weights=None,
+    dtype=jnp.float32,
+) -> EllBatch:
+    """An ELL batch from planes already in the device layout: ``indices``
+    and ``values`` slot-major ``[K, N]`` (host or device arrays; device
+    arrays of the right dtype are taken as they are, no copy), ``dim``
+    columns. The caller vouches for what :func:`ell_from_csr` arranges: no
+    column twice in a row, padded slots of value 0, indices in
+    ``[0, dim)``."""
+    if indices.ndim != 2 or indices.shape != values.shape:
+        raise ValueError(
+            f"indices {indices.shape} and values {values.shape} must both "
+            "be [K, N]")
+    n = indices.shape[1]
+    if np.shape(labels) != (n,):
+        raise ValueError(
+            f"planes {indices.shape} are slot-major [K, N] and hold "
+            f"{n} rows, labels {np.shape(labels)} do not")
+    meta = jnp.promote_types(dtype, jnp.float32)
+    return EllBatch(
+        indices=jnp.asarray(indices, jnp.int32),
+        values=jnp.asarray(values, dtype),
+        labels=jnp.asarray(labels, meta),
+        offsets=jnp.zeros(n, meta)
+        if offsets is None
+        else jnp.asarray(offsets, meta),
+        weights=jnp.ones(n, meta)
+        if weights is None
+        else jnp.asarray(weights, meta),
+        dim=dim,
+    )
+
+
+def row_partition_specs(batch: "Batch", axis: str):
+    """A pytree shaped like ``batch`` of ``PartitionSpec``s that shard the
+    rows over mesh axis ``axis``: the leading axis of every leaf, but the
+    minor axis of the ELL planes."""
+    by_row = PartitionSpec(axis)
+    if isinstance(batch, EllBatch):
+        by_slot_row = PartitionSpec(None, axis)
+        return EllBatch(by_slot_row, by_slot_row, by_row, by_row, by_row,
+                        dim=batch.dim)
+    return DenseBatch(by_row, by_row, by_row, by_row)
 
 
 def canonicalized_csr(mat):
@@ -218,18 +307,10 @@ def ell_from_csr(
             slot_of = np.arange(mat.nnz) - np.repeat(indptr[:-1], lens)
             indices[row_of, slot_of] = mat.indices
             values[row_of, slot_of] = mat.data
-    return EllBatch(
-        indices=jnp.asarray(indices),
-        values=jnp.asarray(values, dtype),
-        labels=jnp.asarray(labels, meta),
-        offsets=jnp.zeros(n, meta)
-        if offsets is None
-        else jnp.asarray(offsets, meta),
-        weights=jnp.ones(n, meta)
-        if weights is None
-        else jnp.asarray(weights, meta),
-        dim=dim,
-    )
+    # host planes are packed row-major (the native packer's form); the
+    # device holds them slot-major
+    return ell_batch(indices.T, values.T, labels, dim, offsets, weights,
+                     dtype=dtype)
 
 
 def ell_from_rows(
@@ -257,18 +338,10 @@ def ell_from_rows(
     for i, (ix, v) in enumerate(rows):
         indices[i, : len(ix)] = ix
         values[i, : len(v)] = v
-    return EllBatch(
-        indices=jnp.asarray(indices),
-        values=jnp.asarray(values, dtype),
-        labels=jnp.asarray(labels, meta),
-        offsets=jnp.zeros(n, meta)
-        if offsets is None
-        else jnp.asarray(offsets, meta),
-        weights=jnp.ones(n, meta)
-        if weights is None
-        else jnp.asarray(weights, meta),
-        dim=dim,
-    )
+    # host planes are packed row-major (the native packer's form); the
+    # device holds them slot-major
+    return ell_batch(indices.T, values.T, labels, dim, offsets, weights,
+                     dtype=dtype)
 
 
 def pad_batch(batch: Batch, target_rows: int) -> Batch:
@@ -291,8 +364,8 @@ def pad_batch(batch: Batch, target_rows: int) -> Batch:
         return DenseBatch(X=jnp.pad(batch.X, ((0, pad), (0, 0))), **meta)
     # ELL: padded rows point at column 0 with value 0 — inert in every sum.
     return EllBatch(
-        indices=jnp.pad(batch.indices, ((0, pad), (0, 0))),
-        values=jnp.pad(batch.values, ((0, pad), (0, 0))),
+        indices=jnp.pad(batch.indices, ((0, 0), (0, pad))),
+        values=jnp.pad(batch.values, ((0, 0), (0, pad))),
         dim=batch.dim,
         **meta,
     )

@@ -142,21 +142,23 @@ def hessian_diagonal(
       H_jj = factors_j^2 sum_i w_i l''(z_i) (x_ij - shifts_j)^2
     expanded into three raw-feature sums so data stays untouched.
     """
-    w_eff, margin_shift = norm.effective_coefficients(coef)
-    z = batch.margins(w_eff, margin_shift)
-    r = batch.weights * loss.d2(z, batch.labels)
-    sq_sum = _maybe_psum(batch.hadamard_square_sum(r), axis_name,
-                         collective_quant)
-    if norm.shifts is None:
-        diag = sq_sum
-    else:
-        lin_sum = _maybe_psum(batch.weighted_feature_sum(r), axis_name,
-                              collective_quant)
-        scalar_sum = _maybe_psum(jnp.sum(r), axis_name, collective_quant)
-        diag = sq_sum - 2.0 * norm.shifts * lin_sum + norm.shifts**2 * scalar_sum
-    if norm.factors is not None:
-        diag = diag * norm.factors**2
-    return diag
+    with jax.named_scope("objective.hessian_diag"):
+        w_eff, margin_shift = norm.effective_coefficients(coef)
+        z = batch.margins(w_eff, margin_shift)
+        r = batch.weights * loss.d2(z, batch.labels)
+        sq_sum = _maybe_psum(batch.hadamard_square_sum(r), axis_name,
+                             collective_quant)
+        if norm.shifts is None:
+            diag = sq_sum
+        else:
+            lin_sum = _maybe_psum(batch.weighted_feature_sum(r), axis_name,
+                                  collective_quant)
+            scalar_sum = _maybe_psum(jnp.sum(r), axis_name, collective_quant)
+            diag = (sq_sum - 2.0 * norm.shifts * lin_sum
+                    + norm.shifts**2 * scalar_sum)
+        if norm.factors is not None:
+            diag = diag * norm.factors**2
+        return diag
 
 
 @jax.tree_util.register_dataclass

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from photon_ml_tpu.data.batch import Batch, pad_batch
+from photon_ml_tpu.data.batch import Batch, pad_batch, row_partition_specs
 from photon_ml_tpu.models.glm import GeneralizedLinearModel
 from photon_ml_tpu.optimize.common import OptimizationResult, solver_x0
 from photon_ml_tpu.optimize.problem import GLMOptimizationProblem
@@ -68,9 +68,10 @@ def run_glm_shard_map(
         initial: Optional[Array] = None,
 ) -> tuple[GeneralizedLinearModel, OptimizationResult]:
     """Fit ``problem`` on ``batch`` with rows explicitly sharded over the
-    mesh ``data`` axis. Works for any row-major batch layout (DenseBatch,
-    EllBatch — every array leaf has rows leading). Rows not divisible by
-    the data-axis size are padded with zero-weight rows here.
+    mesh ``data`` axis. Works for both batch layouts (DenseBatch, EllBatch:
+    ``data/batch.row_partition_specs`` names each leaf's row axis). Rows
+    not divisible by the data-axis size are padded with zero-weight rows
+    here.
 
     With ``problem.shard_weight_update`` set, the optimizer state and the
     coefficient update are additionally sharded over the SAME data axis
@@ -124,7 +125,7 @@ def sharded_fit(problem: GLMOptimizationProblem, batch: Batch, mesh,
     dim = batch.num_features
     # psum-ing objective: every reduction crosses the data axis.
     obj = dataclasses.replace(problem.objective(), axis_name=DATA_AXIS)
-    row_specs = jax.tree_util.tree_map(lambda _: P(DATA_AXIS), batch)
+    row_specs = row_partition_specs(batch, DATA_AXIS)
 
     shard_update = problem.shard_weight_update
     if shard_update and (problem.box is not None or problem.track_iterates):

@@ -30,7 +30,11 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from photon_ml_tpu.data.batch import DenseBatch, EllBatch
+from photon_ml_tpu.data.batch import (
+    DenseBatch,
+    EllBatch,
+    row_partition_specs,
+)
 
 DATA_AXIS = "data"
 ENTITY_AXIS = "entity"
@@ -131,24 +135,11 @@ def shard_batch(batch, mesh: Mesh):
         raise ValueError(
             f"batch rows {rows} not divisible by data axis {n_shards}; "
             "pad with zero-weight rows first")
-    row_sharded = NamedSharding(mesh, P(DATA_AXIS))
-    if isinstance(batch, DenseBatch):
-        return DenseBatch(
-            X=jax.device_put(batch.X, row_sharded),
-            labels=jax.device_put(batch.labels, row_sharded),
-            offsets=jax.device_put(batch.offsets, row_sharded),
-            weights=jax.device_put(batch.weights, row_sharded),
-        )
-    if isinstance(batch, EllBatch):
-        return EllBatch(
-            indices=jax.device_put(batch.indices, row_sharded),
-            values=jax.device_put(batch.values, row_sharded),
-            labels=jax.device_put(batch.labels, row_sharded),
-            offsets=jax.device_put(batch.offsets, row_sharded),
-            weights=jax.device_put(batch.weights, row_sharded),
-            dim=batch.dim,
-        )
-    raise TypeError(f"unknown batch type {type(batch)}")
+    if not isinstance(batch, (DenseBatch, EllBatch)):
+        raise TypeError(f"unknown batch type {type(batch)}")
+    return jax.tree_util.tree_map(
+        lambda leaf, spec: jax.device_put(leaf, NamedSharding(mesh, spec)),
+        batch, row_partition_specs(batch, DATA_AXIS))
 
 
 def pad_rows_to_multiple(n: int, multiple: int) -> int:

@@ -238,10 +238,11 @@ def test_duplicate_libsvm_entries_sum_in_sparse_paths(tmp_path):
     np.testing.assert_allclose(s_sparse.num_nonzeros, s_dense.num_nonzeros)
     batch = csr_to_batch(data.features.tocsr(), data.labels,
                          data.offsets, data.weights, dense_threshold=0)
-    # ELL layout: the duplicated cell occupies ONE slot with value 3.0
-    vals = np.asarray(batch.values)
-    assert 3.0 in vals[0]
-    assert np.count_nonzero(vals[0]) == 1
+    # ELL layout (slot-major [K, N]): the duplicated cell occupies ONE slot
+    # of row 0 with value 3.0
+    row0 = np.asarray(batch.values)[:, 0]
+    assert 3.0 in row0
+    assert np.count_nonzero(row0) == 1
 
 
 @requires_native

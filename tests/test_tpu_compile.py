@@ -228,3 +228,65 @@ def test_sharded_random_effect_fit_compiles(mesh):
     assert "all-reduce" not in text
     assert (compiled.memory_analysis().argument_size_in_bytes
             < 0.6 * e * n * d * 4 * 1.1)
+
+
+# the wide-sparse cell's shape (benchmark/configs/glm-sparse-criteo.json)
+CRITEO_ROWS, CRITEO_SLOTS, CRITEO_DIM = 11_468_800, 39, 1_000_000
+V5E_HBM_BYTES = 16e9
+
+
+def _ell(rows, slots, dim, row_sharding, plane_sharding=None):
+    from photon_ml_tpu.data.batch import EllBatch
+
+    plane_sharding = plane_sharding or row_sharding
+
+    def sds(shape, dt, sharding):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    row = [sds((rows,), jnp.float32, row_sharding) for _ in range(3)]
+    return EllBatch(sds((slots, rows), jnp.int32, plane_sharding),
+                    sds((slots, rows), jnp.float32, plane_sharding), *row,
+                    dim=dim)
+
+
+def test_wide_sparse_solve_fits_one_chip_slot_major(one_chip):
+    """The Criteo-shaped cell's program: L-BFGS over the slot-major ELL
+    batch, 447M stored slots and a 1M-wide solve. The [39, N] planes pad to
+    40 sublanes (2.6%); held [N, 39] the same solve asks the compiler for
+    21.5 GB (PERF.md, PR 29)."""
+    problem = _l2_problem(6, 1e-30, 10.0)
+    x0 = jax.ShapeDtypeStruct((CRITEO_DIM,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(problem.solve).lower(
+        problem.objective(),
+        _ell(CRITEO_ROWS, CRITEO_SLOTS, CRITEO_DIM, one_chip), x0).compile()
+    memory = compiled.memory_analysis()
+    planes = 2 * CRITEO_ROWS * CRITEO_SLOTS * 4
+    vectors = 3 * CRITEO_ROWS * 4 + CRITEO_DIM * 4
+    assert planes + vectors < memory.argument_size_in_bytes < (
+        1.03 * planes + 1.01 * vectors)  # 39 slots in 40 sublanes
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 0.6 * V5E_HBM_BYTES)
+    text = compiled.as_text()
+    assert "objective.margins" in text and "objective.feature_sum" in text
+
+
+def test_sharded_wide_sparse_solve_compiles(mesh, as_on_tpu_mesh):
+    """The same solve inside ``shard_map``, the planes split along their
+    row (minor) axis over the data axis of the 2x2 mesh."""
+    from photon_ml_tpu.data.batch import row_partition_specs
+    from photon_ml_tpu.parallel.distributed import sharded_fit
+
+    problem = _l2_problem(6, 1e-30, 10.0)
+    by_row = NamedSharding(mesh, P(DATA_AXIS))
+    batch = _ell(MESH_ROWS, CRITEO_SLOTS, CRITEO_DIM, by_row,
+                 NamedSharding(mesh, P(None, DATA_AXIS)))
+    specs = row_partition_specs(batch, DATA_AXIS)
+    assert specs.indices == P(None, DATA_AXIS) and specs.labels == P(DATA_AXIS)
+    x0 = jax.ShapeDtypeStruct((CRITEO_DIM,), jnp.float32,
+                              sharding=NamedSharding(mesh, P()))
+    fit, _ = sharded_fit(problem, batch, mesh, jnp.float32)
+    compiled = jax.jit(fit).lower(batch, x0).compile()
+    assert "all-reduce" in compiled.as_text()
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    planes = 2 * MESH_ROWS * CRITEO_SLOTS * 4
+    assert per_device < 0.6 * planes * 1.1 + CRITEO_DIM * 4
