@@ -15,8 +15,9 @@ Two layouts:
 - :class:`EllBatch`  — padded row-sparse (ELL) layout, held **slot-major**
   on the device: ``indices``/``values`` of shape ``[K, N]`` with ``K`` = max
   nnz per row, padded entries pointing at a dummy column with value 0.
-  Margins via gather + sum over the slots; gradients via scatter-add
-  (segment-sum). Right for wide sparse spaces (reference policy switches
+  A pass walks the slots: margins gather one slot of every row at a time
+  into an ``[N]`` accumulator, gradients scatter-add one slot at a time
+  into a ``[D]`` one. Right for wide sparse spaces (reference policy switches
   representation around 200k features; SURVEY §7 hard-part 5). Slot-major
   because a TPU tiles a 32-bit array (8, 128) over its two minor
   dimensions: a solver loop re-lays row-major ``[N, 39]`` planes with every
@@ -36,6 +37,7 @@ from typing import NamedTuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.sharding import PartitionSpec
 
 Array = jnp.ndarray
@@ -107,9 +109,15 @@ class EllBatch:
     is not padded, but inside a solver's ``while`` loop XLA re-lays such
     planes row-major tiled, K = 39 padded to 128 lanes: the L-BFGS program
     over 11.5M rows asked for 21.5 GB of a 16 GB chip and did not compile,
-    where ``[K, N]`` pads 39 to 40 sublanes, runs at a peak of 3.9 GB, and
-    sums over the slots as K row vectors (the chip's readings: PERF.md,
-    PR 29). Build one from host data with :func:`ell_from_csr` /
+    where ``[K, N]`` pads 39 to 40 sublanes and runs at a peak of 3.9 GB
+    (the chip's readings: PERF.md, PR 29). Every method walks the slots in
+    order, one ``fori_loop`` step a slot: row ``k`` of both planes (a
+    contiguous ``[N]`` vector in this layout) meets an accumulator of shape
+    ``[N]`` (margins) or ``[D]`` (column sums), so no ``[K, N]`` value
+    exists between the planes and the result. Written as whole-plane
+    expressions the same pass cost a third more at 11.5M rows: XLA
+    materialised a flat 447M-element product and summed it in a second
+    loop (PERF.md, PR 30). Build one from host data with :func:`ell_from_csr` /
     :func:`ell_from_rows`, or from arrays already in this layout with
     :func:`ell_batch`.
 
@@ -117,9 +125,9 @@ class EllBatch:
     irrelevant for margins; for scatter ops we still route them to a real
     column but the zero value contributes nothing).
 
-    ``dim`` is static pytree aux data (not a leaf): ``segment_sum`` needs a
-    concrete ``num_segments`` under jit, so crossing a jit/pjit boundary must
-    not trace it.
+    ``dim`` is static pytree aux data (not a leaf): the column sums'
+    accumulator needs a concrete length under jit, so crossing a jit/pjit
+    boundary must not trace it.
     """
 
     def __init__(self, indices: Array, values: Array, labels: Array,
@@ -157,28 +165,35 @@ class EllBatch:
 
     def margins(self, w_eff: Array, margin_shift: Array) -> Array:
         with jax.named_scope(MARGINS_SCOPE):
-            gathered = w_eff[self.indices]  # [K, N]
+            def add_slot(k, z):
+                return z + w_eff[self.indices[k]] * self.values[k]
+
+            z = jnp.zeros(self.indices.shape[1:],
+                          jnp.result_type(w_eff, self.values))
             return (
-                jnp.sum(gathered * self.values, axis=0)
+                lax.fori_loop(0, self.indices.shape[0], add_slot, z)
                 + margin_shift
                 + self.offsets
             )
 
-    def _column_sums(self, slot_values: Array, row_scalars: Array) -> Array:
-        """sum_i row_scalars_i * slot_values[:, i], each slot into its
-        column: the scatter-add over all K x N stored slots."""
+    def _column_sums(self, row_scalars: Array, square: bool) -> Array:
+        """sum_i row_scalars_i * values[:, i] (or their squares), each slot
+        into its column: the scatter-add over all K x N stored slots."""
         with jax.named_scope(FEATURE_SUM_SCOPE):
-            contrib = slot_values * row_scalars[None, :]  # [K, N]
-            return jax.ops.segment_sum(
-                contrib.reshape(-1), self.indices.reshape(-1),
-                num_segments=self.dim,
-            )
+            def add_slot(k, sums):
+                v = self.values[k]
+                return sums.at[self.indices[k]].add(
+                    (v * v if square else v) * row_scalars)
+
+            sums = jnp.zeros((self.dim,),
+                             jnp.result_type(self.values, row_scalars))
+            return lax.fori_loop(0, self.indices.shape[0], add_slot, sums)
 
     def weighted_feature_sum(self, row_scalars: Array) -> Array:
-        return self._column_sums(self.values, row_scalars)
+        return self._column_sums(row_scalars, square=False)
 
     def hadamard_square_sum(self, row_scalars: Array) -> Array:
-        return self._column_sums(self.values * self.values, row_scalars)
+        return self._column_sums(row_scalars, square=True)
 
 
 Batch = Union[DenseBatch, EllBatch]

@@ -24,6 +24,7 @@ from photon_ml_tpu.ops import losses
 from photon_ml_tpu.ops.aggregators import GLMObjective
 from photon_ml_tpu.optimize.config import OptimizerType, TaskType
 from photon_ml_tpu.training import train_glm_grid
+from test_linesearch import _sites
 
 LOSSES = [losses.logistic_loss, losses.squared_loss, losses.poisson_loss]
 
@@ -213,3 +214,49 @@ def test_the_row_sharded_ell_fit_equals_one_device(rng, shard_update):
     for a, b in zip(jax.jit(obj.calculate)(w, placed),
                     obj.calculate(w, batch)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-9)
+
+
+@pytest.mark.parametrize("slots", [1, 5, 39])
+def test_the_slot_walk_equals_a_float64_dense_pass(rng, slots):
+    """``margins``, ``weighted_feature_sum`` and ``hadamard_square_sum``
+    carry one accumulator through the K slots; each against the dense
+    float64 sum over the same rows, with padded slots and one column that
+    most rows hold. Under ``vmap`` over a stack of coefficient vectors the
+    walk is still one gather a slot."""
+    n, d, stack = 257, 64, 3
+    X = np.zeros((n, d))
+    for i in range(n):
+        stored = rng.integers(0, slots + 1)  # rows of 0..K stored slots
+        cols = rng.choice(np.arange(1, d), size=stored, replace=False)
+        if stored and rng.random() < 0.8:
+            cols[0] = 0  # the heavy column
+        X[i, cols] = rng.normal(size=stored)
+    X[:2] = 0.0  # an empty row and a full one
+    X[1, :slots] = rng.normal(size=slots)
+    ell = ell_from_rows(_as_rows(X), d, np.zeros(n), rng.normal(size=n) * 0.1,
+                        pad_to_multiple=1)
+    assert ell.indices.shape == (slots, n)
+    assert np.count_nonzero(np.asarray(ell.values)[:, 0]) == 0  # all padding
+    X64 = X.astype(np.float32).astype(np.float64)  # what the planes hold
+    W = rng.normal(size=(stack, d)).astype(np.float32)
+    r = rng.normal(size=n).astype(np.float32)
+    shift = jnp.float32(0.25)
+    close = dict(rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(ell.margins(jnp.asarray(W[0]), shift)),
+        X64 @ W[0] + 0.25 + np.asarray(ell.offsets, np.float64), **close)
+    np.testing.assert_allclose(
+        np.asarray(ell.weighted_feature_sum(jnp.asarray(r))), X64.T @ r,
+        **close)
+    np.testing.assert_allclose(
+        np.asarray(ell.hadamard_square_sum(jnp.asarray(r))),
+        (X64 * X64).T @ r, **close)
+
+    def stacked(W):
+        return jax.vmap(lambda w: ell.margins(w, shift))(W)
+
+    np.testing.assert_array_equal(
+        np.asarray(stacked(jnp.asarray(W))),
+        np.stack([np.asarray(ell.margins(jnp.asarray(w), shift)) for w in W]))
+    jaxpr = jax.make_jaxpr(stacked)(jnp.asarray(W)).jaxpr
+    assert _sites(jaxpr, "gather") == [("scan",)]  # the loop's body, not L

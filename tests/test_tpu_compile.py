@@ -23,7 +23,9 @@ it (monkeypatch), the program has no option for it.
 
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -249,16 +251,20 @@ def _ell(rows, slots, dim, row_sharding, plane_sharding=None):
                     dim=dim)
 
 
-def test_wide_sparse_solve_fits_one_chip_slot_major(one_chip):
-    """The Criteo-shaped cell's program: L-BFGS over the slot-major ELL
-    batch, 447M stored slots and a 1M-wide solve. The [39, N] planes pad to
-    40 sublanes (2.6%); held [N, 39] the same solve asks the compiler for
-    21.5 GB (PERF.md, PR 29)."""
+def _criteo_solve(one_chip):
+    """The Criteo-shaped cell's program, compiled: L-BFGS over the
+    slot-major ELL batch, 447M stored slots and a 1M-wide solve."""
     problem = _l2_problem(6, 1e-30, 10.0)
     x0 = jax.ShapeDtypeStruct((CRITEO_DIM,), jnp.float32, sharding=one_chip)
-    compiled = jax.jit(problem.solve).lower(
+    return jax.jit(problem.solve).lower(
         problem.objective(),
         _ell(CRITEO_ROWS, CRITEO_SLOTS, CRITEO_DIM, one_chip), x0).compile()
+
+
+def test_wide_sparse_solve_fits_one_chip_slot_major(one_chip):
+    """The [39, N] planes pad to 40 sublanes (2.6%); held [N, 39] the same
+    solve asks the compiler for 21.5 GB (PERF.md, PR 29)."""
+    compiled = _criteo_solve(one_chip)
     memory = compiled.memory_analysis()
     planes = 2 * CRITEO_ROWS * CRITEO_SLOTS * 4
     vectors = 3 * CRITEO_ROWS * 4 + CRITEO_DIM * 4
@@ -268,6 +274,28 @@ def test_wide_sparse_solve_fits_one_chip_slot_major(one_chip):
             < 0.6 * V5E_HBM_BYTES)
     text = compiled.as_text()
     assert "objective.margins" in text and "objective.feature_sum" in text
+
+
+# "%name = f32[39,11468800]{layout} opcode(": an instruction that makes one
+# array (a tuple's shape starts with a bracket and is skipped)
+_HLO_ARRAY = re.compile(
+    r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(", re.MULTILINE)
+
+
+def test_wide_sparse_solve_makes_no_plane_sized_temporary(one_chip):
+    """The pass walks the slots with an [N] or [D] accumulator: between the
+    argument planes and the results no instruction of the optimised program
+    makes an array of K x N elements (the parent's gather wrote a flat
+    f32[447283200] and its temporaries were 3.85 GB; PERF.md, PR 30). The
+    planes themselves only pass through the loops' tuples."""
+    compiled = _criteo_solve(one_chip)
+    plane = CRITEO_ROWS * CRITEO_SLOTS
+    made = [(dims, opcode)
+            for dims, opcode in _HLO_ARRAY.findall(compiled.as_text())
+            if math.prod(int(d) for d in dims.split(",") if d) >= plane
+            and opcode not in ("parameter", "get-tuple-element")]
+    assert made == []
+    assert compiled.memory_analysis().temp_size_in_bytes < plane * 4
 
 
 def test_sharded_wide_sparse_solve_compiles(mesh, as_on_tpu_mesh):
