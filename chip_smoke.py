@@ -65,11 +65,12 @@ if REPO not in sys.path:  # imported (tests) rather than run as a script
 
 SEED = 22
 
-# Phase 1: the shape bench.py's headline and the kernel's own comment name.
+# Phase 1: the width the kernel's own comment and the benchmark's dense
+# configuration (benchmark/configs/glm-dense-2048.json) name.
 GLM_ROWS, GLM_DIM = 262144, 2048
 GLM_LAMBDAS = (10.0, 1.0, 0.1)
 
-# Phase 2: the shape bench_glmix names (MovieLens-1M's). Widths are never
+# Phase 2: MovieLens-1M's shape. Widths are never
 # cut; ``rows`` is the only size a run may reduce, and never below
 # GLMIX_MIN_ROWS (the fused kernel must engage on the fixed effect, also
 # per data shard of the four-chip mesh).
@@ -147,7 +148,7 @@ class SmokeFailure(Exception):
 
 def glmix_data(rows: int, seed: int, users: int, items: int,
                d_global: int) -> dict:
-    """MovieLens-shaped GLMix rows (bench.py's recipe): power-law users,
+    """MovieLens-shaped GLMix rows: power-law users,
     uniform items, dense global features, a one-hot item feature per row."""
     rng = np.random.default_rng(seed)
     user = (rng.zipf(1.3, size=rows) % users).astype(np.int64)

@@ -52,8 +52,8 @@ collectives checked everywhere.
   set, writer field sets vs kind-pinned reader accesses.
 - **WBxx telemetry-taxonomy drift** — metric/span names emitted vs the
   README taxonomy tables vs every consumer (``photon_status``,
-  ``bench.py``, trace tools, chaos assertions — loaded as auxiliary
-  modules), plus label-key drift between emit sites sharing a name.
+  trace tools, chaos assertions — loaded as auxiliary modules), plus
+  label-key drift between emit sites sharing a name.
 
 Entry points: :func:`photon_ml_tpu.analysis.runner.lint` (library) and
 ``tools/photonlint.py`` (CLI). Per-line suppressions use

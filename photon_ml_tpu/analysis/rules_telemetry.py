@@ -5,8 +5,8 @@ The observability plane is held together by names: every
 ``trace.span("...")`` / ``trace.record_span("...")``, the README
 taxonomy tables operators read, and
 the consumers that aggregate the stream (``tools/photon_status.py``,
-``bench.py``, ``tools/trace_report.py``, ``tools/trace_diff.py``, the
-chaos drill's assertions). A renamed counter breaks the dashboard
+``tools/trace_report.py``, ``tools/trace_diff.py``, the chaos drill's
+assertions). A renamed counter breaks the dashboard
 silently: the emit side keeps counting, the consumer reads ``None``
 forever. These rules reconcile the three corners:
 
@@ -36,7 +36,7 @@ forever. These rules reconcile the three corners:
 
 Reconciliation against the README only runs when the relevant table
 exists (fixture runs pass READMEs without them). Consumer files that
-are not part of the lint path set (``tools/``, ``bench.py``) are
+are not part of the lint path set (``tools/``) are
 loaded as *auxiliary* modules by the runner — they are scanned for
 reads and honor inline suppressions, but no other family lints them.
 

@@ -6,7 +6,7 @@ deterministic: findings sort by (path, line, col, rule).
 
 Two kinds of extra inputs ride along with the package modules:
 
-- **auxiliary consumer modules** (``bench.py``, ``tools/…``) are loaded
+- **auxiliary consumer modules** (``tools/…``) are loaded
   for the WB telemetry-consumer scan only — they honor inline
   suppressions but are not linted by any other family;
 - an optional **incremental cache** (``cache_dir=…``): per-file
@@ -52,7 +52,6 @@ RULE_MODULES = {
 # Loaded (when present) so WB03 sees the reads that actually power the
 # dashboards; every other family ignores them.
 AUX_CONSUMER_FILES = (
-    "bench.py",
     "tools/photon_status.py",
     "tools/trace_report.py",
     "tools/trace_diff.py",

@@ -88,31 +88,23 @@ class CoordinateDivergenceError(RuntimeError):
     """A coordinate update produced a non-finite state or objective."""
 
 
-# Hot-loop sync telemetry for bench.py / the transfer-guard test: the
-# one-round-trip contract says every non-validation coordinate update
-# performs AT MOST ONE blocking device→host fetch (the fused epilogue's
-# small scalar pytree; a block of B updates shares ONE fetch, so the
-# amortized rate is 1/B). ``update_dispatch_secs`` is host time spent
-# dispatching the update + epilogue (async), ``epilogue_wait_secs`` the
-# blocking wait inside the single fetch. The pipelining keys:
+# Hot-loop schedule counts, the seam the transfer-guard and pipelining
+# tests observe the sweep through: the one-round-trip contract says every
+# non-validation coordinate update performs AT MOST ONE blocking
+# device→host fetch (the fused epilogue's small scalar pytree; a block of
+# B updates shares ONE fetch, so the amortized rate is 1/B).
 # ``max_inflight`` is the most dispatched-but-unfetched updates alive at
-# once (2 with double-buffering at block size 1), ``pipelined_resolves``
-# counts fetches that happened AFTER a later dispatch had already been
-# issued, and ``overlap_secs`` is the host time that elapsed between a
-# block's dispatch completing and its fetch starting — work the host did
-# while the device was still computing, i.e. the hidden dispatch cost.
+# once (2 with double-buffering at block size 1) and
+# ``pipelined_resolves`` counts fetches that happened AFTER a later
+# dispatch had already been issued. The intervals themselves are the
+# ``cd.dispatch`` / ``cd.pipeline_wait`` / ``cd.epilogue_fetch`` spans.
 HOT_LOOP_STATS = {"updates": 0, "epilogue_fetches": 0,
-                  "update_dispatch_secs": 0.0, "epilogue_wait_secs": 0.0,
-                  "max_inflight": 0, "pipelined_resolves": 0,
-                  "overlap_secs": 0.0}
+                  "max_inflight": 0, "pipelined_resolves": 0}
 
 
 def reset_hot_loop_stats() -> None:
     HOT_LOOP_STATS.update({"updates": 0, "epilogue_fetches": 0,
-                           "update_dispatch_secs": 0.0,
-                           "epilogue_wait_secs": 0.0,
-                           "max_inflight": 0, "pipelined_resolves": 0,
-                           "overlap_secs": 0.0})
+                           "max_inflight": 0, "pipelined_resolves": 0})
 
 
 def _sample_live_bytes(sweep: int) -> None:
@@ -183,7 +175,6 @@ class _InFlight:
     # boundary, or a resumed run would re-partition the sweep's blocks
     snapshot_next_ci: int
     t_wall: float
-    t_dispatched: float
     # serial of the dispatch within this run: the ``update`` label that
     # joins the block's cd.dispatch span to its later cd.pipeline_wait /
     # cd.epilogue_fetch (a pipelined block is fetched one dispatch later)
@@ -204,7 +195,7 @@ def _canonical_sum(score_list, num_samples: int):
 @functools.lru_cache(maxsize=32)
 def _canonical_total_jit(num_samples: int):
     """Jitted canonical summation, cached per sample count so repeated
-    runs (and the warm bench pass) reuse the executable."""
+    runs reuse the executable."""
     return jax.jit(lambda score_list: _canonical_sum(score_list,
                                                      num_samples))
 
@@ -243,7 +234,7 @@ def make_update_epilogue(task: TaskType, num_samples: int):
     """
     # this body runs only on an lru_cache MISS — i.e. a new (task, N)
     # shape is about to pay an XLA compile; the counter makes retrace
-    # regressions visible in metrics.jsonl and the bench record
+    # regressions visible in metrics.jsonl
     REGISTRY.counter("retraces").inc(site="cd.epilogue")
     loss = get_loss(TASK_LOSS_NAME[task])
 
@@ -704,7 +695,6 @@ def run_coordinate_descent(
         the seeded ladder treats it as attempt 0, exactly like the
         sequential retry loop."""
         t_wall = time.time()
-        t0 = time.perf_counter()
         counts_before = {
             cid: getattr(coordinates[cid], "_update_count", None)
             for _, cid in block}
@@ -757,7 +747,6 @@ def run_coordinate_descent(
                     if before is not None:
                         coordinates[cid]._update_count = before
             raise
-        HOT_LOOP_STATS["update_dispatch_secs"] += time.perf_counter() - t0
         return _InFlight(
             it=it, block=list(block), attempt=attempt, cands=cands,
             trackers=trackers, new_scores=new_scores, new_regs=new_regs,
@@ -767,7 +756,7 @@ def run_coordinate_descent(
             update_counts_before=counts_before,
             snapshot_due=snapshot_due,
             snapshot_next_ci=snapshot_next_ci,
-            t_wall=t_wall, t_dispatched=time.perf_counter(), update=update)
+            t_wall=t_wall, update=update)
 
     def _set_update_counts(block, counts):
         for _, cid in block:
@@ -791,11 +780,8 @@ def run_coordinate_descent(
         epilogue's scalar pytree for the whole block. Raises
         :class:`CoordinateDivergenceError` (recovery mode only) when the
         block's states/objective are non-finite."""
-        t0 = time.perf_counter()
         if p.pipelined:
             HOT_LOOP_STATS["pipelined_resolves"] += 1
-            HOT_LOOP_STATS["overlap_secs"] += max(0.0,
-                                                  t0 - p.t_dispatched)
         span_labels = {"sweep": p.it, "update": p.update}
         if len(p.block) == 1:
             span_labels["coordinate"] = p.block[0][1]
@@ -814,7 +800,6 @@ def run_coordinate_descent(
                 (p.objective_d, p.train_loss_d, p.finite_d,
                  p.state_finite_d))
         record_host_fetch(site="cd.epilogue")
-        HOT_LOOP_STATS["epilogue_wait_secs"] += time.perf_counter() - t0
         HOT_LOOP_STATS["epilogue_fetches"] += 1
         HOT_LOOP_STATS["updates"] += len(p.block)
         objective = float(objective)

@@ -1,8 +1,8 @@
 """Observability layer: span tracing, labeled metrics, run manifests.
 
-One subsystem replacing three disjoint fragments (the bench-only
-wall-clock splits, the single process-global fetch counter, the
-log-only event bus):
+One subsystem in place of three disjoint fragments (wall-clock splits
+kept in module-level dicts, the single process-global fetch counter,
+the log-only event bus):
 
 - ``obs.trace`` — thread-safe nestable span tracer
   (``trace.span("cd.update", coordinate=cid)``), exported as Chrome

@@ -31,10 +31,11 @@ when armed via ``--device-telemetry``:
   (measured: indistinguishable from jit's C++ fastpath), with the
   site's declared static positions stripped from the argument list.
 
-Armed overhead is gated by the same <2% warm-pass contract as span
-tracing (tests/test_obs_device.py); the signature walk is metadata-only
-(``shape``/``dtype`` attributes, never values), so the armed path adds
-zero device syncs and stays green under the transfer-guard test.
+The signature walk is metadata-only (``shape``/``dtype`` attributes,
+never values), so the armed path adds zero device syncs and stays green
+under the transfer-guard test, and what it records is a fixed function
+of updates, not of solver iterations (tests/test_obs_device.py); its
+share of a sweep on the chip is in PERF.md section 6 (PR 27).
 
 Every AOT step is CONTAINED: a function the AOT API cannot lower (or an
 executable whose calling convention surprises us) permanently falls the
@@ -53,7 +54,7 @@ from photon_ml_tpu.obs.metrics import REGISTRY, MetricsRegistry
 _ARMED = False
 _REGISTRY: MetricsRegistry = REGISTRY
 
-#: site -> _Site; module-level so repeated runs (the warm bench pass)
+#: site -> _Site; module-level so repeated runs (a warm pass)
 #: reuse compiled executables exactly like jit's dispatch cache would.
 _SITES: dict[str, "_Site"] = {}
 

@@ -238,7 +238,7 @@ class MetricsRegistry:
         return out
 
     def reset(self) -> None:
-        """Zero every metric (bench/test isolation; registrations stay)."""
+        """Zero every metric (test isolation; registrations stay)."""
         with self._lock:
             metrics = list(self._metrics.values())
         for m in metrics:

@@ -115,7 +115,7 @@ class _Span:
         return False
 
 
-#: Buffer backstop for a tracer nobody drains (bench, tests, ad-hoc
+#: Buffer backstop for a tracer nobody drains (tests, ad-hoc
 #: ``trace.enable()``): past this many buffered spans new ones are
 #: dropped (and counted on ``spans_dropped``) instead of growing host
 #: RAM without bound. An ObservedRun never gets near it — its heartbeat

@@ -6,7 +6,7 @@ a socket, sustained:
 
 - :mod:`photon_ml_tpu.serve.protocol` — versioned NDJSON request
   protocol over TCP/unix sockets (same endpoint grammar as the
-  telemetry plane) plus the blocking client used by tests and bench.
+  telemetry plane) plus the blocking client used by tests and drills.
 - :mod:`photon_ml_tpu.serve.batcher` — bounded request queue feeding an
   adaptive micro-batcher; overload sheds (counted on
   ``serve_shed{reason}``), never blocks the device loop.

@@ -13,7 +13,7 @@ Batches are padded to power-of-two row buckets (:func:`bucket_rows` —
 the lane-compaction pad convention from ``game/random_effect.py``) so
 the device loop presents XLA a handful of stable shapes: one compile
 per bucket at warmup, zero retraces after (asserted through the
-``obs/compile`` attribution layer in tests and the bench probe).
+``obs/compile`` attribution layer in tests).
 """
 
 from __future__ import annotations

@@ -236,7 +236,7 @@ def swap_response(request_id, outcome: str, generation: int,
 
 
 class ServeClient:
-    """Blocking convenience client (tests, bench, chaos drills).
+    """Blocking convenience client (tests, chaos drills).
 
     One request in flight at a time; responses are matched by arrival
     order, which the single-connection protocol guarantees. Connecting

@@ -23,8 +23,8 @@ The plumbing behind wire-propagated distributed tracing
   span *emission* is sampled).
 
 Everything here is stdlib-only and lock-cheap: nothing on this path may
-add request latency beyond a couple of dict ops (the <2% armed-overhead
-contract bench.py asserts).
+add request latency beyond a couple of dict ops (its share of a request
+on the chip: not measured).
 """
 
 from __future__ import annotations

@@ -190,6 +190,9 @@ class ServeService:
         self._warn = warn or (lambda msg: None)
         self._lock = threading.Lock()
         self._conns: set[socket.socket] = set()
+        # the socket front's threads, so that shutdown() can end them
+        self._accept_thread: Optional[threading.Thread] = None
+        self._conn_threads: list[threading.Thread] = []
         self._closed = False
         self._started_at = time.monotonic()
         self._latencies_ms: list[float] = []
@@ -250,10 +253,9 @@ class ServeService:
     # -- socket front (accept + reader threads) -------------------------
 
     def start(self) -> None:
-        # daemonic and never joined — no reference kept (an always-on
-        # service must not grow a Thread object per accepted connection)
-        threading.Thread(target=self._accept_loop,
-                         name="serve-accept", daemon=True).start()
+        self._accept_thread = threading.Thread(
+            target=self._accept_loop, name="serve-accept", daemon=True)
+        self._accept_thread.start()
 
     def _accept_loop(self) -> None:
         while not self._closed:
@@ -268,8 +270,14 @@ class ServeService:
                     conn.close()
                     return
                 self._conns.add(conn)
-            threading.Thread(target=self._conn_loop, args=(conn,),
-                             name="serve-conn", daemon=True).start()
+                reader = threading.Thread(
+                    target=self._conn_loop, args=(conn,),
+                    name="serve-conn", daemon=True)
+                # pruned here, so an always-on service holds a Thread
+                # object per LIVE connection and no more
+                self._conn_threads = [t for t in self._conn_threads
+                                      if t.is_alive()] + [reader]
+            reader.start()
 
     def _conn_loop(self, conn: socket.socket) -> None:
         wlock = threading.Lock()
@@ -884,6 +892,20 @@ class ServeService:
                 conn.close()
             except OSError:
                 pass
+        # The front's threads hold this service (their target is its bound
+        # method). One that outlived main() was the last holder, and tore
+        # the model's device arrays down inside jaxlib while the
+        # interpreter finalized: CPython exits a thread that asks for the
+        # GIL then, jaxlib swallows the forced unwind, and a drained
+        # service died of SIGABRT where it owed rc 0 (4 of 40 runs on a
+        # loaded host, PR 31). They end here: the accept loop within its
+        # listener's 0.2 s poll, a reader as its connection closes.
+        if self._accept_thread is not None:
+            self._accept_thread.join(timeout=2.0)
+        with self._lock:
+            readers = list(self._conn_threads)
+        for reader in readers:
+            reader.join(timeout=2.0)
 
 
 # ---------------------------------------------------------------------------

@@ -139,8 +139,8 @@ class StopController:
 
     def uninstall_signal_handlers(self) -> None:
         """Restore the dispositions saved by
-        :meth:`install_signal_handlers` (tests and the bench probe run
-        controllers in-process, back to back)."""
+        :meth:`install_signal_handlers` (tests run controllers
+        in-process, back to back)."""
         while self._prev_handlers:
             signum, prev = self._prev_handlers.popitem()
             signal.signal(signum, prev)

@@ -4,18 +4,18 @@ Every INTENTIONAL blocking device→host read in the training path — the CD
 fused-epilogue fetch, a lazy tracker/optimizer-history materialization,
 the lane-compaction unconverged-mask fetch, a checkpoint snapshot's
 payload fetch — calls :func:`record_host_fetch` next to its
-``jax.device_get``, tagging WHERE with ``site=...``. bench.py divides the
-count over a warm run by the number of coordinate updates to report
-``host_syncs_per_update``: 1.0 means the one-round-trip contract held,
-and a lazy-materialization regression (e.g. a tracker forced inside the
-hot loop) shows up as > 1.0 in the very next BENCH record — with the
-per-site breakdown (:func:`host_fetches_by_site`) naming the culprit.
+``jax.device_get``, tagging WHERE with ``site=...``. The count over a
+warm run divided by the number of coordinate updates is
+``host_syncs_per_update`` (``tools/photon_status.py`` shows it live):
+1.0 means the one-round-trip contract held, and a lazy-materialization
+regression (e.g. a tracker forced inside the hot loop) shows up as
+> 1.0 — with the per-site breakdown (:func:`host_fetches_by_site`)
+naming the culprit.
 
 Since the observability layer landed this module is a thin shim over the
 labeled ``host_fetches`` counter in ``photon_ml_tpu.obs.metrics.REGISTRY``
 (one storage, two views): :func:`host_fetch_count` is the label-sum, so
-bench.py and the transfer-guard tests keep their exact legacy contract
-while ``metrics.jsonl`` gets per-site attribution for free. Third-party
+the transfer-guard tests keep their exact legacy contract while ``metrics.jsonl`` gets per-site attribution for free. Third-party
 callers that never pass ``site`` land under ``"unlabeled"``.
 
 This counts the *instrumented* sites only. A raw ``float()``/

@@ -166,7 +166,6 @@ class TestDoubleBufferingParity:
         run_cd(data, iters=2, pipeline_depth=1)
         assert cd.HOT_LOOP_STATS["max_inflight"] >= 2
         assert cd.HOT_LOOP_STATS["pipelined_resolves"] >= 1
-        assert cd.HOT_LOOP_STATS["overlap_secs"] >= 0.0
         assert (cd.HOT_LOOP_STATS["epilogue_fetches"]
                 == cd.HOT_LOOP_STATS["updates"])
         assert REGISTRY.gauge("cd_inflight_updates").total() >= 2

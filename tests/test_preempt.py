@@ -454,19 +454,25 @@ def test_supervisor_stall_kills_and_relaunches(driver_fixture,
     """A run wedged inside an update (scripted 300 s hang) never reaches
     a barrier: the stall heartbeat flags it, the supervisor SIGTERMs,
     escalates to SIGKILL when the graceful window lapses, and the
-    relaunch (hang spec already consumed) completes the run."""
+    relaunch (hang spec already consumed) completes the run.
+
+    The stall window is 20 s against the 300 s hang: a healthy child
+    holds one span open for 2.8 s on an idle host while its first solve
+    compiles, and a 3 s window called that a stall on a host that six
+    test workers share, relaunch after relaunch, until the restart
+    budget was spent."""
     out = str(tmp_path / "out")
     args = chaos.driver_args(
         driver_fixture["data_dir"], driver_fixture["fs_dir"], out,
         str(tmp_path / "ckpt"), str(tmp_path / "trace"))
-    args += ["--trace-stall-seconds", "3"]
+    args += ["--trace-stall-seconds", "20"]
     proc = _supervise(args, {
         "PHOTON_FAULTS": "cd.update@0.0=delay:1:300",
         "PHOTON_FAULTS_STATE_DIR": str(tmp_path / "fault_state"),
         "PHOTON_FAULTS_SEED": "42",
     }, ["--max-restarts", "4", "--backoff-base", "0.05",
         "--backoff-max", "0.2", "--poll-seconds", "0.3",
-        "--grace-seconds", "2", "--startup-grace-seconds", "6"])
+        "--grace-seconds", "2", "--startup-grace-seconds", "20"])
     assert proc.returncode == 0, \
         f"{proc.stdout}\n{proc.stderr[-3000:]}"
     assert "PHOTON_SUPERVISE stall_kill" in proc.stdout

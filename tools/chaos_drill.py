@@ -36,8 +36,10 @@ Usage::
     python tools/chaos_drill.py [--workdir DIR] [--smoke]
                                 [--points P1,P2] [--report PATH]
 
-``--smoke`` runs the curated tier-1 subset (< 60 s); the full campaign
-covers every (point, mode) cell. Emits ``chaos_report.json`` and exits
+``--smoke`` runs the curated tier-1 subset: the reference run, the six
+cells ``tests/test_chaos_drill.py`` reads (one per invariant class) and
+the corrupt-shard scenario, nine driver children one after another; the
+full campaign covers every (point, mode) cell. Emits ``chaos_report.json`` and exits
 0 on an all-green matrix, 2 otherwise (``CHAOS_OK`` / ``CHAOS_FAIL``).
 """
 
@@ -57,6 +59,10 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
+
+#: The serve cells bind unix sockets under ``<workdir>/cells/<cell>/``,
+#: and an AF_UNIX path holds 107 bytes; this is the longest tail.
+_LONGEST_SOCKET_TAIL = "cells/serve_telemetry_dead_consumer/router.sock"
 
 KILL_EXIT = 19
 CLEAN_ABORT_EXIT = 3
@@ -199,7 +205,7 @@ def build_cells(smoke: bool) -> list[CellDef]:
     cells = [
         # --- I/O layer: retry → quarantine → coverage budget ----------
         cell("io.shard_open", "io_error", "io.shard_open=io_error:1",
-             "ok", smoke_cell=True, note="one transient EIO: retried"),
+             "ok", note="one transient EIO: retried"),
         cell("io.shard_open", "flaky", "io.shard_open=flaky:999:0.7",
              "ok_or_abort",
              note="seeded flaky I/O; quarantine within or past budget"),
@@ -225,9 +231,8 @@ def build_cells(smoke: bool) -> list[CellDef]:
              note="persistently unwritable: snapshots skipped, "
                   "training continues"),
         cell("ckpt.write_bytes", "partial", "ckpt.write_bytes=partial:1",
-             "ok", smoke_cell=True,
-             note="torn write that still checksums: restore must fall "
-                  "back past it"),
+             "ok", note="torn write that still checksums: restore must "
+                        "fall back past it"),
         cell("ckpt.write_bytes", "kill",
              f"ckpt.write_bytes=kill:1:{KILL_EXIT}", "killed",
              note="killed mid-write: stale .tmp cleaned on relaunch"),
@@ -250,7 +255,7 @@ def build_cells(smoke: bool) -> list[CellDef]:
              note="chosen step corrupted pre-read → falls back"),
         # --- training loop (recovery policy armed) --------------------
         cell("cd.update", "nan", "cd.update=nan:1", "ok",
-             smoke_cell=True, note="poisoned update: damped retry"),
+             note="poisoned update: damped retry"),
         cell("cd.update", "raise", "cd.update=raise:1", "ok"),
         cell("cd.update", "kill", f"cd.update@1.0=kill:1:{KILL_EXIT}",
              "killed", smoke_cell=True,
@@ -298,7 +303,7 @@ def build_cells(smoke: bool) -> list[CellDef]:
         # --- 0 with the batches dropped+counted, the training result
         # --- bit-exact either way ------------------------------------
         cell("obs.otlp", "io_error", "obs.otlp=io_error:99", "ok",
-             smoke_cell=True, bridge=True, bit_exact=True,
+             bridge=True, bit_exact=True,
              note="OTLP POST path hard down: batches dropped, bridge "
                   "exits 0, training untouched"),
         cell("obs.otlp", "flaky", "obs.otlp=flaky:999:0.5", "ok",
@@ -388,7 +393,7 @@ def build_cells(smoke: bool) -> list[CellDef]:
                   "nothing sheds"),
         cell("serve.route", "kill",
              f"serve.route@1=kill:1:{KILL_EXIT}", "killed",
-             serve=True, variant="fleet", smoke_cell=True,
+             serve=True, variant="fleet",
              note="the no-black-hole drill: member 1 dies mid-request "
                   "under photon_supervise --fleet; every submitted "
                   "request is answered (request-id accounting — "
@@ -1952,11 +1957,20 @@ def run_campaign(workdir: str, smoke: bool,
                  report_path: str | None = None) -> int:
     from photon_ml_tpu.utils.faults import FAULT_POINTS
 
-    os.makedirs(workdir, exist_ok=True)
-    fixture = build_fixture(workdir)
     cells = build_cells(smoke)
     if points:
         cells = [c for c in cells if c["point"] in points]
+    longest = os.path.join(os.path.abspath(workdir), _LONGEST_SOCKET_TAIL)
+    n_bytes = len(os.fsencode(longest))
+    if n_bytes > 107 and any(c["serve"] for c in cells):
+        # bind() would fail in every member, restart after restart,
+        # and the cell would report a timeout minutes later
+        print(f"CHAOS_FAIL --workdir is too long for the serve cells' "
+              f"unix sockets ({n_bytes} > 107 bytes: {longest}); pass a "
+              f"shorter one", flush=True)
+        return 2
+    os.makedirs(workdir, exist_ok=True)
+    fixture = build_fixture(workdir)
     covered = {c["point"] for c in cells}
     skipped = [{"cell": f"{p}=*", "outcome": "skipped",
                 "note": "multihost-only point: needs a multiprocess "
@@ -2066,7 +2080,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workdir", default=None,
                     help="scratch dir (default: fresh tempdir)")
     ap.add_argument("--smoke", action="store_true",
-                    help="curated tier-1 subset (< 60 s)")
+                    help="curated tier-1 subset")
     ap.add_argument("--points", default="",
                     help="comma-separated fault points to restrict to")
     ap.add_argument("--report", default=None,
