@@ -6,6 +6,12 @@ ValueAndGradientAggregator.scala:34-274, HessianVectorAggregator.scala:37-163,
 HessianDiagonalAggregator.scala:97). The reference accumulates per-datum
 contributions in a Spark ``treeAggregate`` (seqOp ``add`` / combOp ``merge``);
 here each pass is a single fused matmul + reduction over the columnar batch.
+On a dense batch at a real size on a TPU a value+gradient evaluation and a
+Hessian-vector product are each ONE Pallas kernel call that reads X once
+(ops/pallas_kernels.py, one gate: ``_fused_kernels``); everywhere else, and as
+the semantics the kernel is tested against, they are the two-pass XLA bodies
+below. ``objective_lowerings{scope, form}`` counts which form each traced
+call site took.
 When the batch is sharded over a mesh data axis, XLA's GSPMD inserts the
 all-reduce that replaces ``treeAggregate`` (SURVEY §3.4, §5.8); an explicit
 ``axis_name`` is accepted for use under ``shard_map``.
@@ -25,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from photon_ml_tpu.data.batch import Batch, DenseBatch
+from photon_ml_tpu.obs.metrics import REGISTRY
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.ops.normalization import NormalizationContext
 from photon_ml_tpu.parallel.quantized_collectives import qpsum
@@ -32,27 +39,35 @@ from photon_ml_tpu.parallel.quantized_collectives import qpsum
 Array = jnp.ndarray
 
 
-def _pallas_sums(loss, w_eff, margin_shift, batch,
-                 axis_name: Optional[str]):
-    """Single-pass fused (value, vector_sum, prefactor_sum) when profitable:
-    dense f32 batch, real size, TPU backend (ops/pallas_kernels.py). Returns
-    None when the two-pass XLA form should be used instead."""
-    if not isinstance(batch, DenseBatch) or batch.X.ndim != 2:
-        return None
-    from photon_ml_tpu.ops.pallas_kernels import (
-        fused_value_gradient_sums,
-        pallas_supported,
-    )
+# A Hessian-vector product takes the fused form only on a full lane tile of
+# columns: at 10,000,054 x 65 f32 on a v5e the fused product reads 33.8 ms
+# (squared loss; logistic 53.3) where the two-pass form reads 7.8 (11.8)
+# (PERF.md, PR 32). The value+gradient form loses there too and keeps its
+# gate (ROADMAP S5).
+_HVP_MIN_FUSED_COLS = 128
 
-    n, d = batch.X.shape
-    # axis_name set => the caller runs us under shard_map (manual
-    # partitioning, per-shard shapes): safe on any device count.
-    if not pallas_supported(n, d, batch.X.dtype,
-                            inside_shard_map=axis_name is not None):
-        return None
-    return fused_value_gradient_sums(
-        loss, False, batch.X, batch.labels, batch.offsets, batch.weights,
-        w_eff, margin_shift)
+
+def _fused_kernels(scope: str, batch, axis_name: Optional[str],
+                   min_cols: int = 0):
+    """The one gate of both fused forms: ops/pallas_kernels (imported here
+    alone, so only a dense batch ever loads Pallas) where the pass under
+    ``scope`` takes the fused form, a dense batch at a real size on a TPU
+    (``pallas_supported``), else None. Books the form the pass is traced in
+    on ``objective_lowerings{scope, form}``: trace time is when the form is
+    decided, so the count can never add a device sync."""
+    kernels = None
+    if isinstance(batch, DenseBatch) and batch.X.ndim == 2:
+        from photon_ml_tpu.ops import pallas_kernels
+
+        n, d = batch.X.shape
+        # axis_name set => the caller runs us under shard_map (manual
+        # partitioning, per-shard shapes): safe on any device count.
+        if d >= min_cols and pallas_kernels.pallas_supported(
+                n, d, batch.X.dtype, inside_shard_map=axis_name is not None):
+            kernels = pallas_kernels
+    REGISTRY.counter("objective_lowerings").inc(
+        scope=scope, form="two_pass" if kernels is None else "fused")
+    return kernels
 
 
 def _maybe_psum(x, axis_name: Optional[str], quant: str = "none"):
@@ -82,9 +97,13 @@ def value_and_gradient(
     # form (fused kernel, two-pass XLA) makes it
     with jax.named_scope("objective.value_and_grad"):
         w_eff, margin_shift = norm.effective_coefficients(coef)
-        sums = _pallas_sums(loss, w_eff, margin_shift, batch, axis_name)
-        if sums is not None:
-            value, vector_sum, prefactor_sum = sums
+        kernels = _fused_kernels("objective.value_and_grad", batch,
+                                 axis_name)
+        if kernels is not None:
+            value, vector_sum, prefactor_sum = (
+                kernels.fused_value_gradient_sums(
+                    loss, False, batch.X, batch.labels, batch.offsets,
+                    batch.weights, w_eff, margin_shift))
         else:
             z = batch.margins(w_eff, margin_shift)
             l, d1 = loss.loss_and_d1(z, batch.labels)
@@ -118,13 +137,22 @@ def hessian_vector(
     with jax.named_scope("objective.hvp"):
         w_eff, margin_shift = norm.effective_coefficients(coef)
         v_eff, v_shift = norm.effective_coefficients(vector)
-        z = batch.margins(w_eff, margin_shift)
-        # zv: margin of v without data offsets (offsets are constant in w).
-        zv = batch.margins(v_eff, v_shift) - batch.offsets
-        r = batch.weights * loss.d2(z, batch.labels) * zv
-        vector_sum = _maybe_psum(batch.weighted_feature_sum(r), axis_name,
-                                 collective_quant)
-        prefactor_sum = _maybe_psum(jnp.sum(r), axis_name, collective_quant)
+        kernels = _fused_kernels("objective.hvp", batch, axis_name,
+                                 _HVP_MIN_FUSED_COLS)
+        if kernels is not None:
+            vector_sum, prefactor_sum = kernels.fused_hessian_vector_sums(
+                loss, False, batch.X, batch.labels, batch.offsets,
+                batch.weights, w_eff, margin_shift, v_eff, v_shift)
+        else:
+            z = batch.margins(w_eff, margin_shift)
+            # zv: margin of v without data offsets (constant in w).
+            zv = batch.margins(v_eff, v_shift) - batch.offsets
+            r = batch.weights * loss.d2(z, batch.labels) * zv
+            vector_sum = batch.weighted_feature_sum(r)
+            prefactor_sum = jnp.sum(r)
+        vector_sum = _maybe_psum(vector_sum, axis_name, collective_quant)
+        prefactor_sum = _maybe_psum(prefactor_sum, axis_name,
+                                    collective_quant)
         return norm.reconstruct_gradient(vector_sum, prefactor_sum)
 
 

@@ -1,9 +1,10 @@
-"""Pallas fused value+gradient kernel vs the two-pass XLA formulation.
+"""The Pallas fused kernel's two forms (value+gradient, Hessian-vector)
+vs the two-pass XLA formulation.
 
-Runs in interpreter mode on CPU: this checks the kernel's arithmetic for
-every loss and for ragged edge tiles, not that Mosaic accepts it (that is
-tests/test_tpu_compile.py, for a described chip) nor that it is right or
-fast on a chip (that is chip_smoke.py, run on one).
+Runs in interpreter mode on CPU: this checks the kernels' arithmetic for
+every loss and for ragged edge tiles, not that Mosaic accepts them (that is
+tests/test_tpu_compile.py, for a described chip) nor that they are right or
+fast on a chip (that is chip_smoke.py and the benchmark, run on one).
 """
 
 import numpy as np
@@ -11,7 +12,12 @@ import pytest
 
 import jax.numpy as jnp
 
+from photon_ml_tpu.data.batch import dense_batch
+from photon_ml_tpu.obs.metrics import REGISTRY
+from photon_ml_tpu.ops import pallas_kernels
+from photon_ml_tpu.ops.aggregators import GLMObjective, hessian_vector
 from photon_ml_tpu.ops.losses import LOSSES, get_loss
+from photon_ml_tpu.ops.normalization import NormalizationContext
 from photon_ml_tpu.ops.pallas_kernels import (
     _xla_sums as _xla_sums_kernelmod,
     fused_value_gradient_sums,
@@ -105,3 +111,148 @@ def test_custom_vjp_differentiable():
     # analytic gradient = vector_sum
     _, vec_ref, _ = _xla_sums(loss, X, y, off, wt, w, 0.0)
     np.testing.assert_allclose(np.asarray(g), vec_ref, rtol=2e-4, atol=2e-4)
+
+
+@pytest.fixture
+def gate_forced_open(monkeypatch):
+    """``aggregators`` takes the fused forms as on one chip at a real size,
+    the kernels in interpret mode (the program has no option for either)."""
+    monkeypatch.setattr(pallas_kernels, "pallas_supported",
+                        lambda *a, **kw: True)
+    for name in ("fused_value_gradient_sums", "fused_hessian_vector_sums"):
+        real = getattr(pallas_kernels, name)
+        monkeypatch.setattr(
+            pallas_kernels, name,
+            lambda loss, interpret, *a, _real=real: _real(loss, True, *a))
+
+
+def _hvp_norm(d, seed):
+    rng = np.random.default_rng(seed)
+    return NormalizationContext(
+        factors=jnp.asarray(rng.uniform(0.5, 2.0, d), jnp.float32),
+        shifts=jnp.asarray(rng.normal(size=d) * 0.3, jnp.float32))
+
+
+# id: loss, rows, cols, normalization, X's dtype, zero-weight tail, rtol
+_HVP_CASES = {
+    "logistic-ragged": ("logistic", 700, 128, False, "float32", 0, 2e-4),
+    "squared-ragged": ("squared", 700, 128, False, "float32", 0, 2e-4),
+    "poisson-ragged": ("poisson", 700, 128, False, "float32", 0, 2e-4),
+    "logistic-exact-tiles": ("logistic", 1024, 256, False, "float32", 0,
+                             2e-4),
+    "logistic-two-ragged-tiles": ("logistic", 2500, 64, False, "float32", 0,
+                                  2e-4),
+    "logistic-factors-shifts": ("logistic", 700, 128, True, "float32", 0,
+                                2e-4),
+    "poisson-factors-shifts": ("poisson", 1300, 96, True, "float32", 0,
+                               2e-4),
+    "logistic-bf16": ("logistic", 700, 128, False, "bfloat16", 0, 5e-2),
+    "squared-bf16-factors-shifts": ("squared", 700, 128, True, "bfloat16", 0,
+                                    5e-2),
+    "logistic-zero-weight-rows": ("logistic", 700, 128, True, "float32", 150,
+                                  2e-4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_HVP_CASES))
+def test_fused_hvp_matches_two_pass(case, gate_forced_open, monkeypatch):
+    """``hessian_vector`` through the fused form against its own two-pass
+    body, the reference semantics (float32 X in both; a bf16 X is held to
+    bf16's input rounding of the f32 reference)."""
+    loss_name, n, d, normalized, dtype, padded, rtol = _HVP_CASES[case]
+    loss = get_loss(loss_name)
+    X, y, off, wt, w = _case(n, d, seed=len(case))
+    v = np.random.default_rng(5).normal(size=d).astype(np.float32)
+    wt[n - padded:] = 0.0
+    norm = _hvp_norm(d, 11) if normalized else NormalizationContext()
+    fused = hessian_vector(loss, norm, jnp.asarray(w), jnp.asarray(v),
+                           dense_batch(X, y, off, wt, dtype=jnp.dtype(dtype)))
+    assert fused.dtype == jnp.float32
+
+    monkeypatch.setattr(pallas_kernels, "pallas_supported",
+                        lambda *a, **kw: False)
+    two_pass = hessian_vector(loss, norm, jnp.asarray(w), jnp.asarray(v),
+                              dense_batch(X, y, off, wt))
+    scale = float(jnp.max(jnp.abs(two_pass)))
+    np.testing.assert_allclose(np.asarray(fused), np.asarray(two_pass),
+                               rtol=rtol, atol=rtol * scale)
+    if padded:  # a zero-weight row adds nothing, whatever it holds
+        X[n - padded:] = 1e6
+        again = hessian_vector(loss, norm, jnp.asarray(w), jnp.asarray(v),
+                               dense_batch(X, y, off, wt))
+        np.testing.assert_allclose(np.asarray(again), np.asarray(two_pass),
+                                   rtol=1e-6, atol=1e-6 * scale)
+
+
+def _tron_solve(loss_name, X, y, off, wt):
+    from photon_ml_tpu.optimize.tron import minimize_tron
+
+    obj = GLMObjective(get_loss(loss_name), l2_lambda=0.1)
+    batch = dense_batch(X, y, off, wt)
+    x, hist, _ = minimize_tron(
+        lambda w, p: p[0].calculate(w, p[1]),
+        lambda w, v, p: p[0].hessian_vector(w, v, p[1]),
+        jnp.zeros(X.shape[1], jnp.float32), (obj, batch), max_iter=30,
+        tolerance=1e-4)
+    return (np.asarray(x), int(np.asarray(hist.num_iterations)),
+            int(np.asarray(hist.hvps).sum()))
+
+
+@pytest.mark.parametrize("loss_name", ["squared", "logistic", "poisson"])
+def test_tron_solve_with_fused_product_lands_on_two_pass_solve(
+        loss_name, gate_forced_open, monkeypatch):
+    """A whole trust-region solve whose every product (and evaluation) is
+    the fused kernel, against the same solve in the two-pass forms. Column
+    scales over a decade, as the benchmark's dense cells have: 3 iterations
+    of 9-14 conjugate-gradient steps. (A tolerance at float32's floor would
+    test which side's last step rounding refuses, not the product.)"""
+    X, y, off, wt, _ = _case(1500, 48, seed=4)
+    X = X * np.logspace(-0.5, 0.5, 48).astype(np.float32)
+    x_fused, iters_fused, hvps_fused = _tron_solve(loss_name, X, y, off, wt)
+    monkeypatch.setattr(pallas_kernels, "pallas_supported",
+                        lambda *a, **kw: False)
+    x_ref, iters_ref, hvps_ref = _tron_solve(loss_name, X, y, off, wt)
+    assert iters_ref >= 3 and hvps_ref >= 9 * iters_ref
+    np.testing.assert_allclose(x_fused, x_ref, rtol=2e-4, atol=2e-4)
+    assert abs(iters_fused - iters_ref) <= 1
+    assert abs(hvps_fused - hvps_ref) <= 1
+
+
+def _lowerings(scope):
+    counter = REGISTRY.counter("objective_lowerings")
+    return {form: counter.value(scope=scope, form=form)
+            for form in ("fused", "two_pass")}
+
+
+def test_objective_lowerings_books_the_form_traced(monkeypatch):
+    """One count a trace of ``hessian_vector`` / ``value_and_gradient``:
+    ``two_pass`` on the CPU, ``fused`` where the gate is open (for a
+    product: and the batch has a full lane tile of columns)."""
+    loss = get_loss("logistic")
+    X, y, off, wt, w = _case(300, 128, seed=6)
+    obj, batch = GLMObjective(loss), dense_batch(X, y, off, wt)
+    narrow = dense_batch(X[:, :65], y, off, wt)
+    w = jnp.asarray(w)
+    scopes = ("objective.hvp", "objective.value_and_grad")
+
+    def booked_since(before):
+        return {scope: {form: count - before[scope][form]
+                        for form, count in _lowerings(scope).items()}
+                for scope in scopes}
+
+    before = {scope: _lowerings(scope) for scope in scopes}
+    obj.hessian_vector(w, w, batch)
+    obj.calculate(w, batch)
+    assert booked_since(before) == {
+        scope: {"fused": 0, "two_pass": 1} for scope in scopes}
+
+    monkeypatch.setattr(pallas_kernels, "pallas_supported",
+                        lambda *a, **kw: True)
+    monkeypatch.setattr(pallas_kernels, "fused_hessian_vector_sums",
+                        lambda *a: (jnp.zeros(128), jnp.zeros(())))
+    before = {scope: _lowerings(scope) for scope in scopes}
+    obj.hessian_vector(w, w, batch)
+    obj.hessian_vector(w[:65], w[:65], narrow)  # 65 columns: the XLA form
+    assert booked_since(before) == {
+        "objective.hvp": {"fused": 1, "two_pass": 1},
+        "objective.value_and_grad": {"fused": 0, "two_pass": 0}}

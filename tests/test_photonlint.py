@@ -2030,14 +2030,14 @@ def test_w801_seeded_pallas_accumulator_deletion(tmp_path_factory):
     target = root / "photon_ml_tpu" / "ops" / "pallas_kernels.py"
     src = target.read_text()
     needle = (
-        "    z = (jax.lax.dot_general(\n"
-        "        X, w_col, (((1,), (0,)), ((), ())),\n"
+        "    return jax.lax.dot_general(\n"
+        "        X, col, (((1,), (0,)), ((), ())),\n"
         "        precision=precision,\n"
         "        preferred_element_type=jnp.float32).reshape(-1)\n")
     assert needle in src, "pallas margin matmul moved; update this test"
     target.write_text(src.replace(needle, (
-        "    z = (jax.lax.dot_general(\n"
-        "        X, w_col, (((1,), (0,)), ((), ())),\n"
+        "    return jax.lax.dot_general(\n"
+        "        X, col, (((1,), (0,)), ((), ())),\n"
         "        precision=precision).reshape(-1)\n")))
     report = runner.lint(root, paths=["photon_ml_tpu"],
                          families={"W8"})

@@ -53,6 +53,7 @@ from photon_ml_tpu.ops.aggregators import GLMObjective
 from photon_ml_tpu.ops.losses import get_loss
 from photon_ml_tpu.ops.pallas_kernels import (
     MAX_PALLAS_DIM,
+    fused_hessian_vector_sums,
     fused_value_gradient_sums,
 )
 from photon_ml_tpu.optimize.config import (
@@ -157,22 +158,28 @@ def _dense(n, d, sharding, dtype=jnp.float32) -> DenseBatch:
                       offsets=sds((n,)), weights=sds((n,)))
 
 
-def _l2_problem(max_iter, tolerance, lam, **kw) -> GLMOptimizationProblem:
+def _l2_problem(max_iter, tolerance, lam,
+                optimizer=OptimizerType.LBFGS,
+                task=TaskType.LOGISTIC_REGRESSION,
+                **kw) -> GLMOptimizationProblem:
     return GLMOptimizationProblem(
         config=GLMOptimizationConfiguration(
             max_iterations=max_iter, tolerance=tolerance,
-            regularization_weight=lam, optimizer_type=OptimizerType.LBFGS,
+            regularization_weight=lam, optimizer_type=optimizer,
             regularization_context=RegularizationContext(
                 RegularizationType.L2)),
-        task=TaskType.LOGISTIC_REGRESSION, **kw)
+        task=task, **kw)
 
 
-@pytest.mark.parametrize("n,d,dtype", [
+KERNEL_SHAPES = pytest.mark.parametrize("n,d,dtype", [
     GLM_SHAPE + ("float32",),
     GLM_SHAPE + ("bfloat16",),
     (GLMIX_ROWS, GLMIX_FIXED_DIM, "float32"),  # ragged last tile, odd width
     (65536, MAX_PALLAS_DIM, "float32"),
 ])
+
+
+@KERNEL_SHAPES
 def test_fused_kernel_compiles(one_chip, n, d, dtype):
     loss = get_loss("logistic")
     b = _dense(n, d, one_chip, jnp.dtype(dtype))
@@ -185,6 +192,23 @@ def test_fused_kernel_compiles(one_chip, n, d, dtype):
     assert MOSAIC_CALL in compiled.as_text()
 
 
+@KERNEL_SHAPES
+def test_fused_hvp_compiles(one_chip, n, d, dtype):
+    """The Hessian-vector form at the value+gradient form's shapes (the
+    program engages it at 128 columns or more; the kernel itself takes the
+    65-wide block too)."""
+    loss = get_loss("logistic")
+    b = _dense(n, d, one_chip, jnp.dtype(dtype))
+    vec = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=one_chip)
+    shift = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda X, y, o, wt, w, s, v, vs: fused_hessian_vector_sums(
+            loss, False, X, y, o, wt, w, s, v, vs)
+    ).lower(b.X, b.labels, b.offsets, b.weights, vec, shift, vec,
+            shift).compile()
+    assert MOSAIC_CALL in compiled.as_text()
+
+
 def test_lbfgs_solve_compiles_with_kernel_in_loop(one_chip, as_on_one_tpu):
     """chip_smoke phase 1's program: train_glm_grid's solve at 262144x2048."""
     n, d = GLM_SHAPE
@@ -194,6 +218,27 @@ def test_lbfgs_solve_compiles_with_kernel_in_loop(one_chip, as_on_one_tpu):
         problem.objective(), _dense(n, d, one_chip), x0).compile()
     text = compiled.as_text()
     assert MOSAIC_CALL in text and "while" in text
+
+
+def test_tron_solve_compiles_with_kernel_in_cg_loop(one_chip, as_on_one_tpu):
+    """The TRON cell's program at chip_smoke's shape: the fused product is
+    a Mosaic call inside the conjugate-gradient ``while`` inside the
+    trust-region ``while``, beside the value+gradient form's calls, and no
+    pass over X is left in plain XLA."""
+    n, d = GLM_SHAPE
+    problem = _l2_problem(80, 1e-6, 10.0, optimizer=OptimizerType.TRON,
+                          task=TaskType.LINEAR_REGRESSION)
+    x0 = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(problem.solve).lower(
+        problem.objective(), _dense(n, d, one_chip), x0).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if MOSAIC_CALL in line]
+    assert any("tron.cg" in line and "objective.hvp" in line
+               for line in calls)
+    assert any("objective.value_and_grad" in line for line in calls)
+    # the two halves of a two-pass product carry these scopes
+    assert "objective.margins" not in text
+    assert "objective.feature_sum" not in text
 
 
 @pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
