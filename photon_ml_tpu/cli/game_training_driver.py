@@ -194,8 +194,8 @@ def parse_args(argv: Sequence[str]) -> argparse.Namespace:
                    help="(N, D) size buckets for random-effect entity "
                         "blocks: >1 pads each size bucket only to its own "
                         "(rows, dims), cutting FLOPs/HBM on skewed entity "
-                        "sizes (SURVEY hard part 1; not applied to "
-                        "factored coordinates, which need one block)")
+                        "sizes (SURVEY hard part 1; factored coordinates "
+                        "take the same buckets)")
     p.add_argument("--re-lane-compaction-chunk",
                    type=_parse_compaction_chunk, default=0,
                    help="solve random-effect entity blocks in iteration "
@@ -582,8 +582,10 @@ class GameTrainingDriver:
             elif cid in self.random_data_configs and cid in factored_cfgs:
                 data_cfg = self.random_data_configs[cid]
                 re_cfg, latent_cfg, mf_cfg = factored_cfgs[cid]
-                ds = build_random_effect_dataset(self.train_data, data_cfg,
-                                                 dtype=dtype)
+                ds = build_random_effect_dataset(
+                    self.train_data, data_cfg, dtype=dtype,
+                    num_buckets=max(
+                        1, int(self.ns.random_effect_block_buckets)))
                 coords[cid] = FactoredRandomEffectCoordinate(
                     dataset=ds,
                     problem=RandomEffectOptimizationProblem(

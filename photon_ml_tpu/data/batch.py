@@ -24,10 +24,18 @@ Two layouts:
   row padded to 128 lanes, 3.3x their bytes, where ``[39, N]`` pads 39 to
   40 sublanes (PERF.md, PR 29).
 
-Both carry ``labels``, ``offsets``, ``weights`` (length N) and are registered
-pytrees so they cross ``jit``/``pjit`` boundaries and shard over the mesh data
-axis (:func:`row_partition_specs` says which axis of each leaf holds the
-rows).
+- :class:`ProjectionRefitBatch` — the factored random effect's projection
+  refit: the rows are those of per-entity blocks ``[E, N, D_b]`` in each
+  entity's reduced feature space, the coefficients are ``vec(B)`` of the
+  shared ``[K, D]`` projection, and a row's features are the Kronecker
+  product ``c_e (x) x`` of its entity's latent coefficients with the row,
+  which is never built: margins gather each entity's columns of ``B``,
+  gradients scatter-add K-wide columns back into a ``[D, K]`` table.
+
+All carry ``labels``, ``offsets``, ``weights`` (length N) and are registered
+pytrees so they cross ``jit``/``pjit`` boundaries; the first two shard over
+the mesh data axis (:func:`row_partition_specs` says which axis of each leaf
+holds the rows).
 """
 
 from __future__ import annotations
@@ -196,7 +204,150 @@ class EllBatch:
         return self._column_sums(row_scalars, square=True)
 
 
-Batch = Union[DenseBatch, EllBatch]
+# ``jax.named_scope`` of the gather of each entity's columns of the shared
+# projection, ``B[:, P_e]``: in the refit's margins and in the latent stage
+# of the factored coordinate (game/coordinate.py).
+PROJECT_SCOPE = "factored.project"
+
+
+def projection_table(B: Array) -> Array:
+    """``[K, D]`` projection -> the ``[D + 1, K]`` table the gathers read:
+    a column of ``B`` a row (K lies contiguous, so one gathered index brings
+    a whole column), and a zero row at ``D``, where the unused slots of an
+    index-map projector point (``raw_indices`` holds ``raw_dim`` there)."""
+    return jnp.concatenate([B.T, jnp.zeros((1, B.shape[0]), B.dtype)])
+
+
+def gather_projection(table: Array, columns: Array) -> Array:
+    """Every entity's own columns of the projection, ``B[:, P_e]`` as
+    ``[E, D_b, K]``: ``columns`` is ``[E, D_b]`` int32 into ``table``'s rows,
+    an unused slot holds ``D`` and gathers zeros."""
+    with jax.named_scope(PROJECT_SCOPE):
+        return table[columns]
+
+
+class RefitBlock(NamedTuple):
+    """One block of per-entity rows in the refit: a bucket of the
+    random-effect data set with its entities' column maps and latent
+    coefficients."""
+
+    X: Array  # [E, N, D_b] rows in each entity's reduced space
+    columns: Array  # [E, D_b] int32 raw column of each slot (dim = unused)
+    latent: Array  # [E, K] the entities' latent coefficients c_e
+
+
+@jax.tree_util.register_pytree_node_class
+class ProjectionRefitBatch:
+    """The factored random effect's projection refit as a batch layout.
+
+    For entity ``e`` with latent coefficients ``c_e`` and a row ``x~`` of its
+    block, stored over the entity's own columns ``P_e`` of the raw space,
+    the margin is ``c_e^T B[:, P_e] x~``: linear in ``vec(B)``, with
+    Kronecker features ``c_e (x) x``. The reference materialises them
+    (kroneckerProductFeaturesAndCoefficients,
+    FactoredRandomEffectCoordinate.scala:271); at K = 32 and 128 columns an
+    entity that is a ``[rows, 4096]`` matrix, so here a pass is
+
+    - margins: ``w_e = B[:, P_e]^T c_e`` (a gather of K-wide columns and a
+      contraction over K), then ``einsum("end,ed->en", X, w)``;
+    - feature sum: ``g_e = X_e^T r_e``, then ``B_grad[:, P_e[d]] += c_e
+      g_e[d]``, a scatter-add of K-wide columns into a ``[D + 1, K]`` table
+      whose last row takes the unused slots and is dropped.
+
+    The coefficient vector is ``B.reshape(-1)`` (``[K, D]`` row-major), the
+    rows are the blocks' rows flattened block after block; ``labels``,
+    ``offsets`` and ``weights`` are flat in that order (weight 0 on padding
+    rows). ``dim`` (D) is static pytree aux data, as in :class:`EllBatch`.
+    Products at "highest": they are a small part of a pass (the gather and
+    the scatter-add are the rest) and the refit is one solve over every
+    entity's rows, where bf16 passes would round ``w_e`` to three digits.
+    """
+
+    def __init__(self, blocks, labels: Array, offsets: Array, weights: Array,
+                 dim: int):
+        self.blocks = tuple(RefitBlock(*b) for b in blocks)
+        self.labels = labels  # [R]
+        self.offsets = offsets  # [R]
+        self.weights = weights  # [R]
+        self.dim = dim  # D, static
+
+    def tree_flatten(self):
+        return ((self.blocks, self.labels, self.offsets, self.weights),
+                self.dim)
+
+    @classmethod
+    def tree_unflatten(cls, dim, leaves):
+        return cls(*leaves, dim=dim)
+
+    def _replace(self, **kw):
+        fields = dict(blocks=self.blocks, labels=self.labels,
+                      offsets=self.offsets, weights=self.weights,
+                      dim=self.dim)
+        fields.update(kw)
+        return ProjectionRefitBatch(**fields)
+
+    @property
+    def latent_dim(self) -> int:
+        return self.blocks[0].latent.shape[-1]
+
+    @property
+    def num_features(self) -> int:
+        return self.latent_dim * self.dim
+
+    @property
+    def acc_dtype(self):
+        """Solver/accumulator dtype (see DenseBatch.acc_dtype)."""
+        return jnp.promote_types(self.blocks[0].X.dtype, jnp.float32)
+
+    def entity_coefficients(self, w_eff: Array) -> list:
+        """Per block, every entity's coefficients over its own columns,
+        ``w_e = B[:, P_e]^T c_e`` as ``[E, D_b]``, for ``w_eff`` =
+        ``vec(B)``."""
+        table = projection_table(w_eff.reshape(self.latent_dim, self.dim))
+        return [jnp.einsum("edk,ek->ed",
+                           gather_projection(table, b.columns), b.latent,
+                           precision=lax.Precision.HIGHEST,
+                           preferred_element_type=self.acc_dtype)
+                for b in self.blocks]
+
+    def margins(self, w_eff: Array, margin_shift: Array) -> Array:
+        with jax.named_scope(MARGINS_SCOPE):
+            z = [jnp.einsum("end,ed->en", b.X, w,
+                            precision=lax.Precision.HIGHEST,
+                            preferred_element_type=self.acc_dtype
+                            ).reshape(-1)
+                 for b, w in zip(self.blocks,
+                                 self.entity_coefficients(w_eff))]
+            return jnp.concatenate(z) + margin_shift + self.offsets
+
+    def _column_sums(self, row_scalars: Array, square: bool) -> Array:
+        """sum over rows of row_scalars x (c_e (x) x) (or its elementwise
+        square), each slot's K-wide column into its raw column."""
+        with jax.named_scope(FEATURE_SUM_SCOPE):
+            sums = jnp.zeros(
+                (self.dim + 1, self.latent_dim),
+                jnp.result_type(self.acc_dtype, row_scalars,
+                                self.blocks[0].latent))
+            at = 0
+            for b in self.blocks:
+                e, n, _ = b.X.shape
+                r = row_scalars[at:at + e * n].reshape(e, n)
+                at += e * n
+                g = jnp.einsum("end,en->ed", b.X * b.X if square else b.X,
+                               r, precision=lax.Precision.HIGHEST,
+                               preferred_element_type=sums.dtype)
+                c = b.latent * b.latent if square else b.latent
+                sums = sums.at[b.columns].add(g[:, :, None] * c[:, None, :])
+            return sums[:self.dim].T.reshape(-1)
+
+    def weighted_feature_sum(self, row_scalars: Array) -> Array:
+        return self._column_sums(row_scalars, square=False)
+
+    def hadamard_square_sum(self, row_scalars: Array) -> Array:
+        return self._column_sums(row_scalars, square=True)
+
+
+Batch = Union[DenseBatch, EllBatch, ProjectionRefitBatch]
 
 
 def dense_batch(

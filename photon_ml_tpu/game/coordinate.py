@@ -34,7 +34,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from photon_ml_tpu.data.batch import DenseBatch
+from photon_ml_tpu.data.batch import (
+    ProjectionRefitBatch,
+    gather_projection,
+    projection_table,
+)
 from photon_ml_tpu.game.dataset import (
     FixedEffectDataset,
     RandomEffectDataset,
@@ -50,7 +54,13 @@ from photon_ml_tpu.game.random_effect import (
     score_random_effect,
 )
 from photon_ml_tpu.models.glm import Coefficients, GeneralizedLinearModel
-from photon_ml_tpu.optimize.common import OptimizationResult, record_solve
+from photon_ml_tpu.obs import compile as obs_compile
+from photon_ml_tpu.obs import trace
+from photon_ml_tpu.optimize.common import (
+    DeferredOptimizationResult,
+    OptimizationResult,
+    record_solve,
+)
 from photon_ml_tpu.optimize.config import TaskType
 from photon_ml_tpu.optimize.problem import GLMOptimizationProblem
 from photon_ml_tpu.sampler.samplers import down_sample
@@ -68,6 +78,16 @@ class FixedEffectTracker:
     """optimization/game/FixedEffectOptimizationTracker analog."""
 
     result: OptimizationResult
+
+    def for_coordinate(self, coordinate: str) -> "FixedEffectTracker":
+        """Name the coordinate whose update this was: the label the
+        solve's counts are booked under when its history is forced."""
+        # (under a mesh with a data axis ``run_lazy`` hands back an eager
+        # result: its counts reached the host inside the solve and stand
+        # under ``site`` alone)
+        if isinstance(self.result, DeferredOptimizationResult):
+            self.result.coordinate = coordinate
+        return self
 
     def materialize(self) -> "FixedEffectTracker":
         """Force a deferred result's device-resident history host-side
@@ -112,6 +132,11 @@ class RandomEffectTracker:
     evaluation_rounds: Optional[np.ndarray] = None  # [B]
     bucket_lanes: Optional[np.ndarray] = None  # [B], pad lanes included
     site: str = "re.fit_blocks"
+    coordinate: Optional[str] = None  # the counters' second label
+
+    def for_coordinate(self, coordinate: str) -> "RandomEffectTracker":
+        self.coordinate = coordinate
+        return self
 
     def materialize(self) -> "RandomEffectTracker":
         """Fetch the per-entity arrays host-side (one explicit
@@ -139,7 +164,8 @@ class RandomEffectTracker:
                 record_solve(
                     self.site, int(self.iterations.sum()),
                     int(self.evaluations.sum()),
-                    lane_evaluations=self._lane_evaluations())
+                    lane_evaluations=self._lane_evaluations(),
+                    coordinate=self.coordinate)
         return self
 
     def _lane_evaluations(self) -> int:
@@ -185,6 +211,13 @@ class RandomEffectTracker:
 @dataclasses.dataclass
 class FactoredRandomEffectTracker:
     inner: list[tuple[RandomEffectTracker, FixedEffectTracker]]
+
+    def for_coordinate(self, coordinate: str
+                       ) -> "FactoredRandomEffectTracker":
+        for re_tracker, fe_tracker in self.inner:
+            re_tracker.for_coordinate(coordinate)
+            fe_tracker.for_coordinate(coordinate)
+        return self
 
     def materialize(self) -> "FactoredRandomEffectTracker":
         for re_tracker, fe_tracker in self.inner:
@@ -328,24 +361,53 @@ class RandomEffectCoordinate:
 # ---------------------------------------------------------------------------
 
 
+def _refit_batch(data, spans, single_block: bool, raw_dim: int,
+                 coefs: Array, offsets) -> ProjectionRefitBatch:
+    """The projection refit's batch: ``data`` is what no update changes
+    (the blocks, their column maps, the flat labels and weights), ``spans``
+    every block's (first global entity, real entities, lanes), ``coefs``
+    the compact global latent block, cut here into the blocks' own lane
+    counts (pad lanes zero), ``offsets`` the per-block training offsets
+    (None: zeros)."""
+    Xs, columns, labels, weights = data
+    if offsets is None:
+        flat = jnp.zeros_like(labels)
+    else:
+        flat = jnp.concatenate([o.reshape(-1) for o in (
+            [offsets] if single_block else offsets)])
+    return ProjectionRefitBatch(
+        blocks=[(X, cols, jnp.pad(coefs[start:start + num_real],
+                                  ((0, e_b - num_real), (0, 0))))
+                for X, cols, (start, num_real, e_b) in zip(Xs, columns,
+                                                           spans)],
+        labels=labels, offsets=flat, weights=weights, dim=raw_dim)
+
+
 @dataclasses.dataclass
 class FactoredRandomEffectCoordinate:
     """Alternating latent-space random effect + projection-matrix fit.
 
-    The dataset must be built with IDENTITY projection (raw-space blocks
-    ``[E, N, D]``). Each update runs ``num_inner_iterations`` of:
+    For entity ``e`` with latent coefficients ``c_e`` in R^K and the shared
+    projection ``B`` in R^{K x D} (D the raw feature space), a row scores
+    ``c_e^T B x``. The dataset is any ``RandomEffectDataset`` in raw space:
+    index-map projected (each entity's block holds only its own columns
+    ``P_e = projectors.raw_indices[e]``, so ``B x = B[:, P_e] x~``),
+    bucketed or one block; identity projection is the case ``P_e =
+    arange(D)``. The per-entity random-effect coordinate's own dataset may
+    be handed in as it is. Each update runs ``num_inner_iterations`` of:
 
-    1. project actives into the current latent space
-       (``X_lat = X · Bᵀ``, one einsum) and solve per-entity latent
+    1. project the blocks into the current latent space
+       (``X_lat = einsum(X~, B[:, P_e])``) and solve per-entity latent
        coefficients with the vmapped block solver
        (FactoredRandomEffectCoordinate.scala:228-257's random-effect step);
-    2. refit B on Kronecker-product features ``c_e ⊗ x`` with a single
-       GLM whose coefficient vector is vec(B)
-       (kroneckerProductFeaturesAndCoefficients :271) — the expansion is an
-       einsum producing ``[E·N, K·D]``.
+    2. refit B with a single GLM whose coefficient vector is vec(B) over
+       the Kronecker features ``c_e (x) x``
+       (kroneckerProductFeaturesAndCoefficients :271), which are never
+       built: the refit's batch layout gathers and scatter-adds each
+       entity's columns of B (data/batch.ProjectionRefitBatch).
     """
 
-    dataset: RandomEffectDataset  # identity-projected (raw blocks)
+    dataset: RandomEffectDataset  # raw space: index-map or identity
     problem: RandomEffectOptimizationProblem  # latent per-entity fits
     latent_problem: GLMOptimizationProblem  # projection-matrix fit
     latent_dim: int
@@ -353,15 +415,81 @@ class FactoredRandomEffectCoordinate:
     seed: int = 0
 
     def __post_init__(self):
-        if self.dataset.projectors is not None or \
-                self.dataset.random_projector is not None:
+        ds = self.dataset
+        if ds.random_projector is not None:
             raise ValueError(
-                "factored coordinate needs an identity-projected dataset")
-        if self.dataset.buckets is not None:
-            raise ValueError(
-                "factored coordinate needs a single-block dataset "
-                "(build with num_buckets=1): the latent refit shares one "
-                "projection matrix across all entities")
+                "factored coordinate needs a dataset in raw feature space "
+                "(index-map or identity projection): a random projection "
+                "has no per-entity columns of B to gather")
+        self.raw_dim = (ds.reduced_dim if ds.projectors is None
+                        else int(ds.projectors.raw_dim))
+        # (first global entity, real entities, lanes) of every block: its
+        # buckets, or the dataset itself where it is one block (whose pad
+        # lanes are part of the coefficient block)
+        blocks = [ds] if ds.buckets is None else ds.buckets
+        self._spans = tuple(
+            (0, int(ds.X.shape[0]), int(ds.X.shape[0]))
+            if ds.buckets is None
+            else (b.entity_start, b.num_real, int(b.X.shape[0]))
+            for b in blocks)
+        # every block's column map [E_b, D_b] into B's columns; raw_dim
+        # marks an unused slot, which gathers zeros and scatters nowhere
+        columns = []
+        for (start, num_real, e_b), block in zip(self._spans, blocks):
+            d_b = int(block.X.shape[2])
+            if ds.projectors is None:
+                cols = np.broadcast_to(np.arange(d_b, dtype=np.int32),
+                                       (e_b, d_b))
+            else:
+                cols = np.full((e_b, d_b), self.raw_dim, np.int32)
+                own = ds.projectors.raw_indices[start:start + num_real,
+                                                :d_b]
+                cols[:len(own)] = own
+            columns.append(jnp.asarray(cols))
+        # what of the refit's batch no update changes: the blocks, their
+        # column maps, the flat labels and weights. Handed to the jitted
+        # stages as arguments (a closed-over array would be baked into the
+        # executable as a constant)
+        self._data = (
+            tuple(b.X for b in blocks), tuple(columns),
+            jnp.concatenate([b.labels.reshape(-1) for b in blocks]),
+            jnp.concatenate([b.weights.reshape(-1) for b in blocks]))
+        # The three jitted stages close over small static facts only (never
+        # over ``self``: a cycle through the coordinate would keep its
+        # device blocks alive until the cycle collector runs).
+        problem, spans, raw_dim = self.latent_problem, self._spans, self.raw_dim
+        single_block, d_red = ds.buckets is None, ds.reduced_dim
+
+        # the refit runs as an XLA module of its own name (the fixed
+        # effect's solve is jit__minimize_lbfgs_impl too, inlined here)
+        def _factored_refit_impl(obj, data, coefs, offsets, x0):
+            return problem.solve(obj, _refit_batch(
+                data, spans, single_block, raw_dim, coefs, offsets), x0)
+
+        def _factored_latent_blocks(data, B):
+            """Every block's rows in the latent space, ``B[:, P_e] x~``:
+            [E_b, N_b, K] each."""
+            table = projection_table(B)
+            return tuple(
+                jnp.einsum("end,edk->enk", X, gather_projection(table, cols),
+                           precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=B.dtype)
+                for X, cols in zip(data[0], data[1]))
+
+        def _factored_entity_coefficients(data, coefs, B):
+            """``w_e = B[:, P_e]^T c_e`` of every entity: the compact
+            global [num_entities, reduced_dim] block."""
+            batch = _refit_batch(data, spans, single_block, raw_dim, coefs,
+                                 None)
+            parts = [
+                jnp.pad(w[:num_real], ((0, 0), (0, d_red - w.shape[1])))
+                for w, (_, num_real, _) in zip(
+                    batch.entity_coefficients(B.reshape(-1)), spans)]
+            return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+        self._refit = jax.jit(_factored_refit_impl)
+        self._latent_blocks = jax.jit(_factored_latent_blocks)
+        self._entity_coefficients = jax.jit(_factored_entity_coefficients)
 
     @property
     def num_samples(self) -> int:
@@ -370,15 +498,27 @@ class FactoredRandomEffectCoordinate:
     def initial_state(self) -> tuple[Array, Array]:
         k = self.latent_dim
         e = self.dataset.num_entities
-        d = self.dataset.reduced_dim
         # Random projection init (MFOptimizationConfiguration analog).
         # Explicit f32: under x64 the default dtype would draw DIFFERENT
         # random bits, and the bilinear alternation amplifies an init
         # difference into a different local optimum — the init must not
         # depend on the precision mode (blocks are f32 regardless).
-        b0 = jax.random.normal(jax.random.PRNGKey(self.seed), (k, d),
+        b0 = jax.random.normal(jax.random.PRNGKey(self.seed),
+                               (k, self.raw_dim),
                                dtype=jnp.float32) / jnp.sqrt(k)
         return jnp.zeros((e, k), jnp.float32), b0
+
+    def _latent_dataset(self, B: Array) -> RandomEffectDataset:
+        """The dataset with every block's rows projected into the latent
+        space: what the per-entity solver sees."""
+        ds = self.dataset
+        lat = self._latent_blocks(self._data, B)
+        if ds.buckets is None:
+            return dataclasses.replace(ds, X=lat[0], projectors=None)
+        return dataclasses.replace(
+            ds, projectors=None, _reduced_dim=self.latent_dim,
+            buckets=[dataclasses.replace(b, X=x)
+                     for b, x in zip(ds.buckets, lat)])
 
     def update(self, state: Optional[tuple[Array, Array]],
                extra_scores: Array) -> tuple[tuple[Array, Array], Tracker]:
@@ -388,52 +528,55 @@ class FactoredRandomEffectCoordinate:
         # The init is drawn in f32 so its BITS don't depend on the x64
         # mode; the running state then promotes to the ambient dtype (x64
         # runs keep solving in f64, with the identical starting values).
-        acc = jnp.promote_types(jnp.promote_types(coefs.dtype, jnp.float32),
-                                offsets.dtype)
+        acc = jnp.promote_types(
+            jnp.promote_types(coefs.dtype, jnp.float32),
+            (offsets if ds.buckets is None else offsets[0]).dtype)
         coefs, B = coefs.astype(acc), B.astype(acc)
+        k = self.latent_dim
+        obj = self.latent_problem.objective()
+        cfg = self.latent_problem.config
         inner: list = []
         for _ in range(self.num_inner_iterations):
             # (1) latent-space per-entity fits on projected blocks.
-            X_lat = jnp.einsum("end,kd->enk", ds.X, B,
-                               preferred_element_type=jnp.float32)
-            lat_ds = dataclasses.replace(ds, X=X_lat, projectors=None,
-                                         random_projector=None)
-            # donate=False: ``offsets`` is reused across inner iterations
-            # and by the Kronecker refit below — its buffer must survive
-            coefs, iters, values, codes, counts = self.problem.run(
-                lat_ds, offsets, initial=coefs, donate=False)
+            with trace.span("factored.latent_solve", latent_dim=k):
+                # donate=False: ``offsets`` is reused across inner
+                # iterations and by the refit below
+                coefs, iters, values, codes, counts = self.problem.run(
+                    self._latent_dataset(B), offsets, initial=coefs,
+                    donate=False)
             re_tracker = RandomEffectTracker(
                 iters, values, codes, num_real=len(ds.entity_codes),
                 **counts._asdict())
-            # (2) projection-matrix fit on Kronecker features c_e ⊗ x.
-            e, n, d = ds.X.shape
-            k = self.latent_dim
-            kron = jnp.einsum("ek,end->enkd", coefs, ds.X,
-                              preferred_element_type=jnp.float32)
-            flat = DenseBatch(
-                X=kron.reshape(e * n, k * d),
-                labels=ds.labels.reshape(-1),
-                offsets=offsets.reshape(-1),
-                weights=ds.weights.reshape(-1),
-            )
-            _, result = self.latent_problem.run(
-                flat, initial=B.reshape(-1))
-            B = result.coefficients.reshape(k, d)
-            inner.append((re_tracker, FixedEffectTracker(result)))
+            # (2) projection-matrix fit over the Kronecker features.
+            with trace.span("factored.refit", latent_dim=k,
+                            raw_dim=self.raw_dim):
+                x, history, progressed = obs_compile.call(
+                    "factored.refit", self._refit,
+                    (obj, self._data, coefs, offsets, B.reshape(-1)),
+                    arg_names=("obj", "data", "coefs", "offsets", "x0"))
+            B = x.reshape(k, self.raw_dim)
+            inner.append((re_tracker, FixedEffectTracker(
+                DeferredOptimizationResult(
+                    x, history, progressed, cfg.max_iterations,
+                    cfg.tolerance,
+                    site=self.latent_problem.solver_site()))))
         return (coefs, B), FactoredRandomEffectTracker(inner)
 
-    def score(self, state: tuple[Array, Array]) -> Array:
+    def entity_coefficients(self, state: tuple[Array, Array]) -> Array:
+        """Every entity's coefficients in its own reduced space, ``w_e =
+        B[:, P_e]^T c_e``: the compact global ``[num_entities,
+        reduced_dim]`` block a random-effect coordinate of this dataset
+        would hold."""
         coefs, B = state
-        X_lat = jnp.einsum("end,kd->enk", self.dataset.X, B,
-                           preferred_element_type=jnp.float32)
-        # Passive rows project through the same latent map for scoring.
-        lat_passive = (None if self.dataset.passive_X is None
-                       else self.dataset.passive_X @ B.T)
-        lat_ds = dataclasses.replace(self.dataset, X=X_lat,
-                                     passive_X=lat_passive,
-                                     projectors=None, random_projector=None)
+        return self._entity_coefficients(self._data, coefs,
+                                         B.astype(coefs.dtype))
+
+    def score(self, state: tuple[Array, Array]) -> Array:
+        """``c_e^T B x`` for every row: the random-effect score of the
+        entities' own coefficients ``w_e``, so active and passive rows
+        alike go through their own entity's columns of B."""
         return score_random_effect(
-            lat_ds, coefs,
+            self.dataset, self.entity_coefficients(state),
             entity_shards=self.problem.entity_shards,
             collective_quant=self.problem.collective_quant)
 

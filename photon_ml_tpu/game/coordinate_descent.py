@@ -713,6 +713,8 @@ def run_coordinate_descent(
                         overlay[cid][0] if cid in overlay else scores[cid]
                     )  # Σ other coordinates (:143-151)
                     cand, tracker = coord.update(states[cid], partial)
+                    # the solver_* counters' second label, beside site
+                    tracker.for_coordinate(cid)
                     cand = fault_point("cd.update", tag=f"{it}.{ci}",
                                        arrays=cand)
                     if attempt > 0:

@@ -399,6 +399,7 @@ class RandomEffectDataset:
     # (N, D)-bucketed active blocks (replaces X... when present)
     buckets: Optional[list[EntityBucket]] = None
     _reduced_dim: Optional[int] = None  # set when bucketed
+    _score_positions: Optional[Array] = None  # made by score_positions()
 
     @property
     def num_entities(self) -> int:
@@ -442,6 +443,38 @@ class RandomEffectDataset:
         if self.passive_row_ids is None:
             return jnp.zeros(0)
         return scores[self.passive_row_ids]
+
+    def score_positions(self) -> Array:
+        """``[num_samples]`` int32: each row's place in the concatenation
+        of the active blocks' flattened margins (bucket-major), then the
+        passive rows' margins, then one zero (the place of a row this
+        coordinate does not score). A row is in one block, so scoring
+        gathers by this table what it would otherwise scatter by
+        ``row_ids``: the same numbers, and the TPU's compiler takes
+        seconds for the gather where it takes 7-16 s for every scatter
+        into a sample-long vector. Made on the host at the first call and
+        kept (``dataclasses.replace`` carries it with the ``row_ids`` it
+        was made from). None where this process cannot read every block's
+        ``row_ids`` (a dataset sharded over several hosts): the table
+        would need the other hosts' rows, and scoring scatters there."""
+        if self._score_positions is None:
+            blocks = self.buckets if self.buckets is not None else [self]
+            ids = [b.row_ids for b in blocks]
+            if self.num_passive:
+                ids.append(self.passive_row_ids)
+            if not all(getattr(a, "is_fully_addressable", True)
+                       for a in ids):
+                return None
+            ids = np.concatenate([np.asarray(a).reshape(-1) for a in ids])
+            real = np.flatnonzero(ids < self.num_samples)
+            positions = np.full(self.num_samples, len(ids), np.int32)
+            positions[ids[real]] = real
+            if np.count_nonzero(positions < len(ids)) != len(real):
+                raise ValueError(
+                    "a row is in two blocks of this random-effect dataset: "
+                    "its score cannot be gathered from one position")
+            self._score_positions = jnp.asarray(positions)
+        return self._score_positions
 
 
 def _topk_per_segment(seg: np.ndarray, score: np.ndarray,

@@ -1064,22 +1064,55 @@ def score_passive(passive_X: Array, passive_entity: Array, coefs: Array,
             num_segments=num_samples + 1)[:num_samples]
 
 
+@jax.jit
+def _active_margins(dataset_X: Array, coefs: Array, weights: Array) -> Array:
+    """``score_active``'s margins, left in the block's own ``[E, N]``
+    layout (padded rows, weight 0, read 0)."""
+    with jax.named_scope("re.score"):
+        margins = jnp.einsum("end,ed->en", dataset_X, coefs,
+                             preferred_element_type=jnp.float32)
+        return jnp.where(weights > 0, margins, 0.0)
+
+
+@jax.jit
+def _passive_margins(passive_X: Array, passive_entity: Array,
+                     coefs: Array) -> Array:
+    """``score_passive``'s margins, in the passive rows' own order."""
+    with jax.named_scope("re.score"):
+        return jnp.sum(passive_X * coefs[passive_entity], axis=-1)
+
+
+@jax.jit
+def _gather_scores(margins, positions: Array) -> Array:
+    """Each sample's score from its one place among the blocks' margins
+    and the passive rows' (``RandomEffectDataset.score_positions``; one
+    zero after them for a row this coordinate does not score)."""
+    with jax.named_scope("re.score"):
+        flat = [m.reshape(-1) for m in margins]
+        return jnp.concatenate(flat + [jnp.zeros(1, jnp.float32)])[positions]
+
+
 def score_random_effect(dataset: RandomEffectDataset, coefs: Array,
                         entity_shards: int = 1,
                         collective_quant: str = "none") -> Array:
     """Full sample-axis score vector (active + passive) for this coordinate.
 
-    ``coefs`` is the compact global block ``[num_entities, reduced_dim]``;
-    bucketed datasets score per bucket (row sets are disjoint, so the
-    per-bucket scatters sum without overlap). With ``entity_shards`` > 1
-    (and the same engagement conditions as the sharded solve), each
-    block's scoring runs shard-local and the per-shard partial score
-    vectors reduce with an on-device psum over the entity axis — the
-    replicated result feeds the CD fused epilogue with zero added host
-    syncs; ``collective_quant="int8"`` ships that psum's partials
+    ``coefs`` is the compact global block ``[num_entities, reduced_dim]``.
+    Shard-count 1 takes every block's margins and the passive rows' as
+    they lie and gathers every sample's score from its place among them
+    (``_gather_scores``), wherever this process holds every block: the
+    TPU's compiler takes 7-16 s for each scatter into a sample-long
+    vector, and seconds for the gather. Otherwise (several hosts, or
+    ``entity_shards`` > 1) every block scatters its margins by ``row_ids``
+    (row sets are disjoint, so the per-bucket scatters sum without
+    overlap): with ``entity_shards`` > 1 (and the same engagement
+    conditions as the sharded solve), each block's scoring runs
+    shard-local and the per-shard partial score vectors reduce with an
+    on-device psum over the entity axis — the replicated result feeds the
+    CD fused epilogue with zero added host syncs;
+    ``collective_quant="int8"`` ships that psum's partials
     blockwise-quantized (parallel/quantized_collectives.py) and counts
-    the wire bytes on ``collective_bytes{site="re.score_psum"}``.
-    Shard-count 1 is the unchanged single-program path."""
+    the wire bytes on ``collective_bytes{site="re.score_psum"}``."""
     from photon_ml_tpu.parallel.quantized_collectives import \
         record_collective_bytes
 
@@ -1096,15 +1129,28 @@ def score_random_effect(dataset: RandomEffectDataset, coefs: Array,
                 return out
         return score_active(X, c_b, row_ids, weights, dataset.num_samples)
 
+    def _bucket_coefs(bucket):
+        e_b, _, d_b = bucket.X.shape
+        nr, start = bucket.num_real, bucket.entity_start
+        c_b = jnp.zeros((e_b, d_b), coefs.dtype)
+        return c_b.at[:nr].set(coefs[start:start + nr, :d_b])
+
+    positions = dataset.score_positions() if entity_shards <= 1 else None
+    if positions is not None:
+        if dataset.buckets is not None:
+            margins = [_active_margins(b.X, _bucket_coefs(b), b.weights)
+                       for b in dataset.buckets]
+        else:
+            margins = [_active_margins(dataset.X, coefs, dataset.weights)]
+        if dataset.num_passive:
+            margins.append(_passive_margins(
+                dataset.passive_X, dataset.passive_entity, coefs))
+        return _gather_scores(margins, positions)
     if dataset.buckets is not None:
         s = jnp.zeros(dataset.num_samples, jnp.float32)
         for bucket in dataset.buckets:
-            e_b, _, d_b = bucket.X.shape
-            nr, start = bucket.num_real, bucket.entity_start
-            c_b = jnp.zeros((e_b, d_b), coefs.dtype)
-            c_b = c_b.at[:nr].set(coefs[start:start + nr, :d_b])
-            s = s + _score_block(bucket.X, c_b, bucket.row_ids,
-                                 bucket.weights)
+            s = s + _score_block(bucket.X, _bucket_coefs(bucket),
+                                 bucket.row_ids, bucket.weights)
     else:
         s = _score_block(dataset.X, coefs, dataset.row_ids, dataset.weights)
     if dataset.num_passive:

@@ -241,10 +241,15 @@ def _compile_here(site: "_Site", fn, args, static_argnums, signature):
         # photonlint: allow-W201(host-side compile timing: call() bypasses this whole path when a jax trace is active)
         t0 = time.perf_counter()
         try:
+            # the counter's clock runs inside the span, so both read the
+            # same interval (entering an armed span makes a profiler
+            # annotation, 0.3 ms on a busy host against 1.5 ms of lowering)
             with trace.span("xla.lower", site=site.name):
+                # photonlint: allow-W201(host-side compile timing: call() bypasses this whole path when a jax trace is active)
+                t_lower = time.perf_counter()
                 lowered = fn.lower(*args)  # Python tracing + lowering
-            # photonlint: allow-W201(host-side compile timing: call() bypasses this whole path when a jax trace is active)
-            lower_secs = time.perf_counter() - t0
+                # photonlint: allow-W201(host-side compile timing: call() bypasses this whole path when a jax trace is active)
+                lower_secs = time.perf_counter() - t_lower
             compiled = lowered.compile()
         except Exception:
             # not AOT-lowerable (or convention mismatch): the plain call

@@ -153,9 +153,11 @@ class OptimizationResult:
         tolerance: float,
         made_progress_last_iter: bool = True,
         site: Optional[str] = None,
+        coordinate: Optional[str] = None,
     ) -> "OptimizationResult":
         """``site`` (an ``obs/compile.py`` site name, e.g.
-        ``optimizer.lbfgs``) books the solve on the ``solver_*`` counters:
+        ``optimizer.lbfgs``) books the solve on the ``solver_*`` counters,
+        under ``coordinate`` too where the solve was a GAME coordinate's:
         pass it where a solve's history reaches the host ONCE. A history
         still on the device comes over in one explicit fetch of the whole
         pytree (it used to cost one blocking read per field)."""
@@ -171,7 +173,7 @@ class OptimizationResult:
                        else int(np.sum(history.evaluations)))
         hvps = None if history.hvps is None else int(np.sum(history.hvps))
         if site is not None:
-            record_solve(site, k, evaluations, hvps)
+            record_solve(site, k, evaluations, hvps, coordinate=coordinate)
         values = np.asarray(history.values)[: k + 1]
         grad_norms = np.asarray(history.grad_norms)[: k + 1]
         reason = _convergence_reason(
@@ -194,21 +196,29 @@ class OptimizationResult:
 
 def record_solve(site: str, iterations: int, evaluations: Optional[int],
                  hvps: Optional[int] = None,
-                 lane_evaluations: Optional[int] = None) -> None:
+                 lane_evaluations: Optional[int] = None,
+                 coordinate: Optional[str] = None) -> None:
     """Book solves whose counts just reached the host on the
     ``solver_*{site}`` counters. A solve with no evaluation count books
     nothing: a ratio of the counters must never mix counted and uncounted
     solves. ``lane_evaluations`` is what a batched loop executed (lanes x
-    rounds, pad lanes included); a single solve executes what it needs."""
+    rounds, pad lanes included); a single solve executes what it needs.
+    ``coordinate`` (the id of the GAME coordinate whose update made the
+    solve, in the updating sequence) is a second label beside ``site``: a
+    sweep's coordinates share their sites, and a reader that filters on
+    ``site`` alone still reads the totals."""
     if evaluations is None:
         return
-    REGISTRY.counter("solver_iterations").inc(iterations, site=site)
-    REGISTRY.counter("solver_evaluations").inc(evaluations, site=site)
+    labels = {"site": site}
+    if coordinate is not None:
+        labels["coordinate"] = coordinate
+    REGISTRY.counter("solver_iterations").inc(iterations, **labels)
+    REGISTRY.counter("solver_evaluations").inc(evaluations, **labels)
     REGISTRY.counter("solver_lane_evaluations").inc(
         evaluations if lane_evaluations is None else lane_evaluations,
-        site=site)
+        **labels)
     if hvps is not None:
-        REGISTRY.counter("solver_hvps").inc(hvps, site=site)
+        REGISTRY.counter("solver_hvps").inc(hvps, **labels)
 
 
 class DeferredOptimizationResult:
@@ -233,6 +243,9 @@ class DeferredOptimizationResult:
         self._max_iter = max_iter
         self._tolerance = tolerance
         self._site = site
+        # the GAME coordinate whose update this solve was, set by its
+        # tracker before the history is forced (record_solve's label)
+        self.coordinate: Optional[str] = None
         self._result: Optional[OptimizationResult] = None
 
     def _force(self) -> OptimizationResult:
@@ -247,7 +260,7 @@ class DeferredOptimizationResult:
             self._result = OptimizationResult.from_history(
                 self.coefficients, history,
                 self._max_iter, self._tolerance, bool(progressed),
-                site=self._site)
+                site=self._site, coordinate=self.coordinate)
             self._history = self._progressed = None
         return self._result
 

@@ -268,6 +268,54 @@ class TestEntityBucketing:
         np.testing.assert_allclose(np.asarray(s2), np.asarray(s1),
                                    rtol=2e-4, atol=2e-4)
 
+    @pytest.mark.parametrize("num_buckets", [1, 3])
+    def test_scores_gathered_by_position_equal_the_scatter(self, rng,
+                                                           num_buckets):
+        """``score_random_effect`` gathers each row's score from its one
+        place among the blocks' margins and the passive rows'
+        (``RandomEffectDataset.score_positions``): bit for bit what the
+        scatter by ``row_ids`` gives, block by block, active rows capped
+        so that there are passive ones; a row in two blocks is refused."""
+        from photon_ml_tpu.game.random_effect import (
+            score_active,
+            score_passive,
+        )
+
+        data, _, _ = self._skewed_data(rng)
+        ds = build_random_effect_dataset(
+            data, RandomEffectDataConfiguration(
+                "u", "s", 1, num_active_data_points_upper_bound=40),
+            num_buckets=num_buckets)
+        assert ds.num_passive > 0
+        coefs = jnp.asarray(rng.normal(
+            size=(ds.num_entities, ds.reduced_dim)), jnp.float32)
+        n = data.num_samples
+        want = score_passive(ds.passive_X, ds.passive_entity, coefs,
+                             ds.passive_row_ids, n)
+        if ds.buckets is None:
+            want = want + score_active(ds.X, coefs, ds.row_ids, ds.weights,
+                                       n)
+        else:
+            for b in ds.buckets:
+                c_b = jnp.zeros((b.X.shape[0], b.X.shape[2]), coefs.dtype)
+                c_b = c_b.at[:b.num_real].set(coefs[
+                    b.entity_start:b.entity_start + b.num_real,
+                    :b.X.shape[2]])
+                want = want + score_active(b.X, c_b, b.row_ids, b.weights, n)
+        got = score_random_effect(ds, coefs)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        positions = np.asarray(ds.score_positions())
+        assert positions.shape == (n,) and positions.dtype == np.int32
+        assert len(np.unique(positions)) == n  # every row scored, once
+
+        first = ds if ds.buckets is None else ds.buckets[0]
+        twice = np.asarray(first.row_ids).copy()
+        twice[0, 1] = twice[0, 0]
+        first.row_ids = jnp.asarray(twice)
+        ds._score_positions = None
+        with pytest.raises(ValueError, match="two blocks"):
+            ds.score_positions()
+
     def test_bucketed_cd_matches_single_block(self, rng):
         """Full coordinate descent (fixed + bucketed RE) reaches the same
         objective as the single-block build."""
@@ -334,14 +382,44 @@ class TestEntityBucketing:
             counts = (np.asarray(b.weights) > 0).sum(axis=1)
             assert counts.max() <= 20
 
-    def test_factored_coordinate_rejects_buckets(self, rng):
+    def test_factored_coordinate_accepts_buckets(self, rng):
+        """Until PR 33 the factored coordinate refused every bucketed
+        dataset; it takes them now, and solves the same problem on them as
+        on the one block (one more solve in other lanes, so close, not
+        equal to the bit)."""
+        data, *_ = self._skewed_data(rng)
+        task = TaskType.LINEAR_REGRESSION
+
+        def fit(num_buckets):
+            ds = build_random_effect_dataset(
+                data, RandomEffectDataConfiguration(
+                    "u", "s", 1,
+                    projector=ProjectorConfig(ProjectorType.IDENTITY)),
+                num_buckets=num_buckets)
+            coord = FactoredRandomEffectCoordinate(
+                dataset=ds,
+                problem=RandomEffectOptimizationProblem(
+                    config=l2_config(), task=task),
+                latent_problem=GLMOptimizationProblem(
+                    config=l2_config(), task=task),
+                latent_dim=2, num_inner_iterations=1)
+            state, _ = coord.update(None, jnp.zeros(data.num_samples))
+            return ds, np.asarray(coord.score(state)), np.asarray(state[1])
+
+        ds3, score3, B3 = fit(3)
+        ds1, score1, B1 = fit(1)
+        assert ds3.buckets is not None and len(ds3.buckets) > 1
+        assert ds1.buckets is None
+        np.testing.assert_allclose(B3, B1, rtol=1e-6, atol=1e-8)
+        np.testing.assert_allclose(score3, score1, rtol=1e-6, atol=1e-8)
+
+    def test_factored_coordinate_rejects_a_random_projection(self, rng):
         data, *_ = self._skewed_data(rng)
         ds = build_random_effect_dataset(
             data, RandomEffectDataConfiguration(
-                "u", "s", 1,
-                projector=ProjectorConfig(ProjectorType.IDENTITY)),
-            num_buckets=3)
-        with pytest.raises(ValueError, match="single-block"):
+                "u", "s", 1, projector=ProjectorConfig(
+                    ProjectorType.RANDOM, projected_dim=3)))
+        with pytest.raises(ValueError, match="raw feature space"):
             FactoredRandomEffectCoordinate(
                 dataset=ds,
                 problem=RandomEffectOptimizationProblem(
@@ -877,6 +955,9 @@ class _RecordingCoordinate:
         self.update_count += 1
 
         class _Tracker:
+            def for_coordinate(self, coordinate):
+                return self
+
             def summary(self):
                 return "mock"
 

@@ -398,3 +398,99 @@ def test_sharded_wide_sparse_solve_compiles(mesh, as_on_tpu_mesh):
     per_device = compiled.memory_analysis().argument_size_in_bytes
     planes = 2 * MESH_ROWS * CRITEO_SLOTS * 4
     assert per_device < 0.6 * planes * 1.1 + CRITEO_DIM * 4
+
+
+# --- the factored coordinate's projection refit (PR 33) ---------------------
+
+# benchmark/configs/game-ml20m.json: the per-user buckets of one chip's
+# quarter of MovieLens-20M, K = 32 latent factors, 26,744 movies
+GAME_USER_BUCKETS = ((15781, 128, 128), (4562, 96, 96), (6486, 72, 72),
+                     (7795, 48, 48))
+GAME_LATENT_DIM, GAME_MOVIES = 32, 26744
+
+
+def test_factored_refit_fits_one_chip_at_the_cells_size(one_chip):
+    """The refit of ``game-ml20m.train`` compiled: six L-BFGS iterations
+    over the four per-user buckets in the refit's own layout. The reference
+    builds the Kronecker features, here a [3.0M, 855,808] matrix; the layout
+    gathers and scatter-adds K-wide columns, so no instruction of the
+    optimised program makes an array a tenth that size, and arguments and
+    temporaries fit a third of one chip."""
+    from photon_ml_tpu.data.batch import ProjectionRefitBatch
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    rows = sum(e * n for e, n, _ in GAME_USER_BUCKETS)
+    batch = ProjectionRefitBatch(
+        [(sds((e, n, d)), sds((e, d), jnp.int32),
+          sds((e, GAME_LATENT_DIM))) for e, n, d in GAME_USER_BUCKETS],
+        sds((rows,)), sds((rows,)), sds((rows,)), dim=GAME_MOVIES)
+    problem = _l2_problem(6, 1e-30, 1.0)
+
+    def _factored_refit_impl(obj, batch, x0):
+        return problem.solve(obj, batch, x0)
+
+    compiled = jax.jit(_factored_refit_impl).lower(
+        problem.objective(), batch,
+        sds((GAME_LATENT_DIM * GAME_MOVIES,))).compile()
+    memory = compiled.memory_analysis()
+    blocks = sum(e * n * d for e, n, d in GAME_USER_BUCKETS) * 4
+    assert memory.argument_size_in_bytes > blocks
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < V5E_HBM_BYTES / 3)
+    text = compiled.as_text()
+    assert "jit__factored_refit_impl" in text[:400]
+    for scope in ("factored.project", "objective.margins",
+                  "objective.feature_sum", "lbfgs.linesearch"):
+        assert scope in text, scope
+    kronecker = rows * GAME_LATENT_DIM * GAME_MOVIES
+    largest = max(math.prod(int(d) for d in dims.split(",") if d)
+                  for dims, _ in _HLO_ARRAY.findall(text))
+    assert largest < kronecker // 1000
+
+
+# --- a random-effect coordinate's scores, gathered by position (PR 33) ------
+
+# the per-item side of the same configuration: the skew's other end
+GAME_ITEM_BUCKETS = ((3678, 128, 128), (1680, 64, 64), (2668, 24, 24),
+                     (12368, 8, 8))
+GAME_ROWS, GAME_ITEM_PASSIVE = 5_046_676, 4_469_393
+
+
+def test_scoring_by_position_compiles_without_a_scatter(one_chip):
+    """``score_random_effect`` on one chip: every block's margins as they
+    lie, the passive rows', and one gather by each sample's place among
+    them. The TPU's compiler took 7-16 s for each of the five scatters
+    into a sample-long vector this replaces (ROADMAP S13); none of the six
+    optimised programs holds one, and the last gathers the whole score
+    vector."""
+    from photon_ml_tpu.game.random_effect import (
+        _active_margins,
+        _gather_scores,
+        _passive_margins,
+    )
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    entities = sum(e for e, _, _ in GAME_ITEM_BUCKETS)
+    programs = [_active_margins.lower(sds((e, n, d)), sds((e, d)),
+                                      sds((e, n))).compile()
+                for e, n, d in GAME_ITEM_BUCKETS]
+    programs.append(_passive_margins.lower(
+        sds((GAME_ITEM_PASSIVE, 128)), sds((GAME_ITEM_PASSIVE,), jnp.int32),
+        sds((entities, 128))).compile())
+    gather = _gather_scores.lower(
+        [sds((e, n)) for e, n, _ in GAME_ITEM_BUCKETS]
+        + [sds((GAME_ITEM_PASSIVE,))], sds((GAME_ROWS,), jnp.int32)).compile()
+    for compiled in programs + [gather]:
+        text = compiled.as_text()
+        assert not re.search(r"\bscatter\(", text)  # the instruction
+        assert "re.score" in text
+    text = gather.as_text()
+    assert "jit__gather_scores" in text[:400]
+    assert f"f32[{GAME_ROWS}]" in text  # the gathered score vector
+    assert gather.memory_analysis().output_size_in_bytes >= GAME_ROWS * 4
+    assert sum(c.memory_analysis().temp_size_in_bytes
+               for c in programs + [gather]) < V5E_HBM_BYTES / 2
