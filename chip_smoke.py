@@ -72,8 +72,9 @@ GLM_LAMBDAS = (10.0, 1.0, 0.1)
 
 # Phase 2: MovieLens-1M's shape. Widths are never
 # cut; ``rows`` is the only size a run may reduce, and never below
-# GLMIX_MIN_ROWS (the fused kernel must engage on the fixed effect, also
-# per data shard of the four-chip mesh).
+# GLMIX_MIN_ROWS (a real size for the fixed effect, also per data shard of
+# the four-chip mesh; at its 65 columns every pass is the two-pass XLA
+# form: under ops/pallas_kernels.MIN_PALLAS_DIM).
 GLMIX_FULL_ROWS = 1_000_209
 GLMIX_MIN_ROWS = 131072
 GLMIX = dict(users=6040, items=3706, d_global=64, active_cap=128,
@@ -105,8 +106,8 @@ OBJECTIVE_REL_BOUND = 1e-5
 # different points (2^-24 relative per term).
 SCORE_ABS_BOUND = 1e-5
 # Mesh vs one device: the same solves with a psum-reassociated gradient
-# and, on the mesh, the fused kernel per shard — the noise-floor parity
-# documented in parallel/distributed.py, at GLMix scale.
+# over per-shard partial sums — the noise-floor parity documented in
+# parallel/distributed.py, at GLMix scale.
 MESH_OBJECTIVE_REL_BOUND = 1e-4
 MESH_COEF_ABS_BOUND = 5e-2
 
@@ -555,7 +556,8 @@ def phase_glm(rows: int, dim: int, lambdas, seed: int) -> dict:
     objective = GLMObjective(loss=loss, l2_lambda=0.0)
     compiled = jax.jit(objective.calculate).lower(w_probe, batch).compile()
     mosaic = "tpu_custom_call" in compiled.as_text()
-    supported = pallas_kernels.pallas_supported(rows, dim, batch.X.dtype)
+    supported = pallas_kernels.pallas_supported("value_and_grad", rows, dim,
+                                                batch.X.dtype)
     say(f"glm: pallas_supported={supported}, Mosaic custom call in the "
         f"compiled objective: {mosaic}")
     if not (supported and mosaic):

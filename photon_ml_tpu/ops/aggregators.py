@@ -6,8 +6,8 @@ ValueAndGradientAggregator.scala:34-274, HessianVectorAggregator.scala:37-163,
 HessianDiagonalAggregator.scala:97). The reference accumulates per-datum
 contributions in a Spark ``treeAggregate`` (seqOp ``add`` / combOp ``merge``);
 here each pass is a single fused matmul + reduction over the columnar batch.
-On a dense batch at a real size on a TPU a value+gradient evaluation and a
-Hessian-vector product are each ONE Pallas kernel call that reads X once
+On a dense batch at a real size and width on a TPU a value+gradient evaluation
+and a Hessian-vector product are each ONE Pallas kernel call that reads X once
 (ops/pallas_kernels.py, one gate: ``_fused_kernels``); everywhere else, and as
 the semantics the kernel is tested against, they are the two-pass XLA bodies
 below. ``objective_lowerings{scope, form}`` counts which form each traced
@@ -39,21 +39,13 @@ from photon_ml_tpu.parallel.quantized_collectives import qpsum
 Array = jnp.ndarray
 
 
-# A Hessian-vector product takes the fused form only on a full lane tile of
-# columns: at 10,000,054 x 65 f32 on a v5e the fused product reads 33.8 ms
-# (squared loss; logistic 53.3) where the two-pass form reads 7.8 (11.8)
-# (PERF.md, PR 32). The value+gradient form loses there too and keeps its
-# gate (ROADMAP S5).
-_HVP_MIN_FUSED_COLS = 128
-
-
-def _fused_kernels(scope: str, batch, axis_name: Optional[str],
-                   min_cols: int = 0):
+def _fused_kernels(form: str, batch, axis_name: Optional[str]):
     """The one gate of both fused forms: ops/pallas_kernels (imported here
-    alone, so only a dense batch ever loads Pallas) where the pass under
-    ``scope`` takes the fused form, a dense batch at a real size on a TPU
-    (``pallas_supported``), else None. Books the form the pass is traced in
-    on ``objective_lowerings{scope, form}``: trace time is when the form is
+    alone, so only a dense batch ever loads Pallas) where the pass takes
+    the fused ``form`` ("value_and_grad" or "hvp"), a dense batch at a real
+    size and at a width the form wins at on a TPU (``pallas_supported``),
+    else None. Books the form the pass is traced in on
+    ``objective_lowerings{scope, form}``: trace time is when the form is
     decided, so the count can never add a device sync."""
     kernels = None
     if isinstance(batch, DenseBatch) and batch.X.ndim == 2:
@@ -62,11 +54,13 @@ def _fused_kernels(scope: str, batch, axis_name: Optional[str],
         n, d = batch.X.shape
         # axis_name set => the caller runs us under shard_map (manual
         # partitioning, per-shard shapes): safe on any device count.
-        if d >= min_cols and pallas_kernels.pallas_supported(
-                n, d, batch.X.dtype, inside_shard_map=axis_name is not None):
+        if pallas_kernels.pallas_supported(
+                form, n, d, batch.X.dtype,
+                inside_shard_map=axis_name is not None):
             kernels = pallas_kernels
     REGISTRY.counter("objective_lowerings").inc(
-        scope=scope, form="two_pass" if kernels is None else "fused")
+        scope="objective." + form,
+        form="two_pass" if kernels is None else "fused")
     return kernels
 
 
@@ -97,8 +91,7 @@ def value_and_gradient(
     # form (fused kernel, two-pass XLA) makes it
     with jax.named_scope("objective.value_and_grad"):
         w_eff, margin_shift = norm.effective_coefficients(coef)
-        kernels = _fused_kernels("objective.value_and_grad", batch,
-                                 axis_name)
+        kernels = _fused_kernels("value_and_grad", batch, axis_name)
         if kernels is not None:
             value, vector_sum, prefactor_sum = (
                 kernels.fused_value_gradient_sums(
@@ -137,8 +130,7 @@ def hessian_vector(
     with jax.named_scope("objective.hvp"):
         w_eff, margin_shift = norm.effective_coefficients(coef)
         v_eff, v_shift = norm.effective_coefficients(vector)
-        kernels = _fused_kernels("objective.hvp", batch, axis_name,
-                                 _HVP_MIN_FUSED_COLS)
+        kernels = _fused_kernels("hvp", batch, axis_name)
         if kernels is not None:
             vector_sum, prefactor_sum = kernels.fused_hessian_vector_sums(
                 loss, False, batch.X, batch.labels, batch.offsets,

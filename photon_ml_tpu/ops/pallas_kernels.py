@@ -40,11 +40,28 @@ from photon_ml_tpu.ops.losses import PointwiseLoss
 Array = jnp.ndarray
 
 # VMEM budget: a [tile_rows, D] f32 tile must fit comfortably with double
-# buffering — target 4 MB per buffer (measured best at D=2048 on v5-class
-# HBM: tile 512 → ~394 GB/s single-pass vs ~270 GB/s for the 2-pass XLA
-# form).
+# buffering — target 4 MB per buffer (at D=2048 on a v5e, tile 512: a
+# value+gradient pass reads X once at 716 GB/s and a Hessian-vector pass at
+# 755 GB/s, where the two-pass XLA form manages 377 GB/s of one read;
+# ledger, PR 33, and PERF.md, PR 34).
 _TILE_BYTES = 4 * 1024 * 1024
 MAX_PALLAS_DIM = 4096
+
+
+# The fewest columns each fused form engages at. Under them the form loses
+# to the two-pass XLA body it replaces: a narrow tile's [T, 1] products at
+# "highest", its row vectors' relayout and the loss's transcendentals on
+# them cost more than the second read of X they save. Read on a v5e inside
+# the solvers' loops, 2.6 GB of f32 X, ms a pass fused / two-pass, squared |
+# logistic loss (PERF.md, PR 34; at the sweep cells' 65 columns the
+# value+gradient form reads 43.2 / 13.7 | 56.8 / 13.7). A bf16 X has no
+# reading of its own and takes the same rule:
+MIN_PALLAS_DIM = {
+    # 512 columns 6.18 / 6.88 | 8.33 / 6.88; 1,024: 4.33 / 7.15 | 5.24 / 7.16
+    "value_and_grad": 1024,
+    # 256 columns 6.05 / 6.88 | 10.63 / 10.34; 512: 3.92 / 6.87 | 6.88 / 6.88
+    "hvp": 512,
+}
 
 
 # Below this many elements the two-pass XLA form is already cache-resident;
@@ -66,9 +83,10 @@ def _tile_rows(d: int, itemsize: int = 4) -> int:
     return int(max(256, min(1024, (rows // 8) * 8)))
 
 
-def pallas_supported(n: int, d: int, dtype,
+def pallas_supported(form: str, n: int, d: int, dtype,
                      inside_shard_map: bool = False) -> bool:
-    """Gate for the fused kernel. ``inside_shard_map``: under an explicit
+    """Gate for the fused kernel's ``form`` (a key of ``MIN_PALLAS_DIM``) on
+    an [n, d] block. ``inside_shard_map``: under an explicit
     shard_map the computation is manually partitioned and per-shard shapes
     are local, so the kernel is safe on any device count; OUTSIDE one, a
     pallas_call is opaque to GSPMD (no partitioning rule) and would force a
@@ -87,7 +105,8 @@ def pallas_supported(n: int, d: int, dtype,
     if jnp.dtype(dtype) not in (jnp.dtype("float32"),
                                 jnp.dtype("bfloat16")):
         return False
-    return d <= MAX_PALLAS_DIM and n * d >= MIN_PALLAS_ELEMENTS
+    return (MIN_PALLAS_DIM[form] <= d <= MAX_PALLAS_DIM
+            and n * d >= MIN_PALLAS_ELEMENTS)
 
 
 def _zero_at_first_tile(i, vec_ref, *sum_refs):

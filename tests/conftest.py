@@ -56,3 +56,12 @@ def _reset_default_mesh():
 @pytest.fixture()
 def rng():
     return np.random.default_rng(42)
+
+
+@pytest.fixture
+def as_on_one_tpu(monkeypatch):
+    """Steer the kernel gate (ops/pallas_kernels.pallas_supported) the way
+    one attached chip would: its shape, width and dtype rules still run.
+    The program has no option for this; a test steers it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)

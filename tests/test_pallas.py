@@ -10,16 +10,19 @@ fast on a chip (that is chip_smoke.py and the benchmark, run on one).
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
-from photon_ml_tpu.data.batch import dense_batch
+from photon_ml_tpu.data.batch import DenseBatch, dense_batch
 from photon_ml_tpu.obs.metrics import REGISTRY
 from photon_ml_tpu.ops import pallas_kernels
 from photon_ml_tpu.ops.aggregators import GLMObjective, hessian_vector
 from photon_ml_tpu.ops.losses import LOSSES, get_loss
 from photon_ml_tpu.ops.normalization import NormalizationContext
 from photon_ml_tpu.ops.pallas_kernels import (
+    MIN_PALLAS_DIM,
     _xla_sums as _xla_sums_kernelmod,
+    fused_hessian_vector_sums,
     fused_value_gradient_sums,
     pallas_supported,
 )
@@ -72,8 +75,9 @@ def test_exact_tile_multiple():
 def test_gate_disabled_on_cpu():
     # Tests run on CPU, so the production gate must refuse (interpret mode
     # is only for testing).
-    assert not pallas_supported(1 << 20, 1024, jnp.float32)
-    assert not pallas_supported(1 << 20, 1024, jnp.bfloat16)
+    for form in MIN_PALLAS_DIM:
+        assert not pallas_supported(form, 1 << 20, 1024, jnp.float32)
+        assert not pallas_supported(form, 1 << 20, 1024, jnp.bfloat16)
 
 
 def test_fused_bf16_matches_f32_reference():
@@ -96,8 +100,6 @@ def test_fused_bf16_matches_f32_reference():
 def test_custom_vjp_differentiable():
     """jax.grad through the fused sums must work (falls back to the XLA
     formulation in the backward pass)."""
-    import jax
-
     loss = get_loss("logistic")
     X, y, off, wt, w = _case(300, 64, seed=2)
 
@@ -114,11 +116,12 @@ def test_custom_vjp_differentiable():
 
 
 @pytest.fixture
-def gate_forced_open(monkeypatch):
+def gate_forced_open(as_on_one_tpu, monkeypatch):
     """``aggregators`` takes the fused forms as on one chip at a real size,
-    the kernels in interpret mode (the program has no option for either)."""
-    monkeypatch.setattr(pallas_kernels, "pallas_supported",
-                        lambda *a, **kw: True)
+    the kernels in interpret mode (the program has no option for either).
+    The width rule stays as it is: a batch under a form's
+    ``MIN_PALLAS_DIM`` columns is two-pass here too."""
+    monkeypatch.setattr(pallas_kernels, "MIN_PALLAS_ELEMENTS", 0)
     for name in ("fused_value_gradient_sums", "fused_hessian_vector_sums"):
         real = getattr(pallas_kernels, name)
         monkeypatch.setattr(
@@ -133,8 +136,34 @@ def _hvp_norm(d, seed):
         shifts=jnp.asarray(rng.normal(size=d) * 0.3, jnp.float32))
 
 
+def _lowerings(scope):
+    counter = REGISTRY.counter("objective_lowerings")
+    return {form: counter.value(scope=scope, form=form)
+            for form in ("fused", "two_pass")}
+
+
+def _fused_hessian_vector(loss, norm, w, v, batch):
+    """``hessian_vector`` in the fused form: through the gate where the
+    batch is wide enough for it (and then the count says it was taken), the
+    kernel called as ``hessian_vector`` calls it where the width rule keeps
+    the program off it: ragged tiles and odd widths are the kernel's own
+    edge cases."""
+    if batch.X.shape[1] >= MIN_PALLAS_DIM["hvp"]:
+        before = _lowerings("objective.hvp")["fused"]
+        out = hessian_vector(loss, norm, w, v, batch)
+        assert _lowerings("objective.hvp")["fused"] == before + 1
+        return out
+    w_eff, margin_shift = norm.effective_coefficients(w)
+    v_eff, v_shift = norm.effective_coefficients(v)
+    return norm.reconstruct_gradient(*fused_hessian_vector_sums(
+        loss, True, batch.X, batch.labels, batch.offsets, batch.weights,
+        w_eff, margin_shift, v_eff, v_shift))
+
+
 # id: loss, rows, cols, normalization, X's dtype, zero-weight tail, rtol
 _HVP_CASES = {
+    "logistic-at-the-width-rule": ("logistic", 1100, MIN_PALLAS_DIM["hvp"],
+                                   True, "float32", 0, 2e-4),
     "logistic-ragged": ("logistic", 700, 128, False, "float32", 0, 2e-4),
     "squared-ragged": ("squared", 700, 128, False, "float32", 0, 2e-4),
     "poisson-ragged": ("poisson", 700, 128, False, "float32", 0, 2e-4),
@@ -142,6 +171,7 @@ _HVP_CASES = {
                              2e-4),
     "logistic-two-ragged-tiles": ("logistic", 2500, 64, False, "float32", 0,
                                   2e-4),
+    "logistic-odd-width": ("logistic", 1300, 65, True, "float32", 0, 2e-4),
     "logistic-factors-shifts": ("logistic", 700, 128, True, "float32", 0,
                                 2e-4),
     "poisson-factors-shifts": ("poisson", 1300, 96, True, "float32", 0,
@@ -156,17 +186,18 @@ _HVP_CASES = {
 
 @pytest.mark.parametrize("case", sorted(_HVP_CASES))
 def test_fused_hvp_matches_two_pass(case, gate_forced_open, monkeypatch):
-    """``hessian_vector`` through the fused form against its own two-pass
-    body, the reference semantics (float32 X in both; a bf16 X is held to
-    bf16's input rounding of the f32 reference)."""
+    """``hessian_vector`` in the fused form against its own two-pass body,
+    the reference semantics (float32 X in both; a bf16 X is held to bf16's
+    input rounding of the f32 reference)."""
     loss_name, n, d, normalized, dtype, padded, rtol = _HVP_CASES[case]
     loss = get_loss(loss_name)
     X, y, off, wt, w = _case(n, d, seed=len(case))
     v = np.random.default_rng(5).normal(size=d).astype(np.float32)
     wt[n - padded:] = 0.0
     norm = _hvp_norm(d, 11) if normalized else NormalizationContext()
-    fused = hessian_vector(loss, norm, jnp.asarray(w), jnp.asarray(v),
-                           dense_batch(X, y, off, wt, dtype=jnp.dtype(dtype)))
+    fused = _fused_hessian_vector(
+        loss, norm, jnp.asarray(w), jnp.asarray(v),
+        dense_batch(X, y, off, wt, dtype=jnp.dtype(dtype)))
     assert fused.dtype == jnp.float32
 
     monkeypatch.setattr(pallas_kernels, "pallas_supported",
@@ -202,13 +233,21 @@ def _tron_solve(loss_name, X, y, off, wt):
 def test_tron_solve_with_fused_product_lands_on_two_pass_solve(
         loss_name, gate_forced_open, monkeypatch):
     """A whole trust-region solve whose every product (and evaluation) is
-    the fused kernel, against the same solve in the two-pass forms. Column
-    scales over a decade, as the benchmark's dense cells have: 3 iterations
-    of 9-14 conjugate-gradient steps. (A tolerance at float32's floor would
+    the fused kernel, against the same solve in the two-pass forms, at the
+    narrowest width the program takes both fused forms at. Column scales
+    over a decade, as the benchmark's dense cells have: 3 iterations of
+    9-14 conjugate-gradient steps. (A tolerance at float32's floor would
     test which side's last step rounding refuses, not the product.)"""
-    X, y, off, wt, _ = _case(1500, 48, seed=4)
-    X = X * np.logspace(-0.5, 0.5, 48).astype(np.float32)
+    d = max(MIN_PALLAS_DIM.values())
+    X, y, off, wt, _ = _case(6 * d, d, seed=4)  # rows enough to condition it
+    X = X * np.logspace(-0.5, 0.5, d).astype(np.float32)
+    scopes = ("objective.hvp", "objective.value_and_grad")
+    before = {scope: _lowerings(scope) for scope in scopes}
     x_fused, iters_fused, hvps_fused = _tron_solve(loss_name, X, y, off, wt)
+    for scope in scopes:  # every call site of the solve took the kernel
+        after = _lowerings(scope)
+        assert after["fused"] > before[scope]["fused"], scope
+        assert after["two_pass"] == before[scope]["two_pass"], scope
     monkeypatch.setattr(pallas_kernels, "pallas_supported",
                         lambda *a, **kw: False)
     x_ref, iters_ref, hvps_ref = _tron_solve(loss_name, X, y, off, wt)
@@ -218,41 +257,68 @@ def test_tron_solve_with_fused_product_lands_on_two_pass_solve(
     assert abs(hvps_fused - hvps_ref) <= 1
 
 
-def _lowerings(scope):
-    counter = REGISTRY.counter("objective_lowerings")
-    return {form: counter.value(scope=scope, form=form)
-            for form in ("fused", "two_pass")}
-
-
-def test_objective_lowerings_books_the_form_traced(monkeypatch):
-    """One count a trace of ``hessian_vector`` / ``value_and_gradient``:
-    ``two_pass`` on the CPU, ``fused`` where the gate is open (for a
-    product: and the batch has a full lane tile of columns)."""
+@pytest.mark.parametrize("n,d", [(2500, 64), (1300, 65), (700, 96)])
+def test_fused_value_gradient_takes_narrow_and_odd_widths(n, d):
+    """The program keeps a batch under ``MIN_PALLAS_DIM`` columns off a
+    fused form because the form is slow there, not because it is wrong:
+    the kernel itself takes any width (ragged row tiles, a width that fills
+    no lane tile), against the two-pass sums. (``_HVP_CASES`` has the
+    product's narrow cases.)"""
     loss = get_loss("logistic")
-    X, y, off, wt, w = _case(300, 128, seed=6)
-    obj, batch = GLMObjective(loss), dense_batch(X, y, off, wt)
-    narrow = dense_batch(X[:, :65], y, off, wt)
-    w = jnp.asarray(w)
-    scopes = ("objective.hvp", "objective.value_and_grad")
+    X, y, off, wt, w = _case(n, d, seed=n + d)
+    batch = dense_batch(X, y, off, wt)
+    shift = jnp.float32(0.2)
+    value, vec, pre = fused_value_gradient_sums(
+        loss, True, batch.X, batch.labels, batch.offsets, batch.weights,
+        jnp.asarray(w), shift)
+    z = batch.margins(jnp.asarray(w), shift)
+    r = batch.weights * loss.d1(z, batch.labels)
+    assert float(value) == pytest.approx(
+        float(jnp.sum(batch.weights * loss.loss(z, batch.labels))), rel=2e-5)
+    want = np.asarray(batch.weighted_feature_sum(r))
+    np.testing.assert_allclose(np.asarray(vec), want, rtol=2e-4,
+                               atol=2e-4 * np.abs(want).max())
+    assert float(pre) == pytest.approx(float(jnp.sum(r)), rel=2e-4, abs=1e-3)
 
-    def booked_since(before):
-        return {scope: {form: count - before[scope][form]
-                        for form, count in _lowerings(scope).items()}
-                for scope in scopes}
 
-    before = {scope: _lowerings(scope) for scope in scopes}
-    obj.hessian_vector(w, w, batch)
-    obj.calculate(w, batch)
-    assert booked_since(before) == {
-        scope: {"fused": 0, "two_pass": 1} for scope in scopes}
+_TRACES = {"value_and_grad": lambda obj, w, b: obj.calculate(w, b),
+           "hvp": lambda obj, w, b: obj.hessian_vector(w, w, b)}
+# columns given the form's own rule, as on one chip, the form booked
+_LOWERING_CASES = {
+    "65-cols": (lambda rule: 65, True, "two_pass"),
+    "one-under-the-rule": (lambda rule: rule - 1, True, "two_pass"),
+    "at-the-rule": (lambda rule: rule, True, "fused"),
+    "2048-cols": (lambda rule: 2048, True, "fused"),
+    "2048-cols-on-the-cpu": (lambda rule: 2048, False, "two_pass"),
+}
 
-    monkeypatch.setattr(pallas_kernels, "pallas_supported",
-                        lambda *a, **kw: True)
-    monkeypatch.setattr(pallas_kernels, "fused_hessian_vector_sums",
-                        lambda *a: (jnp.zeros(128), jnp.zeros(())))
-    before = {scope: _lowerings(scope) for scope in scopes}
-    obj.hessian_vector(w, w, batch)
-    obj.hessian_vector(w[:65], w[:65], narrow)  # 65 columns: the XLA form
-    assert booked_since(before) == {
-        "objective.hvp": {"fused": 1, "two_pass": 1},
-        "objective.value_and_grad": {"fused": 0, "two_pass": 0}}
+
+@pytest.mark.parametrize("traced", sorted(_TRACES))
+@pytest.mark.parametrize("case", sorted(_LOWERING_CASES))
+def test_objective_lowerings_books_the_form_traced(case, traced, request):
+    """One count a trace of ``value_and_gradient`` / ``hessian_vector``, each
+    by its own width rule: ``two_pass`` on the CPU and, on one chip, under
+    the form's ``MIN_PALLAS_DIM`` columns (the sweep cells' 65-wide fixed
+    effect); ``fused`` from there on (the dense cells' 2,048). The batch is
+    traced at a real size (``MIN_PALLAS_ELEMENTS`` stands) and never run."""
+    columns, on_one_tpu, form = _LOWERING_CASES[case]
+    d = columns(MIN_PALLAS_DIM[traced])
+    if on_one_tpu:
+        request.getfixturevalue("as_on_one_tpu")
+    n = -(-pallas_kernels.MIN_PALLAS_ELEMENTS // d)
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    batch = DenseBatch(X=sds(n, d), labels=sds(n), offsets=sds(n),
+                       weights=sds(n))
+    obj = GLMObjective(get_loss("logistic"))
+    scopes = ["objective." + name for name in _TRACES]
+    before = {s: _lowerings(s) for s in scopes}
+    jax.eval_shape(lambda w, b: _TRACES[traced](obj, w, b), sds(d), batch)
+    booked = {s: {f: count - before[s][f]
+                  for f, count in _lowerings(s).items()} for s in scopes}
+    nothing = {"fused": 0, "two_pass": 0}
+    assert booked == {
+        s: dict(nothing, **{form: 1}) if s == "objective." + traced
+        else nothing for s in scopes}

@@ -53,6 +53,7 @@ from photon_ml_tpu.ops.aggregators import GLMObjective
 from photon_ml_tpu.ops.losses import get_loss
 from photon_ml_tpu.ops.pallas_kernels import (
     MAX_PALLAS_DIM,
+    MIN_PALLAS_DIM,
     fused_hessian_vector_sums,
     fused_value_gradient_sums,
 )
@@ -138,14 +139,6 @@ def _as_the_program_runs():
 
 
 @pytest.fixture
-def as_on_one_tpu(monkeypatch):
-    """Steer the kernel gate (ops/pallas_kernels.pallas_supported) the way
-    one attached chip would: its shape and dtype rules still run."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(jax, "device_count", lambda: 1)
-
-
-@pytest.fixture
 def as_on_tpu_mesh(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
@@ -195,8 +188,8 @@ def test_fused_kernel_compiles(one_chip, n, d, dtype):
 @KERNEL_SHAPES
 def test_fused_hvp_compiles(one_chip, n, d, dtype):
     """The Hessian-vector form at the value+gradient form's shapes (the
-    program engages it at 128 columns or more; the kernel itself takes the
-    65-wide block too)."""
+    program engages each from its ``MIN_PALLAS_DIM`` columns on; the
+    kernels themselves take the 65-wide block too)."""
     loss = get_loss("logistic")
     b = _dense(n, d, one_chip, jnp.dtype(dtype))
     vec = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=one_chip)
@@ -218,6 +211,32 @@ def test_lbfgs_solve_compiles_with_kernel_in_loop(one_chip, as_on_one_tpu):
         problem.objective(), _dense(n, d, one_chip), x0).compile()
     text = compiled.as_text()
     assert MOSAIC_CALL in text and "while" in text
+
+
+@pytest.mark.parametrize("n", [10_000_054, 5_046_676])
+def test_narrow_fixed_effect_solve_compiles_two_pass(one_chip, as_on_one_tpu,
+                                                     n):
+    """The sweep cells' fixed-effect solve at the cells' own shapes
+    (benchmark/configs/glmix-ml10m.json, game-ml20m.json: 64 global
+    features + intercept): under ``MIN_PALLAS_DIM`` columns the gate keeps
+    both loops off the kernel, so
+    no Mosaic call, the two halves of a two-pass evaluation under their
+    scopes, and no more temporary memory than the row-major, lane-padded
+    copy of X that the loops carry in either form (the fused form's
+    temporaries read 5,242,291,200 bytes at 10,000,054 rows, this form's
+    5,121,770,496: PERF.md, PR 34)."""
+    d = GLMIX_FIXED_DIM
+    assert d < MIN_PALLAS_DIM["value_and_grad"]
+    problem = _l2_problem(6, 1e-30, 10.0)
+    x0 = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(problem.solve).lower(
+        problem.objective(), _dense(n, d, one_chip), x0).compile()
+    text = compiled.as_text()
+    assert MOSAIC_CALL not in text and "while" in text
+    assert "objective.margins" in text and "objective.feature_sum" in text
+    padded_copy = n * 128 * 4
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < 1.005 * padded_copy < 5_242_291_200 * n / 10_000_054)
 
 
 def test_tron_solve_compiles_with_kernel_in_cg_loop(one_chip, as_on_one_tpu):
@@ -268,26 +287,30 @@ def test_donating_random_effect_fit_compiles(one_chip, variant):
     assert e * n * 4 <= aliased < 2 * e * n * 4, (variant, aliased)
 
 
-def test_sharded_fixed_effect_step_compiles(mesh, as_on_tpu_mesh):
+@pytest.mark.parametrize("d", [GLMIX_FIXED_DIM, GLM_SHAPE[1]])
+def test_sharded_fixed_effect_step_compiles(mesh, as_on_tpu_mesh, d):
     """``chip_smoke.py --chips 4``'s fixed-effect update: the solver inside
-    ``shard_map`` over the data axis of a 2x2 mesh, the fused kernel on
-    each shard, the weight update sharded (what --re-entity-shards sets)."""
+    ``shard_map`` over the data axis of a 2x2 mesh, the weight update
+    sharded (what --re-entity-shards sets). The gate's width rule holds per
+    shard: at the smoke's 65 columns each shard's passes are two-pass XLA,
+    at the dense width the fused kernel runs on each shard."""
     from photon_ml_tpu.parallel.distributed import sharded_fit
 
     problem = _l2_problem(40, 1e-7, 10.0, shard_weight_update=True)
-    batch = _dense(MESH_ROWS, GLMIX_FIXED_DIM,
-                   NamedSharding(mesh, P(DATA_AXIS)))
-    x0 = jax.ShapeDtypeStruct((GLMIX_FIXED_DIM,), jnp.float32,
+    batch = _dense(MESH_ROWS, d, NamedSharding(mesh, P(DATA_AXIS)))
+    x0 = jax.ShapeDtypeStruct((d,), jnp.float32,
                               sharding=NamedSharding(mesh, P()))
     fit, shard_update = sharded_fit(problem, batch, mesh, jnp.float32)
     assert shard_update
     compiled = jax.jit(fit).lower(batch, x0).compile()
     text = compiled.as_text()
-    assert MOSAIC_CALL in text
+    fused = d >= MIN_PALLAS_DIM["value_and_grad"]
+    assert (MOSAIC_CALL in text) == fused
+    assert ("objective.margins" in text) == (not fused)
     assert "all-reduce" in text
     per_device = compiled.memory_analysis().argument_size_in_bytes
     # rows split over the data axis: each device holds half of X
-    assert per_device < 0.6 * MESH_ROWS * GLMIX_FIXED_DIM * 4 * 1.1
+    assert per_device < 0.6 * MESH_ROWS * d * 4 * 1.1
 
 
 def test_sharded_random_effect_fit_compiles(mesh):
