@@ -13,8 +13,8 @@ Two layouts:
   narrow-to-medium feature spaces (the reference densifies per-entity blocks
   the same way after projection).
 - :class:`EllBatch`  — padded row-sparse (ELL) layout, held **slot-major**
-  on the device: ``indices``/``values`` of shape ``[K, N]`` with ``K`` = max
-  nnz per row, padded entries pointing at a dummy column with value 0.
+  on the device: ``indices``/``values`` of shape ``[K, N]``, padded entries
+  pointing at a dummy column with value 0.
   A pass walks the slots: margins gather one slot of every row at a time
   into an ``[N]`` accumulator, gradients scatter-add one slot at a time
   into a ``[D]`` one. Right for wide sparse spaces (reference policy switches
@@ -22,7 +22,11 @@ Two layouts:
   because a TPU tiles a 32-bit array (8, 128) over its two minor
   dimensions: a solver loop re-lays row-major ``[N, 39]`` planes with every
   row padded to 128 lanes, 3.3x their bytes, where ``[39, N]`` pads 39 to
-  40 sublanes (PERF.md, PR 29).
+  40 sublanes (PERF.md, PR 29). Where the rows differ in length the
+  layout holds them longest first, and further blocks of slots over the
+  rows long enough to reach them (``[K_1 - K_0, n_1]``, ...), so that a
+  pass walks about the stored non-zeros and not rows x longest
+  (:func:`ell_block_bounds`); rows of one length give the one block.
 
 - :class:`ProjectionRefitBatch` — the factored random effect's projection
   refit: the rows are those of per-entity blocks ``[E, N, D_b]`` in each
@@ -129,6 +133,34 @@ class EllBatch:
     :func:`ell_from_rows`, or from arrays already in this layout with
     :func:`ell_batch`.
 
+    **Rows of uneven length.** A padded slot costs a pass what a stored one
+    costs, so where the rows differ in length the layout holds them
+    **longest first** and in several blocks of slots: ``indices``/``values``
+    are slots ``[0, K_0)`` of all N rows, and ``tail`` holds further
+    ``(indices, values)`` blocks ``[1, K_g - K_(g-1), n_g]``, slots
+    ``[K_(g-1), K_g)`` of the first ``n_g`` rows, the only ones long enough
+    to reach them (``n_1 > n_2 > ...``). A pass walks block after block with
+    the same loops, each over its own rows: ``sum_g (K_g - K_(g-1)) n_g``
+    slots, which :func:`ell_block_bounds` keeps near the stored non-zeros.
+    ``order`` is then the permutation: the row at place ``p`` of the planes
+    is the caller's row ``order[p]``. **The caller's order holds at the
+    surface**: ``labels``, ``offsets`` and ``weights`` are in it, ``margins``
+    returns it and the column sums take row scalars in it, at the price of
+    one scatter or gather of N elements a call. A solve reads the rows
+    through sums alone, so it asks once for :func:`rows_in_layout_order`
+    (the row vectors permuted, ``order`` gone) and pays nothing per
+    evaluation. Rows of one length give ``tail == ()`` and ``order is
+    None``: the one block, and the program it always was.
+
+    **On a mesh** the rows are split into S equal runs, one a shard
+    (:func:`deal_rows`: the rows longest first dealt round-robin, so that
+    every run is again longest first and holds its share of every length).
+    A tail block is then ``[S, K_g - K_(g-1), m_g]``: its leading axis is
+    the run, the axis a mesh shards, and it covers the first ``m_g`` rows of
+    every run. One shard of such a batch is the ``S == 1`` batch of its own
+    rows, so ``shard_map`` hands every device the single-device program;
+    off ``shard_map`` the methods read S from the blocks' shape.
+
     Padded slots must satisfy ``values == 0`` (their index value is then
     irrelevant for margins; for scatter ops we still route them to a real
     column but the zero value contributes nothing).
@@ -139,17 +171,21 @@ class EllBatch:
     """
 
     def __init__(self, indices: Array, values: Array, labels: Array,
-                 offsets: Array, weights: Array, dim: int):
-        self.indices = indices  # [K, N] int32
-        self.values = values  # [K, N]
+                 offsets: Array, weights: Array, tail=(), order=None, *,
+                 dim: int):
+        self.indices = indices  # [K_0, N] int32
+        self.values = values  # [K_0, N]
         self.labels = labels  # [N]
         self.offsets = offsets  # [N]
         self.weights = weights  # [N]
+        # ((indices, values) [S, K_g - K_(g-1), m_g], ...), m_g falling
+        self.tail = tuple((ix, v) for ix, v in tail)
+        self.order = order  # [N] int32: place in the planes -> caller's row
         self.dim = dim  # D, static
 
     def tree_flatten(self):
         return ((self.indices, self.values, self.labels, self.offsets,
-                 self.weights), self.dim)
+                 self.weights, self.tail, self.order), self.dim)
 
     @classmethod
     def tree_unflatten(cls, dim, leaves):
@@ -158,9 +194,10 @@ class EllBatch:
     def _replace(self, **kw):
         fields = dict(indices=self.indices, values=self.values,
                       labels=self.labels, offsets=self.offsets,
-                      weights=self.weights, dim=self.dim)
+                      weights=self.weights, tail=self.tail,
+                      order=self.order, dim=self.dim)
         fields.update(kw)
-        return EllBatch(**fields)
+        return type(self)(**fields)
 
     @property
     def num_features(self) -> int:
@@ -171,37 +208,146 @@ class EllBatch:
         """Solver/accumulator dtype (see DenseBatch.acc_dtype)."""
         return jnp.promote_types(self.values.dtype, jnp.float32)
 
+    @property
+    def blocks(self) -> tuple:
+        """Every ``(indices, values)`` block of slots, the one over all rows
+        first."""
+        return ((self.indices, self.values),) + self.tail
+
+    @property
+    def walked_slots(self) -> int:
+        """Slots a pass walks: every block's, padding included."""
+        return sum(int(np.prod(ix.shape)) for ix, _ in self.blocks)
+
+    def _tail_prefixes(self):
+        """For every tail block: its planes as ``[K, S * m_g]`` (run after
+        run along the rows, as block 0 holds them) and ``take`` / ``put``,
+        which read and write the ``S * m_g`` rows it covers, the first
+        ``m_g`` of every run, in a vector over all rows in the planes'
+        order. One run needs no reshaping: a slice of the first ``m_g``
+        (said apart because the TPU compiler otherwise re-lays every
+        ``[1, m_g]`` row of a plane to ``[m_g]`` inside the slot loop)."""
+        for indices, values in self.tail:
+            runs, k, m = indices.shape
+            if runs == 1:
+                yield (indices[0], values[0], lambda v, m=m: v[:m],
+                       lambda v, part, m=m: v.at[:m].set(part))
+                continue
+
+            def flat(plane, runs=runs, k=k, m=m):
+                return plane.transpose(1, 0, 2).reshape(k, runs * m)
+
+            def take(v, runs=runs, m=m):
+                return v.reshape(runs, -1)[:, :m].reshape(runs * m)
+
+            def put(v, part, runs=runs, m=m):
+                return lax.dynamic_update_slice(
+                    v.reshape(runs, -1), part.reshape(runs, m),
+                    (0, 0)).reshape(v.shape)
+
+            yield flat(indices), flat(values), take, put
+
     def margins(self, w_eff: Array, margin_shift: Array) -> Array:
         with jax.named_scope(MARGINS_SCOPE):
-            def add_slot(k, z):
-                return z + w_eff[self.indices[k]] * self.values[k]
+            def walk(indices, values, z):
+                def add_slot(k, z):
+                    return z + w_eff[indices[k]] * values[k]
 
-            z = jnp.zeros(self.indices.shape[1:],
-                          jnp.result_type(w_eff, self.values))
-            return (
-                lax.fori_loop(0, self.indices.shape[0], add_slot, z)
-                + margin_shift
-                + self.offsets
-            )
+                return lax.fori_loop(0, indices.shape[0], add_slot, z)
+
+            z = walk(self.indices, self.values,
+                     jnp.zeros(self.indices.shape[1:],
+                               jnp.result_type(w_eff, self.values)))
+            for indices, values, take, put in self._tail_prefixes():
+                z = put(z, walk(indices, values, take(z)))
+            if self.order is not None:
+                z = jnp.zeros_like(z).at[self.order].set(
+                    z, unique_indices=True)
+            return z + margin_shift + self.offsets
 
     def _column_sums(self, row_scalars: Array, square: bool) -> Array:
         """sum_i row_scalars_i * values[:, i] (or their squares), each slot
-        into its column: the scatter-add over all K x N stored slots."""
+        into its column: the scatter-add over every block's slots."""
         with jax.named_scope(FEATURE_SUM_SCOPE):
-            def add_slot(k, sums):
-                v = self.values[k]
-                return sums.at[self.indices[k]].add(
-                    (v * v if square else v) * row_scalars)
+            if self.order is not None:
+                row_scalars = row_scalars[self.order]
 
-            sums = jnp.zeros((self.dim,),
-                             jnp.result_type(self.values, row_scalars))
-            return lax.fori_loop(0, self.indices.shape[0], add_slot, sums)
+            def walk(indices, values, r, sums):
+                def add_slot(k, sums):
+                    v = values[k]
+                    return sums.at[indices[k]].add(
+                        (v * v if square else v) * r)
+
+                return lax.fori_loop(0, indices.shape[0], add_slot, sums)
+
+            sums = walk(self.indices, self.values, row_scalars,
+                        jnp.zeros((self.dim,),
+                                  jnp.result_type(self.values, row_scalars)))
+            for indices, values, take, _ in self._tail_prefixes():
+                sums = walk(indices, values, take(row_scalars), sums)
+            return sums
 
     def weighted_feature_sum(self, row_scalars: Array) -> Array:
         return self._column_sums(row_scalars, square=False)
 
     def hadamard_square_sum(self, row_scalars: Array) -> Array:
         return self._column_sums(row_scalars, square=True)
+
+
+def rows_in_layout_order(batch: "Batch") -> "Batch":
+    """``batch`` for a consumer that only sums over its rows (a solve): an
+    ELL layout that holds the rows in an order of its own hands over
+    ``labels``, ``offsets`` and ``weights`` in that order, with no ``order``
+    left, once, so that no evaluation permutes anything (the margins of the
+    result are in that order too); every other batch as it is."""
+    if not isinstance(batch, EllBatch) or batch.order is None:
+        return batch
+    return batch._replace(labels=batch.labels[batch.order],
+                          offsets=batch.offsets[batch.order],
+                          weights=batch.weights[batch.order], order=None)
+
+
+def deal_rows(batch: "Batch", shards: int) -> "Batch":
+    """``batch`` with its rows in ``shards`` equal runs, for a mesh that
+    gives every shard one run (:func:`row_partition_specs`). Only an ELL
+    layout of several blocks of slots has anything to do: its rows lie
+    longest first, and place ``p`` goes to run ``p % shards``, so every
+    run is again longest first, a tail block covers the first
+    ``ceil(n_g / shards)`` rows of every run, and the runs' work is equal
+    to within a row a block. The caller's order stays at the surface
+    (``order`` is dealt with the planes; a batch already in layout order
+    has its row vectors dealt). ``shards`` must divide the rows
+    (:func:`pad_batch` first)."""
+    if not isinstance(batch, EllBatch) or not batch.tail:
+        return batch
+    have = batch.tail[0][0].shape[0]
+    if have == shards:
+        return batch
+    n = batch.labels.shape[0]
+    if have != 1 or n % shards:
+        raise ValueError(
+            f"an ELL batch of {n} rows in {have} run(s) cannot be dealt "
+            f"into {shards}: deal a batch of one run whose rows {shards} "
+            "divides (pad_batch first)")
+
+    def deal(plane):  # rows on the last axis: place p -> run p % shards
+        lead = plane.shape[:-1]
+        return plane.reshape(lead + (-1, shards)).swapaxes(-1, -2).reshape(
+            lead + (-1,))
+
+    def deal_tail(plane):  # [1, K, n_g] -> [shards, K, ceil(n_g / shards)]
+        _, k, n_g = plane.shape
+        plane = jnp.pad(plane[0], ((0, 0), (0, -n_g % shards)))
+        return plane.reshape(k, -1, shards).transpose(2, 0, 1)
+
+    rows = {}
+    if batch.order is None:
+        rows = dict(labels=deal(batch.labels), offsets=deal(batch.offsets),
+                    weights=deal(batch.weights))
+    return batch._replace(
+        indices=deal(batch.indices), values=deal(batch.values),
+        tail=tuple((deal_tail(ix), deal_tail(v)) for ix, v in batch.tail),
+        order=None if batch.order is None else deal(batch.order), **rows)
 
 
 # ``jax.named_scope`` of the gather of each entity's columns of the shared
@@ -416,11 +562,16 @@ def ell_batch(
 def row_partition_specs(batch: "Batch", axis: str):
     """A pytree shaped like ``batch`` of ``PartitionSpec``s that shard the
     rows over mesh axis ``axis``: the leading axis of every leaf, but the
-    minor axis of the ELL planes."""
+    minor axis of the ELL planes; of an ELL layout's further blocks of
+    slots the leading axis too, which is the run (:func:`deal_rows` makes
+    as many runs as the axis has shards)."""
     by_row = PartitionSpec(axis)
     if isinstance(batch, EllBatch):
         by_slot_row = PartitionSpec(None, axis)
+        by_run = PartitionSpec(axis, None, None)  # a tail block: S first
         return EllBatch(by_slot_row, by_slot_row, by_row, by_row, by_row,
+                        tail=((by_run, by_run),) * len(batch.tail),
+                        order=None if batch.order is None else by_row,
                         dim=batch.dim)
     return DenseBatch(by_row, by_row, by_row, by_row)
 
@@ -434,6 +585,134 @@ def canonicalized_csr(mat):
         mat = mat.copy()
         mat.sum_duplicates()
     return mat
+
+
+# An ELL layout's blocks of slots end at multiples of ``_SUBLANES`` (a 32-bit
+# plane is tiled (8, 128), so 8 slots are stored whether used or not), and
+# there are at most ``_MAX_BLOCKS`` of them. Read on a v5e over the KDD-2010
+# rows (142.6M non-zeros, one value+gradient pass; PERF.md, PR 35): 2 blocks
+# 7.51 s, 4 4.85 s, 6 4.24 s, 8 4.02 s, 12 3.93 s, 16 3.92 s. A block has no
+# fixed cost the chip shows (the pass follows the slots down to 16 blocks);
+# the cap is there for the program's size, two loops a block a pass, and
+# eight blocks are within 2.5% of sixteen.
+_SUBLANES = 8
+_MAX_BLOCKS = 8
+
+
+def ell_block_bounds(lengths: np.ndarray, multiple: int = _SUBLANES) -> list:
+    """Where the blocks of slots of an ELL layout end, from the rows'
+    lengths alone: ascending multiples of ``multiple``, the last the longest
+    row's length rounded up. A row of length l is walked up to the first
+    bound at or over l, so the bounds are chosen to make ``sum_rows
+    bound(l)`` least over at most ``_MAX_BLOCKS`` blocks: a dynamic
+    programme over the histogram of the rounded lengths, the fewest blocks
+    among equals. Rows of one length give one bound; a log-normal law of
+    mean 29 cut at 128 gives 8 and walks 1.14x its non-zeros where one
+    block walks 4.3x (PERF.md, PR 35). Never more slots than one block:
+    that is always among the choices."""
+    lengths = np.asarray(lengths)
+    if not lengths.size:
+        return [multiple]
+    groups = -(-np.maximum(lengths, 1) // multiple)  # length in multiples
+    hist = np.bincount(groups)
+    cand = np.nonzero(hist)[0]
+    bound = cand * multiple
+    rows_upto = np.cumsum(hist)[cand]  # rows no longer than bound[j]
+    # best[b, j]: least slots walked by the rows up to bound[j] in b + 1
+    # blocks whose last ends there
+    blocks = min(_MAX_BLOCKS, cand.size)
+    best = np.full((blocks, cand.size), np.inf)
+    came_from = np.zeros((blocks, cand.size), np.int64)
+    best[0] = bound * rows_upto
+    for b in range(1, blocks):
+        for j in range(b, cand.size):
+            i = np.arange(b - 1, j)
+            walked = best[b - 1, i] + bound[j] * (rows_upto[j] - rows_upto[i])
+            came_from[b, j] = i[np.argmin(walked)]
+            best[b, j] = walked.min()
+    b = int(np.argmin(best[:, -1]))
+    out, j = [], cand.size - 1
+    for b in range(b, -1, -1):
+        out.append(int(bound[j]))
+        j = came_from[b, j]
+    return out[::-1]
+
+
+def _pack_block(indptr, cols, data, rows, lo: int, hi: int, stage):
+    """Slots ``[lo, hi)`` of the CSR rows ``rows`` (``None``: all, in
+    order) as row-major ``[len(rows), hi - lo]`` planes, zeros where a row
+    ends sooner: the (row, slot) coordinate of every stored element in
+    bulk from ``indptr``, no per-row Python loop."""
+    starts = indptr[:-1] if rows is None else indptr[rows]
+    ends = indptr[1:] if rows is None else indptr[rows + 1]
+    count = np.clip(ends - starts - lo, 0, hi - lo)
+    indices = np.zeros((len(count), hi - lo), dtype=np.int32)
+    values = np.zeros((len(count), hi - lo), dtype=stage)
+    total = int(count.sum())
+    if total:
+        row_of = np.repeat(np.arange(len(count), dtype=np.int32), count)
+        slot_of = (np.arange(total, dtype=np.int64)
+                   - np.repeat(np.cumsum(count) - count, count))
+        source = np.repeat(starts + lo, count) + slot_of
+        indices[row_of, slot_of] = cols[source]
+        values[row_of, slot_of] = data[source]
+    return indices, values
+
+
+def _ell_from_csr_arrays(indptr, cols, data, dim: int, labels, offsets,
+                         weights, pad_to_multiple: int, dtype) -> EllBatch:
+    """The layout of a CSR matrix given as its three arrays: the blocks'
+    bounds from the rows' lengths (:func:`ell_block_bounds`), the rows
+    longest first where there is more than one block, every block packed
+    on the host and placed slot-major. Books what a pass will walk against
+    what the matrix stores (``ell_walked_slots`` / ``ell_stored_slots``)."""
+    from photon_ml_tpu.obs import trace
+    from photon_ml_tpu.obs.metrics import REGISTRY
+
+    indptr = np.asarray(indptr, np.int64)
+    n = len(indptr) - 1
+    lens = np.diff(indptr)
+    nnz = int(indptr[-1]) if n else 0
+    bounds = ell_block_bounds(lens, pad_to_multiple)
+    meta = jnp.promote_types(dtype, jnp.float32)
+    # Host staging in the narrowest exact container (f64 only when asked).
+    stage = np.float64 if meta == jnp.float64 else np.float32
+    with trace.span("ell.build", rows=n, nonzeros=nnz, blocks=len(bounds)):
+        order = None
+        if len(bounds) > 1:
+            order = np.argsort(-lens, kind="stable").astype(np.int32)
+        planes, lo = [], 0
+        for hi in bounds:
+            if order is None:  # one block over the rows as they come
+                rows = None
+            else:  # the rows that reach slot ``lo``, longest first
+                rows = order[:n if lo == 0 else np.count_nonzero(lens > lo)]
+            indices = values = None
+            if rows is None and nnz and stage == np.float32:
+                from photon_ml_tpu.io.native_loader import pack_ell_native
+
+                indices = np.zeros((n, hi), dtype=np.int32)
+                values = np.zeros((n, hi), dtype=stage)
+                if not pack_ell_native(indptr, cols, data, hi, indices,
+                                       values):
+                    indices = None
+            if indices is None:
+                indices, values = _pack_block(indptr, cols, data, rows, lo,
+                                              hi, stage)
+            # host planes are packed row-major (the native packer's form);
+            # the device holds them slot-major
+            planes.append((jnp.asarray(indices.T, jnp.int32),
+                           jnp.asarray(values.T, dtype)))
+            lo = hi
+    batch = ell_batch(*planes[0], labels, dim, offsets, weights, dtype=dtype)
+    if order is not None:  # the further blocks: one run of rows, [1, K, n]
+        batch = batch._replace(
+            tail=tuple((ix[None], v[None]) for ix, v in planes[1:]),
+            order=jnp.asarray(order))
+    REGISTRY.counter("ell_stored_slots").inc(nnz, blocks=len(bounds))
+    REGISTRY.counter("ell_walked_slots").inc(batch.walked_slots,
+                                             blocks=len(bounds))
+    return batch
 
 
 def ell_from_csr(
@@ -450,33 +729,14 @@ def ell_from_csr(
     from the CSR ``indptr`` — no per-row Python loop — so packing a
      10M-row shard is a handful of NumPy ops (the ingestion-scale analog of
     the reference's distributed build,
-    data/RandomEffectDataSet.scala:169-206).
+    data/RandomEffectDataSet.scala:169-206). The rows' lengths decide the
+    blocks of slots (:func:`ell_block_bounds`, bounds at multiples of
+    ``pad_to_multiple``): rows of one length give the one ``[K, N]``
+    block, rows of uneven length several, held longest first.
     """
-    n, dim = mat.shape
-    indptr = np.asarray(mat.indptr)
-    lens = np.diff(indptr)
-    k = int(lens.max()) if n else 1
-    k = max(1, -(-max(k, 1) // pad_to_multiple) * pad_to_multiple)
-    meta = jnp.promote_types(dtype, jnp.float32)
-    stage = np.float64 if meta == jnp.float64 else np.float32
-    indices = np.zeros((n, k), dtype=np.int32)
-    values = np.zeros((n, k), dtype=stage)
-    if mat.nnz:
-        packed = False
-        if stage == np.float32:
-            from photon_ml_tpu.io.native_loader import pack_ell_native
-
-            packed = pack_ell_native(indptr, mat.indices, mat.data, k,
-                                     indices, values)
-        if not packed:
-            row_of = np.repeat(np.arange(n), lens)
-            slot_of = np.arange(mat.nnz) - np.repeat(indptr[:-1], lens)
-            indices[row_of, slot_of] = mat.indices
-            values[row_of, slot_of] = mat.data
-    # host planes are packed row-major (the native packer's form); the
-    # device holds them slot-major
-    return ell_batch(indices.T, values.T, labels, dim, offsets, weights,
-                     dtype=dtype)
+    return _ell_from_csr_arrays(mat.indptr, np.asarray(mat.indices),
+                                np.asarray(mat.data), mat.shape[1], labels,
+                                offsets, weights, pad_to_multiple, dtype)
 
 
 def ell_from_rows(
@@ -488,26 +748,19 @@ def ell_from_rows(
     pad_to_multiple: int = 8,
     dtype=jnp.float32,
 ) -> EllBatch:
-    """Build an ELL batch from per-row (indices, values) sparse rows.
-
-    K is padded up to a multiple of ``pad_to_multiple`` to stabilize compiled
-    shapes across similar batches.
+    """Build an ELL batch from per-row (indices, values) sparse rows: the
+    layout :func:`ell_from_csr` gives the same matrix. Block bounds are
+    multiples of ``pad_to_multiple``, which stabilizes compiled shapes
+    across similar batches.
     """
-    n = len(rows)
-    k = max((len(ix) for ix, _ in rows), default=1)
-    k = max(1, -(-k // pad_to_multiple) * pad_to_multiple)
-    meta = jnp.promote_types(dtype, jnp.float32)
-    # Host staging in the narrowest exact container (f64 only when asked).
-    stage = np.float64 if meta == jnp.float64 else np.float32
-    indices = np.zeros((n, k), dtype=np.int32)
-    values = np.zeros((n, k), dtype=stage)
-    for i, (ix, v) in enumerate(rows):
-        indices[i, : len(ix)] = ix
-        values[i, : len(v)] = v
-    # host planes are packed row-major (the native packer's form); the
-    # device holds them slot-major
-    return ell_batch(indices.T, values.T, labels, dim, offsets, weights,
-                     dtype=dtype)
+    indptr = np.zeros(len(rows) + 1, np.int64)
+    np.cumsum([len(ix) for ix, _ in rows], out=indptr[1:])
+    cols = np.concatenate([np.asarray(ix, np.int32) for ix, _ in rows]
+                          + [np.zeros(0, np.int32)])
+    data = np.concatenate([np.asarray(v, np.float64) for _, v in rows]
+                          + [np.zeros(0, np.float64)])
+    return _ell_from_csr_arrays(indptr, cols, data, dim, labels, offsets,
+                                weights, pad_to_multiple, dtype)
 
 
 def pad_batch(batch: Batch, target_rows: int) -> Batch:
@@ -529,9 +782,20 @@ def pad_batch(batch: Batch, target_rows: int) -> Batch:
     if isinstance(batch, DenseBatch):
         return DenseBatch(X=jnp.pad(batch.X, ((0, pad), (0, 0))), **meta)
     # ELL: padded rows point at column 0 with value 0 — inert in every sum.
-    return EllBatch(
+    # They are the shortest rows there can be, so they lie last in the
+    # planes, behind every row the further blocks of slots cover: last in
+    # the one run of rows, so a batch is padded before it is dealt.
+    if batch.tail and batch.tail[0][0].shape[0] != 1:
+        raise ValueError(
+            f"an ELL batch dealt into {batch.tail[0][0].shape[0]} runs of "
+            "rows cannot be padded: pad_batch before deal_rows")
+    order = batch.order
+    if order is not None:
+        order = jnp.concatenate(
+            [order, jnp.arange(n, target_rows, dtype=order.dtype)])
+    return batch._replace(
         indices=jnp.pad(batch.indices, ((0, 0), (0, pad))),
         values=jnp.pad(batch.values, ((0, 0), (0, pad))),
-        dim=batch.dim,
+        order=order,
         **meta,
     )

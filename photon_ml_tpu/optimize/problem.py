@@ -29,7 +29,7 @@ from typing import Optional
 
 import jax.numpy as jnp
 
-from photon_ml_tpu.data.batch import Batch
+from photon_ml_tpu.data.batch import Batch, rows_in_layout_order
 from photon_ml_tpu.models.glm import Coefficients, GeneralizedLinearModel
 from photon_ml_tpu.obs import trace
 from photon_ml_tpu.ops.aggregators import GLMObjective
@@ -145,7 +145,10 @@ class GLMOptimizationProblem:
         callables, and a pre-sliced L1 mask; every d-vector reduction
         inside the solver then psums over the axis."""
         cfg = self.config
-        payload = (obj, batch)
+        # a solve reads the rows through sums alone: a layout that holds
+        # them in an order of its own hands the row vectors over in that
+        # order here, once, and no evaluation permutes anything
+        payload = (obj, rows_in_layout_order(batch))
         vg = _objective_vg if vg_fn is None else vg_fn
         hvp = _objective_hvp if hvp_fn is None else hvp_fn
         mask = self.l1_mask if l1_mask is None else l1_mask

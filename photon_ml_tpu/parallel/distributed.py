@@ -40,7 +40,13 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from photon_ml_tpu.data.batch import Batch, pad_batch, row_partition_specs
+from photon_ml_tpu.data.batch import (
+    Batch,
+    deal_rows,
+    pad_batch,
+    row_partition_specs,
+    rows_in_layout_order,
+)
 from photon_ml_tpu.models.glm import GeneralizedLinearModel
 from photon_ml_tpu.optimize.common import OptimizationResult, solver_x0
 from photon_ml_tpu.optimize.problem import GLMOptimizationProblem
@@ -71,7 +77,10 @@ def run_glm_shard_map(
     mesh ``data`` axis. Works for both batch layouts (DenseBatch, EllBatch:
     ``data/batch.row_partition_specs`` names each leaf's row axis). Rows
     not divisible by the data-axis size are padded with zero-weight rows
-    here.
+    here, and an ELL layout of several blocks of slots is dealt into one
+    run of rows a shard (``data/batch.deal_rows``), its row vectors handed
+    over in the layout's order before the split: a shard cannot look up a
+    global permutation.
 
     With ``problem.shard_weight_update`` set, the optimizer state and the
     coefficient update are additionally sharded over the SAME data axis
@@ -88,8 +97,9 @@ def run_glm_shard_map(
 
     dim = batch.num_features
     x0 = solver_x0(batch.acc_dtype, dim, initial)
-    fit, shard_update = sharded_fit(problem, batch, mesh, x0.dtype)
-    x, history, progressed = jax.jit(fit)(batch, x0)
+    shards = deal_rows(rows_in_layout_order(batch), n_shards)
+    fit, shard_update = sharded_fit(problem, shards, mesh, x0.dtype)
+    x, history, progressed = jax.jit(fit)(shards, x0)
 
     # Host-side collective-traffic ledger (collectives run inside the
     # jitted loop where counting is impossible): one d-vector gradient
@@ -120,7 +130,8 @@ def sharded_fit(problem: GLMOptimizationProblem, batch: Batch, mesh,
     weight update engaged. Only the batch's structure and width are read,
     so its leaves may be arrays or ``jax.ShapeDtypeStruct``s (ahead-of-time
     compiles for a described topology, tests/test_tpu_compile.py). Rows
-    must already divide the mesh data axis."""
+    must already divide the mesh data axis, an ELL layout of several
+    blocks be dealt over it and in its own row order."""
     n_shards = mesh.shape[DATA_AXIS]
     dim = batch.num_features
     # psum-ing objective: every reduction crosses the data axis.

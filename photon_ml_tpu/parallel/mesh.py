@@ -33,6 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from photon_ml_tpu.data.batch import (
     DenseBatch,
     EllBatch,
+    deal_rows,
     row_partition_specs,
 )
 
@@ -127,7 +128,8 @@ def shard_batch(batch, mesh: Mesh):
 
     Rows must be a multiple of the data-axis size — callers pad with
     zero-weight rows first (data/batch.pad_batch), the moral equivalent of
-    the reference's partition balancing.
+    the reference's partition balancing. An ELL layout of several blocks
+    of slots is dealt into one run of rows a shard (data/batch.deal_rows).
     """
     n_shards = mesh.shape[DATA_AXIS]
     rows = batch.labels.shape[0]
@@ -137,6 +139,7 @@ def shard_batch(batch, mesh: Mesh):
             "pad with zero-weight rows first")
     if not isinstance(batch, (DenseBatch, EllBatch)):
         raise TypeError(f"unknown batch type {type(batch)}")
+    batch = deal_rows(batch, n_shards)
     return jax.tree_util.tree_map(
         lambda leaf, spec: jax.device_put(leaf, NamedSharding(mesh, spec)),
         batch, row_partition_specs(batch, DATA_AXIS))

@@ -1044,6 +1044,71 @@ class TestWideSparse:
         w = np.asarray(driver.models[0].model.coefficients.means)
         assert np.all(np.isfinite(w)) and np.abs(w).max() > 0
 
+    def test_ragged_wide_sparse_trains_over_the_default_mesh(
+            self, tmp_path, monkeypatch):
+        """Rows of uneven length past the dense threshold, elastic net:
+        ``run`` installs the eight-device mesh, so the fit goes through
+        ``run_glm_shard_map`` on a layout of several blocks of slots dealt
+        over the shards, and gives what one device gives."""
+        from photon_ml_tpu.data.batch import EllBatch
+        from photon_ml_tpu.game.dataset import DENSE_FEATURE_THRESHOLD
+        from photon_ml_tpu.parallel import mesh as mesh_mod
+
+        d = DENSE_FEATURE_THRESHOLD + 100
+        rng = np.random.default_rng(35)
+        hot = rng.choice(d, size=40, replace=False) + 1  # 1-based
+        w_true = rng.normal(size=40)
+        libsvm = str(tmp_path / "ragged.libsvm")
+        with open(libsvm, "w") as fh:
+            for _ in range(300):
+                at = np.sort(rng.choice(40, size=rng.integers(1, 31),
+                                        replace=False))
+                x = rng.normal(size=len(at))
+                feats = " ".join(f"{int(hot[j])}:{v:.5f}"
+                                 for j, v in zip(at, x))
+                fh.write(f"{'+1' if x @ w_true[at] > 0 else '-1'} {feats}\n")
+        hot = np.sort(hot)
+
+        def fit(out):
+            driver = LegacyDriver(parse_args([
+                "--training-data-directory", libsvm,
+                "--output-directory", str(tmp_path / out),
+                "--task", "LOGISTIC_REGRESSION",
+                "--input-file-format", "LIBSVM",
+                "--feature-dimension", str(d),
+                "--regularization-type", "ELASTIC_NET",
+                "--regularization-weights", "2",
+                "--num-iterations", "6",
+            ]))
+            driver.run()
+            return driver
+
+        from photon_ml_tpu.parallel import distributed
+
+        routed = []
+        real = distributed.run_glm_shard_map
+
+        def seen(problem, batch, mesh, *a, **kw):
+            routed.append((type(batch), len(batch.tail),
+                           mesh.shape["data"]))
+            return real(problem, batch, mesh, *a, **kw)
+
+        monkeypatch.setattr(distributed, "run_glm_shard_map", seen)
+        on_mesh = fit("mesh")
+        assert routed and all(r == (EllBatch, routed[0][1], 8)
+                              for r in routed) and routed[0][1] >= 2
+        monkeypatch.setattr(mesh_mod, "setup_default_mesh",
+                            lambda *a, **kw: mesh_mod.set_default_mesh(None))
+        del routed[:]
+        alone = fit("alone")
+        assert not routed
+        w_mesh, w_alone = (
+            np.asarray(drv.models[0].model.coefficients.means)
+            for drv in (on_mesh, alone))
+        assert np.abs(w_alone).max() > 0
+        np.testing.assert_allclose(w_mesh, w_alone, rtol=1e-3, atol=1e-5)
+        np.testing.assert_array_equal(w_mesh == 0.0, w_alone == 0.0)
+
     def test_wide_sparse_with_standardization(self, tmp_path):
         """Sparse summarization feeds STANDARDIZATION on a wide shard: the
         normalization context builds from sparse statistics and training

@@ -125,15 +125,20 @@ def test_shard_map_backend_ell_batch(rng):
     X = sp.random(n, d, density=0.2, random_state=7, format="csr")
     w = np.asarray(rng.normal(size=d))
     y = (rng.uniform(size=n) < 1 / (1 + np.exp(-(X @ w)))).astype(float)
+    # float64: the rows are of uneven length, so the layout holds them in
+    # blocks of slots, longest first, and the mesh deals them over the
+    # shards; in float32 the two sums' orders end a tolerance-1e-9 solve an
+    # iteration apart (1e-3 in a coefficient), in float64 they agree to 1e-15
     ell = csr_to_batch(X.tocsr(), y, np.zeros(n), np.ones(n),
-                        dense_threshold=8)  # force ELL
+                        dense_threshold=8, dtype=jnp.float64)  # force ELL
+    assert len(ell.tail) == 2
     problem = _problem()
     model_local, _ = problem.run(ell)
     mesh = make_mesh()
     model_dist, _ = distributed.run_glm_shard_map(problem, ell, mesh)
     np.testing.assert_allclose(
         np.asarray(model_dist.coefficients.means),
-        np.asarray(model_local.coefficients.means), rtol=2e-4, atol=2e-5)
+        np.asarray(model_local.coefficients.means), rtol=1e-9, atol=1e-12)
 
 
 def test_pallas_kernel_parity_per_shard_interpret(rng):

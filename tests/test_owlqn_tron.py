@@ -324,3 +324,89 @@ def test_counters_book_each_solve_once(rng):
         x, hist._replace(evaluations=None, hvps=None), 15, 1e-5,
         site="t.solver")
     assert totals() == after
+
+
+# --- OWL-QN on rows of uneven length (the ragged ELL layout) -----------------
+
+
+def _ragged_design(rng, n=9000, d=5000):
+    """Rows of 13 to 72 cells of value 1 / sqrt(length) over ``d`` columns,
+    a few columns in many rows: enough rows for a layout of several blocks
+    of slots."""
+    import scipy.sparse as sp
+
+    lens = np.minimum(12 + np.round(np.exp(2.6 + 0.7 * rng.normal(size=n))),
+                      72).astype(int)
+    p = 1.0 / (np.arange(d) + 10.0)
+    p /= p.sum()
+    cols = np.concatenate([np.sort(rng.choice(d, size=l, replace=False, p=p))
+                           for l in lens])
+    vals = np.repeat(1.0 / np.sqrt(lens), lens)
+    indptr = np.concatenate([[0], np.cumsum(lens)])
+    mat = sp.csr_matrix((vals, cols, indptr), shape=(n, d))
+    z = mat @ rng.normal(size=d) + 1.0
+    y = (rng.random(n) < 1 / (1 + np.exp(-z))).astype(np.float64)
+    return mat, y
+
+
+@pytest.mark.parametrize("regularization,alpha", [("ELASTIC_NET", 0.5),
+                                                  ("L1", 1.0)])
+def test_owlqn_on_the_ragged_layout_against_the_textbook_and_the_dense_fit(
+        rng, regularization, alpha):
+    """``train_glm_grid`` (LBFGS + an L1 part -> OWL-QN) on the program's
+    layout of a ragged matrix: the objective and the zero set of the
+    benchmark reference's textbook OWL-QN after as many iterations, and the
+    same fit on the densified matrix."""
+    from benchmark.reference import glm_ragged as reference
+    from photon_ml_tpu.game.dataset import csr_to_batch
+    from photon_ml_tpu.optimize.config import (
+        OptimizerType,
+        RegularizationContext,
+        RegularizationType,
+        TaskType,
+    )
+    from photon_ml_tpu.training import train_glm_grid
+
+    mat, y = _ragged_design(rng)
+    n, d = mat.shape
+    zeros, ones = np.zeros(n), np.ones(n)
+    ell = csr_to_batch(mat, y, zeros, ones, dtype=jnp.float64)
+    assert len(ell.blocks) >= 3 and ell.order is not None
+    dense = dense_batch(mat.toarray(), y, dtype=jnp.float64)
+    lam, iterations = 2.0, 12
+    context = RegularizationContext(RegularizationType[regularization],
+                                    alpha=alpha)
+    fits = [train_glm_grid(batch, TaskType.LOGISTIC_REGRESSION, [lam],
+                           optimizer_type=OptimizerType.LBFGS,
+                           regularization_context=context,
+                           max_iterations=iterations, tolerance=1e-30)[0]
+            for batch in (ell, dense)]
+    on_ell, on_dense = (np.asarray(f.result.coefficients) for f in fits)
+    assert fits[0].result.iterations == iterations
+    # the same fit on the densified matrix: the same path
+    np.testing.assert_allclose(on_ell, on_dense, rtol=1e-6, atol=1e-9)
+    np.testing.assert_array_equal(on_ell == 0.0, on_dense == 0.0)
+    assert float(fits[0].result.value) == pytest.approx(
+        float(fits[1].result.value), rel=1e-10)
+    # the textbook on the reference's own evaluations (float32 sums)
+    l1, l2 = context.l1_weight(lam), context.l2_weight(lam)
+    flat = tuple(jnp.asarray(a) for a in reference.flat_blocks(
+        mat.indptr, mat.indices, mat.data, 1000))
+    data = (*flat, jnp.asarray(y, jnp.float32), jnp.zeros(n, jnp.float32),
+            jnp.ones(n, jnp.float32))
+
+    def fn(w):
+        return reference.smooth(*data, w, l2)
+
+    w_ref, values_ref, gnorm_ref = reference.owlqn(fn, l1, np.zeros(d),
+                                                   iterations)
+    F_at, _, pg_at = reference.penalised(fn, on_ell, l1)
+    assert float(fits[0].result.value) == pytest.approx(F_at, rel=1e-6)
+    assert float(fits[0].result.grad_norm) == pytest.approx(
+        np.linalg.norm(pg_at), rel=1e-3)
+    decrease = values_ref[0] - values_ref[-1]
+    assert decrease > 0.01 * values_ref[0]
+    assert abs(F_at - values_ref[-1]) <= 1e-3 * decrease
+    zero, zero_ref = on_ell == 0.0, w_ref == 0.0
+    assert 0.2 < zero.mean() < 0.99  # the penalty selects
+    assert np.mean(zero != zero_ref) <= 0.01

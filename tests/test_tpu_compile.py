@@ -423,6 +423,111 @@ def test_sharded_wide_sparse_solve_compiles(mesh, as_on_tpu_mesh):
     assert per_device < 0.6 * planes * 1.1 + CRITEO_DIM * 4
 
 
+# the ragged cell's shape (benchmark/configs/glm-ragged-kddb.json): 74 blocks
+# of 65,536 rows over 29,890,095 columns; the blocks of slots the layout's
+# rule gives the generator's row lengths, [slots, rows] each (PERF.md, PR 35)
+KDDB_ROWS, KDDB_DIM = 4_849_664, 29_890_095
+KDDB_BLOCKS = ((16, 4_849_664), (8, 4_575_254), (8, 2_666_620),
+               (8, 1_360_526), (8, 710_441), (16, 388_101), (24, 132_049),
+               (40, 33_388))
+
+
+def _kddb(row_sharding, plane_sharding, run_sharding, shards=1):
+    """The cell's layout as ``shards`` runs of its rows: block 0
+    ``[16, shards x N]``, a further block ``[shards, K, n_g]``."""
+    from photon_ml_tpu.data.batch import EllBatch
+
+    def sds(shape, dt, sharding):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    def planes(shape, sharding):
+        return sds(shape, jnp.int32, sharding), sds(shape, jnp.float32,
+                                                    sharding)
+
+    (k0, n0), rest = KDDB_BLOCKS[0], KDDB_BLOCKS[1:]
+    rows = [sds((shards * n0,), jnp.float32, row_sharding) for _ in range(3)]
+    return EllBatch(
+        *planes((k0, shards * n0), plane_sharding), *rows,
+        tuple(planes((shards, k, n), run_sharding) for k, n in rest), None,
+        dim=KDDB_DIM)
+
+
+def _elastic_net_problem():
+    problem = GLMOptimizationProblem(
+        config=GLMOptimizationConfiguration(
+            max_iterations=5, tolerance=1e-30, regularization_weight=1.0,
+            optimizer_type=OptimizerType.LBFGS,
+            regularization_context=RegularizationContext(
+                RegularizationType.ELASTIC_NET, alpha=0.5)),
+        task=TaskType.LOGISTIC_REGRESSION)
+    assert problem.solver_site() == "optimizer.owlqn"
+    return problem
+
+
+KDDB_SLOTS = sum(k * n for k, n in KDDB_BLOCKS)
+KDDB_HISTORY = 2 * 16 * KDDB_DIM * 4  # S and Y, 10 rows in 16 sublanes
+
+
+def test_ragged_owlqn_solve_fits_one_chip_and_updates_its_history_in_place(
+        one_chip):
+    """The KDD Cup 2010-shaped cell's program, compiled: OWL-QN (LBFGS +
+    ELASTIC_NET) over the ELL layout in eight blocks of slots, 162,811,664
+    slots walked for 142,603,811 stored, a 29.9M-wide solve. The ``[10, D]``
+    history is updated one row at a time in place (a ``dynamic-update-
+    slice``, no whole copy), so the program stays under half the chip;
+    its 10 rows are tiled to 16 sublanes, which is most of the
+    temporaries. A further block's leading axis of one run costs nothing:
+    no plane is padded or copied."""
+    problem = _elastic_net_problem()
+    compiled = jax.jit(problem.solve).lower(
+        problem.objective(), _kddb(one_chip, one_chip, one_chip),
+        jax.ShapeDtypeStruct((KDDB_DIM,), jnp.float32,
+                             sharding=one_chip)).compile()
+    memory = compiled.memory_analysis()
+    assert 8 * KDDB_SLOTS < memory.argument_size_in_bytes < 1.02 * (
+        8 * KDDB_SLOTS + 12 * KDDB_ROWS + 4 * KDDB_DIM)
+    assert KDDB_HISTORY < memory.temp_size_in_bytes < (
+        KDDB_HISTORY + 16 * KDDB_DIM * 4)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 0.5 * V5E_HBM_BYTES)
+    text = compiled.as_text()
+    whole = [(dims, opcode) for dims, opcode in _HLO_ARRAY.findall(text)
+             if dims == f"10,{KDDB_DIM}"]
+    assert {opcode for _, opcode in whole} <= {
+        "dynamic-update-slice", "broadcast", "parameter",
+        "get-tuple-element"}, whole
+    assert "owlqn.update" in text and "objective.feature_sum" in text
+
+
+def test_the_ragged_fit_compiles_row_sharded_over_a_v5e_host(topo):
+    """The configuration's stated deployment: the rows data-parallel over
+    the four chips of one host, each chip the one-chip cell's share, through
+    ``sharded_fit`` on the layout dealt into four runs of rows. A chip holds
+    one run of every block of slots and what the one-chip program holds."""
+    from photon_ml_tpu.data.batch import row_partition_specs
+    from photon_ml_tpu.parallel.distributed import sharded_fit
+
+    mesh = make_mesh(num_data=4, num_entity=1, devices=list(topo.devices))
+    batch = _kddb(NamedSharding(mesh, P(DATA_AXIS)),
+                  NamedSharding(mesh, P(None, DATA_AXIS)),
+                  NamedSharding(mesh, P(DATA_AXIS, None, None)), shards=4)
+    specs = row_partition_specs(batch, DATA_AXIS)
+    assert specs.indices == P(None, DATA_AXIS) and specs.order is None
+    assert all(ix == v == P(DATA_AXIS, None, None) for ix, v in specs.tail)
+    fit, _ = sharded_fit(_elastic_net_problem(), batch, mesh, jnp.float32)
+    compiled = jax.jit(fit).lower(batch, jax.ShapeDtypeStruct(
+        (KDDB_DIM,), jnp.float32,
+        sharding=NamedSharding(mesh, P()))).compile()
+    assert "all-reduce" in compiled.as_text()
+    memory = compiled.memory_analysis()  # of one device
+    assert 8 * KDDB_SLOTS < memory.argument_size_in_bytes < 1.02 * (
+        8 * KDDB_SLOTS + 12 * KDDB_ROWS + 4 * KDDB_DIM)
+    assert KDDB_HISTORY < memory.temp_size_in_bytes < (
+        KDDB_HISTORY + 24 * KDDB_DIM * 4)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 0.5 * V5E_HBM_BYTES)
+
+
 # --- the factored coordinate's projection refit (PR 33) ---------------------
 
 # benchmark/configs/game-ml20m.json: the per-user buckets of one chip's
