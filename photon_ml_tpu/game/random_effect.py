@@ -250,7 +250,15 @@ def _fit_blocks_impl(
     ``resume`` is the previous chunk's per-lane carry (lane-compacted by
     the caller): the solvers continue their loop state verbatim and every
     convergence check stays anchored to the ORIGINAL dispatch's f₀/‖g₀‖,
-    so a chunked solve is bit-identical to the single dispatch."""
+    so a chunked solve is bit-identical to the single dispatch.
+
+    L-BFGS and OWL-QN are asked for their newest-first curvature history
+    here (``newest_first=True``, optimize/lbfgs.py): this is the one call
+    site that runs them under ``vmap``, where the circular history's
+    ``head`` would be one index a lane and every slot read a gather of one
+    row a lane. All four fit paths (this dispatch, the compacted one, and
+    both sharded ones) trace this function, so all carry that layout, and
+    the ``LBFGSResume`` they pass between chunks has ``head=None``."""
 
     def solve_one(Xe, ye, oe, we, x0, res):
         batch = DenseBatch(X=Xe, labels=ye, offsets=oe, weights=we)
@@ -258,7 +266,7 @@ def _fit_blocks_impl(
             out = minimize_owlqn(
                 _vg, x0, (obj, batch), l1=l1,
                 max_iter=max_iter, tolerance=tolerance,
-                resume=res, return_carry=return_carry)
+                resume=res, return_carry=return_carry, newest_first=True)
         elif solver == "tron":
             out = minimize_tron(
                 _vg, _hvp, x0, (obj, batch),
@@ -268,7 +276,7 @@ def _fit_blocks_impl(
             out = minimize_lbfgs(
                 _vg, x0, (obj, batch),
                 max_iter=max_iter, tolerance=tolerance,
-                resume=res, return_carry=return_carry)
+                resume=res, return_carry=return_carry, newest_first=True)
         x, hist, progressed = out[:3]
         carry = out[3] if return_carry else None
         k = hist.num_iterations
@@ -761,8 +769,7 @@ def _sharded_score_fn(mesh, num_samples, collective_quant="none"):
     lane = P(ENTITY_AXIS)
 
     def impl(X, coefs, row_ids, weights):
-        margins = jnp.einsum("end,ed->en", X, coefs,
-                             preferred_element_type=jnp.float32)
+        margins = _block_margins(X, coefs)
         margins = jnp.where(weights > 0, margins, 0.0)
         flat = jax.ops.segment_sum(
             margins.reshape(-1), row_ids.reshape(-1).astype(jnp.int32),
@@ -1029,6 +1036,17 @@ class RandomEffectOptimizationProblem:
         return val if isinstance(val, float) else float(val)
 
 
+def _block_margins(dataset_X: Array, coefs: Array) -> Array:
+    """margins[e, n] = X[e, n] . coefs[e], as a product and a sum (what
+    ``score_passive`` does for its rows) and not an einsum: alone in its
+    program, XLA:TPU makes the einsum of a bucket 64 columns wide or more
+    an MXU convolution over the coefficients rounded to bfloat16, so that
+    the scores the sweep's objective is summed from are not those of the
+    model it publishes (PERF.md, PR 36). The sum reads X once, as the
+    convolution did."""
+    return jnp.sum(dataset_X * coefs[:, None, :], axis=-1).astype(jnp.float32)
+
+
 @partial(jax.jit, static_argnames=("num_samples",))
 def score_active(dataset_X: Array, coefs: Array, row_ids: Array,
                  weights: Array, num_samples: int) -> Array:
@@ -1039,8 +1057,7 @@ def score_active(dataset_X: Array, coefs: Array, row_ids: Array,
     the score exchange (RandomEffectCoordinate.score :137-151 analog).
     """
     with jax.named_scope("re.score"):
-        margins = jnp.einsum("end,ed->en", dataset_X, coefs,
-                             preferred_element_type=jnp.float32)
+        margins = _block_margins(dataset_X, coefs)
         margins = jnp.where(weights > 0, margins, 0.0)
         flat = jax.ops.segment_sum(
             margins.reshape(-1), row_ids.reshape(-1).astype(jnp.int32),
@@ -1069,9 +1086,7 @@ def _active_margins(dataset_X: Array, coefs: Array, weights: Array) -> Array:
     """``score_active``'s margins, left in the block's own ``[E, N]``
     layout (padded rows, weight 0, read 0)."""
     with jax.named_scope("re.score"):
-        margins = jnp.einsum("end,ed->en", dataset_X, coefs,
-                             preferred_element_type=jnp.float32)
-        return jnp.where(weights > 0, margins, 0.0)
+        return jnp.where(weights > 0, _block_margins(dataset_X, coefs), 0.0)
 
 
 @jax.jit

@@ -5,11 +5,25 @@ TPU-native replacement for the reference's Breeze-backed LBFGS
 LBFGS.scala:42-156 — wraps ``breeze.optimize.LBFGS.iterations`` and projects
 each iterate onto box constraints; defaults maxIter=100, m=10, tol=1e-7).
 
-Design: the two-loop recursion runs over a fixed-size circular history held in
+Design: the two-loop recursion runs over a fixed-size history held in
 ``[m, d]`` device arrays with per-slot validity masks, so the whole solve is
 one XLA computation — no host round-trips per iteration (the reference pays a
 Spark broadcast + treeAggregate per function evaluation; here a sharded
 objective's all-reduce is fused into the loop body).
+
+The history has two layouts, and the call site names the one it needs
+(``newest_first``); no option a user can set does:
+
+- circular (the default, every unbatched solve: a fixed effect, a grid fit,
+  the factored refit): ``head`` is the next slot to write and a new pair is
+  one row written in place. A ``[10, 29.9M]`` history cannot afford more.
+- newest-first (the per-entity solves, which run under ``vmap``): slot 0 is
+  the newest pair, there is no ``head``, a new pair shifts the rest down.
+  Under ``vmap`` the batched ``while_loop`` predicate batches the whole
+  carry, so ``head`` would be one index a lane and every ``S[i]`` a gather
+  of one row a lane, every ``.at[head].set`` a scatter; a static slot order
+  needs neither. Per lane the operations and their order are the circular
+  form's: newest to oldest, invalid slots skipped by the same ``where``.
 
 Convergence checks mirror Optimizer.scala:156-170 (see optimize/common.py).
 """
@@ -50,7 +64,7 @@ class _LBFGSCarry(NamedTuple):
     Y: Array  # [m, d] gradient differences
     rho: Array  # [m]
     valid: Array  # [m] bool
-    head: Array  # next write slot
+    head: Optional[Array]  # next write slot; None: newest-first history
     made_progress: Array  # bool: last line search succeeded
     values: Array
     grad_norms: Array
@@ -68,7 +82,13 @@ class LBFGSResume(NamedTuple):
     must never re-anchor at a chunk boundary). Produced by
     ``return_carry=True``; under ``vmap`` every leaf grows a lane axis,
     which is what lets the lane-compaction driver gather only the
-    still-active lanes' carries between chunks."""
+    still-active lanes' carries between chunks.
+
+    The history is in the layout of the solve that made it (module
+    docstring) and goes back into a solve of the same layout: circular
+    with its ``head``, or newest-first with ``head=None`` (the per-entity
+    solves' carry, an empty pytree leaf that gathers and shards as
+    nothing)."""
 
     x: Array
     f: Array
@@ -78,7 +98,7 @@ class LBFGSResume(NamedTuple):
     Y: Array
     rho: Array
     valid: Array
-    head: Array
+    head: Optional[Array]
     f0: Array  # original-dispatch anchor f₀
     g0n: Array  # original-dispatch anchor ‖g₀‖
 
@@ -107,16 +127,25 @@ def axis_norm(axis_name: Optional[str], collective_quant: str = "none"):
 
 
 def two_loop_direction(g: Array, S: Array, Y: Array, rho: Array, valid: Array,
-                       head: Array,
+                       head: Optional[Array],
                        axis_name: Optional[str] = None,
                        collective_quant: str = "none") -> Array:
-    """Two-loop recursion over a masked circular history buffer.
+    """Two-loop recursion over a masked history buffer: circular with
+    ``head`` the next slot to write, or newest-first (slot 0 the newest
+    pair) with ``head=None``. Both walk the pairs newest to oldest and back
+    and skip invalid slots by the same ``where``; they differ in how a slot
+    is found. The circular form indexes with ``head``, which under ``vmap``
+    is a gather of one row a lane a step. The newest-first form scans the
+    history itself: the scan's counter is no part of any carry, so it stays
+    unbatched under ``vmap`` and slices every lane's slot ``i`` at once.
 
     With ``axis_name`` set, g/S/Y are per-replica shards and every inner
     product is psum'd — the recursion then produces this replica's shard
     of the exact full-dimension direction."""
-    m = S.shape[0]
     vdot = axis_dot(axis_name, collective_quant)
+    if head is None:
+        return _two_loop_newest_first(g, S, Y, rho, valid, vdot)
+    m = S.shape[0]
 
     # Order slots newest -> oldest: head-1, head-2, ...
     idx = (head - 1 - jnp.arange(m)) % m
@@ -148,7 +177,84 @@ def two_loop_direction(g: Array, S: Array, Y: Array, rho: Array, valid: Array,
     return -r
 
 
-@partial(jax.jit, static_argnums=(0, 3, 4, 5, 7, 9, 10, 11))
+def _two_loop_newest_first(g, S, Y, rho, valid, vdot) -> Array:
+    """:func:`two_loop_direction` over a newest-first history: the slots
+    are the scans' ``xs``, forward then in reverse."""
+
+    def first_loop(q, slot):
+        s_i, y_i, rho_i, valid_i = slot
+        a_i = jnp.where(valid_i, rho_i * vdot(s_i, q), 0.0)
+        return q - a_i * y_i, a_i
+
+    q, alphas = lax.scan(first_loop, g, (S, Y, rho, valid))
+
+    sy = vdot(S[0], Y[0])
+    yy = vdot(Y[0], Y[0])
+    gamma = jnp.where(valid[0] & (yy > 0), sy / jnp.maximum(yy, 1e-300), 1.0)
+    r = gamma * q
+
+    def second_loop(r, slot):
+        s_i, y_i, rho_i, valid_i, a_i = slot
+        beta = jnp.where(valid_i, rho_i * vdot(y_i, r), 0.0)
+        return r + s_i * (a_i - beta), None
+
+    r, _ = lax.scan(second_loop, r, (S, Y, rho, valid, alphas), reverse=True)
+    return -r
+
+
+def empty_history(m: int, d: int, dtype, newest_first: bool):
+    """(S, Y, rho, valid, head) of a solve that has stored no pair yet."""
+    return (jnp.zeros((m, d), dtype), jnp.zeros((m, d), dtype),
+            jnp.zeros(m, dtype), jnp.zeros(m, bool),
+            None if newest_first else jnp.int32(0))
+
+
+def check_history_layout(resume: LBFGSResume, newest_first: bool) -> None:
+    """A carry read in the other layout would solve on, wrongly and in
+    silence: slot 0 is the newest pair in one and any pair in the other."""
+    if (resume.head is None) != newest_first:
+        raise ValueError(
+            "a resumed solve takes the history layout of the solve that "
+            f"made its carry: newest_first={newest_first} but the carry "
+            f"has {'no' if resume.head is None else 'a'} head")
+
+
+def push_pair(S: Array, Y: Array, rho: Array, valid: Array,
+              head: Optional[Array], s: Array, y: Array, sy: Array,
+              store: Array):
+    """The history after an iteration, (S, Y, rho, valid, head): with the
+    pair ``(s, y)`` in it where ``store``, as it was where not. Once ``m``
+    pairs are held a new one replaces the oldest. Circular (``head`` an
+    index): one row written in place at ``head``, which moves on.
+    Newest-first (``head=None``): the pair enters at slot 0 and the rest
+    shift down a slot, a whole copy but no index."""
+    if head is None:
+        def push(row, rows):
+            return jnp.where(store, jnp.concatenate([row[None], rows[:-1]]),
+                             rows)
+
+        return (push(s, S), push(y, Y),
+                push(1.0 / jnp.maximum(sy, 1e-300), rho),
+                push(jnp.bool_(True), valid), None)
+    m = S.shape[0]
+    return (jnp.where(store, S.at[head].set(s), S),
+            jnp.where(store, Y.at[head].set(y), Y),
+            jnp.where(store,
+                      rho.at[head].set(1.0 / jnp.maximum(sy, 1e-300)), rho),
+            jnp.where(store, valid.at[head].set(True), valid),
+            jnp.where(store, (head + 1) % m, head))
+
+
+def record(trail: Array, at: Array, value: Array, by_select: bool) -> Array:
+    """``trail`` (a RunHistory array) with ``value`` at iteration ``at``.
+    ``by_select`` is for a solve under ``vmap``, where ``at`` is one index
+    a lane and ``.at[].set`` a scatter over every lane."""
+    if by_select:
+        return jnp.where(jnp.arange(trail.shape[0]) == at, value, trail)
+    return trail.at[at].set(value)
+
+
+@partial(jax.jit, static_argnums=(0, 3, 4, 5, 7, 9, 10, 11, 12))
 def _minimize_lbfgs_impl(
     value_and_grad_fn,
     x0: Array,
@@ -162,6 +268,7 @@ def _minimize_lbfgs_impl(
     return_carry: bool = False,
     update_axis_name: Optional[str] = None,
     collective_quant: str = "none",
+    newest_first: bool = False,
 ):
     # ``data`` is a traced pytree (the batch): one compiled kernel per
     # function object serves every batch of the same shape — critical for the
@@ -179,6 +286,8 @@ def _minimize_lbfgs_impl(
     # the exact full-dimension recursion (arXiv 2004.13336). Box
     # projection and iterate tracking would need full vectors per step —
     # unsupported in sharded-update mode (callers fall back).
+    # ``newest_first``: the history layout of a solve under ``vmap`` (module
+    # docstring); its RunHistory is written by select for the same reason.
     if update_axis_name is not None and (box is not None or track_iterates):
         raise ValueError(
             "sharded weight update supports neither box constraints nor "
@@ -193,12 +302,10 @@ def _minimize_lbfgs_impl(
         anchor_g0n = vnorm(g_start)
         x_start = x0
         prev_f0 = f_start + jnp.asarray(jnp.inf, dtype)
-        S0 = jnp.zeros((m, d), dtype)
-        Y0 = jnp.zeros((m, d), dtype)
-        rho0 = jnp.zeros(m, dtype)
-        valid0 = jnp.zeros(m, bool)
-        head0 = jnp.int32(0)
+        S0, Y0, rho0, valid0, head0 = empty_history(m, d, dtype,
+                                                    newest_first)
     else:
+        check_history_layout(resume, newest_first)
         x_start, f_start, g_start = resume.x, resume.f, resume.g
         prev_f0 = resume.prev_f
         S0, Y0, rho0 = resume.S, resume.Y, resume.rho
@@ -286,22 +393,17 @@ def _minimize_lbfgs_impl(
             sy = vdot(s, y)
             store = ok & (sy > 1e-10)
 
-            S = jnp.where(store, c.S.at[c.head].set(s), c.S)
-            Y = jnp.where(store, c.Y.at[c.head].set(y), c.Y)
-            rho = jnp.where(
-                store, c.rho.at[c.head].set(1.0 / jnp.maximum(sy, 1e-300)),
-                c.rho)
-            valid = jnp.where(store, c.valid.at[c.head].set(True), c.valid)
-            head = jnp.where(store, (c.head + 1) % m, c.head)
+            S, Y, rho, valid, head = push_pair(
+                c.S, c.Y, c.rho, c.valid, c.head, s, y, sy, store)
 
             it_new = c.it + 1
-            values = c.values.at[it_new].set(jnp.where(ok, f_new, c.f))
-            grad_norms = c.grad_norms.at[it_new].set(
-                vnorm(jnp.where(ok, g_new, c.g)))
-            # a select, not ``.at[].set``: under ``vmap`` that is a scatter
-            # over every lane, and this array is here to be cheap
-            evaluations = jnp.where(
-                jnp.arange(max_iter + 1) == it_new, evals, c.evaluations)
+            values = record(c.values, it_new, jnp.where(ok, f_new, c.f),
+                            newest_first)
+            grad_norms = record(c.grad_norms, it_new,
+                                vnorm(jnp.where(ok, g_new, c.g)),
+                                newest_first)
+            # always by select: this array is here to be cheap
+            evaluations = record(c.evaluations, it_new, evals, True)
             x_acc = jnp.where(ok, x_new, c.x)
             iterates = (c.iterates.at[it_new].set(x_acc)
                         if track_iterates else None)
@@ -344,6 +446,7 @@ def minimize_lbfgs(
     return_carry: bool = False,
     update_axis_name: Optional[str] = None,
     collective_quant: str = "none",
+    newest_first: bool = False,
 ):
     """Minimize ``f(x, data)`` from ``x0``; returns (x, RunHistory, made_progress).
 
@@ -359,6 +462,11 @@ def minimize_lbfgs(
     where it stopped (original f₀/‖g₀‖ anchors, curvature history,
     previous objective) — the lane-compaction driver's chunk restarts
     use this to stay bit-identical to a single dispatch.
+
+    ``newest_first`` is for the caller that runs this solve under ``vmap``
+    (the per-entity solves): the curvature history, and the carry, in the
+    layout that needs no per-lane index (module docstring). Every other
+    caller leaves it off and keeps the in-place circular history.
     """
     from photon_ml_tpu.obs import compile as obs_compile
 
@@ -366,8 +474,9 @@ def minimize_lbfgs(
         "optimizer.lbfgs", _minimize_lbfgs_impl,
         (value_and_grad_fn, x0, data, max_iter, m, tolerance, box,
          track_iterates, resume, return_carry, update_axis_name,
-         collective_quant),
-        static_argnums=(0, 3, 4, 5, 7, 9, 10, 11),
+         collective_quant, newest_first),
+        static_argnums=(0, 3, 4, 5, 7, 9, 10, 11, 12),
         arg_names=("value_and_grad_fn", "x0", "data", "max_iter", "m",
                    "tolerance", "box", "track_iterates", "resume",
-                   "return_carry", "update_axis_name", "collective_quant"))
+                   "return_carry", "update_axis_name", "collective_quant",
+                   "newest_first"))

@@ -38,6 +38,10 @@ from photon_ml_tpu.optimize.lbfgs import (
     LBFGSResume,
     axis_dot,
     axis_norm,
+    check_history_layout,
+    empty_history,
+    push_pair,
+    record,
     two_loop_direction,
 )
 from photon_ml_tpu.parallel.quantized_collectives import qpsum
@@ -69,7 +73,7 @@ class _OWLQNCarry(NamedTuple):
     Y: Array
     rho: Array
     valid: Array
-    head: Array
+    head: Optional[Array]  # None: newest-first history (see lbfgs)
     made_progress: Array
     values: Array
     grad_norms: Array  # pseudo-gradient norms
@@ -77,7 +81,7 @@ class _OWLQNCarry(NamedTuple):
     iterates: Optional[Array]  # [max_iter+1, d] when tracking, else None
 
 
-@partial(jax.jit, static_argnums=(0, 3, 4, 5, 8, 10, 11, 12))
+@partial(jax.jit, static_argnums=(0, 3, 4, 5, 8, 10, 11, 12, 13))
 def _minimize_owlqn_impl(
     value_and_grad_fn,
     x0: Array,
@@ -92,6 +96,7 @@ def _minimize_owlqn_impl(
     return_carry: bool = False,
     update_axis_name: Optional[str] = None,
     collective_quant: str = "none",
+    newest_first: bool = False,
 ):
     # Sharded weight update (see lbfgs): x0/g/l1 are per-replica shards,
     # every d-vector reduction (including the L1 penalty sum) is psum'd.
@@ -120,19 +125,18 @@ def _minimize_owlqn_impl(
     # ``resume`` continues a previous chunk's solve verbatim: carry
     # (iterate, SMOOTH-gradient curvature pairs, prev F) plus the ORIGINAL
     # F₀/‖pg₀‖ anchors, so chunked restarts never re-anchor the relative
-    # tolerances (see lbfgs.LBFGSResume — the carry shape is shared).
+    # tolerances (see lbfgs.LBFGSResume — the carry shape is shared, and
+    # so are the two history layouts ``newest_first`` chooses between).
     if resume is None:
         f_start, g_start = full_objective(x0)
         anchor_f0 = f_start
         anchor_g0n = vnorm(pseudo_gradient(x0, g_start, l1))
         x_start = x0
         prev_f0 = f_start + jnp.asarray(jnp.inf, dtype)
-        S0 = jnp.zeros((m, d), dtype)
-        Y0 = jnp.zeros((m, d), dtype)
-        rho0 = jnp.zeros(m, dtype)
-        valid0 = jnp.zeros(m, bool)
-        head0 = jnp.int32(0)
+        S0, Y0, rho0, valid0, head0 = empty_history(m, d, dtype,
+                                                    newest_first)
     else:
+        check_history_layout(resume, newest_first)
         x_start, f_start, g_start = resume.x, resume.f, resume.g
         prev_f0 = resume.prev_f
         S0, Y0, rho0 = resume.S, resume.Y, resume.rho
@@ -230,24 +234,18 @@ def _minimize_owlqn_impl(
             sy = vdot(s, y)
             store = accepted & (sy > 1e-10)
 
-            S = jnp.where(store, c.S.at[c.head].set(s), c.S)
-            Y = jnp.where(store, c.Y.at[c.head].set(y), c.Y)
-            rho = jnp.where(
-                store, c.rho.at[c.head].set(1.0 / jnp.maximum(sy, 1e-300)),
-                c.rho)
-            valid = jnp.where(store, c.valid.at[c.head].set(True), c.valid)
-            head = jnp.where(store, (c.head + 1) % m, c.head)
+            S, Y, rho, valid, head = push_pair(
+                c.S, c.Y, c.rho, c.valid, c.head, s, y, sy, store)
 
             it_new = c.it + 1
             pg_new = pseudo_gradient(x_new, g_new, l1)
-            values = c.values.at[it_new].set(
-                jnp.where(accepted, f_new, c.f))
-            grad_norms = c.grad_norms.at[it_new].set(vnorm(
-                jnp.where(accepted, pg_new, pg)))
-            # a select, not ``.at[].set``: under ``vmap`` that is a scatter
-            # over every lane, and this array is here to be cheap
-            evaluations = jnp.where(
-                jnp.arange(max_iter + 1) == it_new, evals, c.evaluations)
+            values = record(c.values, it_new,
+                            jnp.where(accepted, f_new, c.f), newest_first)
+            grad_norms = record(
+                c.grad_norms, it_new,
+                vnorm(jnp.where(accepted, pg_new, pg)), newest_first)
+            # always by select: this array is here to be cheap
+            evaluations = record(c.evaluations, it_new, evals, True)
             x_acc = jnp.where(accepted, x_new, c.x)
             iterates = (c.iterates.at[it_new].set(x_acc)
                         if track_iterates else None)
@@ -291,13 +289,16 @@ def minimize_owlqn(
     return_carry: bool = False,
     update_axis_name: Optional[str] = None,
     collective_quant: str = "none",
+    newest_first: bool = False,
 ):
     """Minimize f(x, data) + l1 ||x||_1; returns (x, RunHistory, made_progress).
 
     ``value_and_grad_fn`` returns the SMOOTH part's (value, gradient); the L1
     term is handled here. ``l1`` may be scalar or per-coordinate (length d).
     ``resume``/``return_carry`` continue a chunked solve bit-identically
-    (see :func:`minimize_lbfgs` — the carry shape is shared).
+    (see :func:`minimize_lbfgs` — the carry shape is shared), and
+    ``newest_first`` is the history layout for a solve under ``vmap``, as
+    there.
     """
     from photon_ml_tpu.obs import compile as obs_compile
 
@@ -305,8 +306,9 @@ def minimize_owlqn(
         "optimizer.owlqn", _minimize_owlqn_impl,
         (value_and_grad_fn, x0, data, max_iter, m, tolerance, l1, box,
          track_iterates, resume, return_carry, update_axis_name,
-         collective_quant),
-        static_argnums=(0, 3, 4, 5, 8, 10, 11, 12),
+         collective_quant, newest_first),
+        static_argnums=(0, 3, 4, 5, 8, 10, 11, 12, 13),
         arg_names=("value_and_grad_fn", "x0", "data", "max_iter", "m",
                    "tolerance", "l1", "box", "track_iterates", "resume",
-                   "return_carry", "update_axis_name", "collective_quant"))
+                   "return_carry", "update_axis_name", "collective_quant",
+                   "newest_first"))

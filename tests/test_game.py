@@ -1131,6 +1131,32 @@ class TestCheckpointedCoordinateDescent:
         assert resumed_obj == pytest.approx(full_obj, rel=1e-4)
 
 
+def lanes_that_skip_the_store_before(chunk, blocks, obj, l1, solver,
+                                     tolerance):
+    """The lanes of ``blocks`` = (X, labels, offsets, weights, x0) whose
+    iteration ``chunk`` moves the iterate but stores no curvature pair
+    (``s.y <= 1e-10``), and that are not done by then: with chunks of
+    ``chunk`` their history crosses a chunk boundary as it was. Read off
+    the per-lane carry of two solves stopped one iteration apart. Shared
+    with tests/test_re_sharding.py."""
+    from photon_ml_tpu.game import random_effect as re_mod
+
+    def stopped_at(budget):
+        *_, iters, _, codes, _, _, carry = re_mod._fit_blocks(
+            *blocks, obj, l1, solver, budget, tolerance,
+            boundary_convergence=True, return_carry=True)
+        return np.asarray(iters), np.asarray(codes), carry
+
+    _, _, before = stopped_at(chunk - 1)
+    iters, codes, at = stopped_at(chunk)
+    assert at.head is None  # the per-entity carry: newest-first
+    return [
+        e for e in range(len(iters))
+        if iters[e] == chunk and codes[e] == re_mod.CONV_MAX_ITERATIONS
+        and np.array_equal(np.asarray(at.S[e]), np.asarray(before.S[e]))
+        and not np.array_equal(np.asarray(at.x[e]), np.asarray(before.x[e]))]
+
+
 class TestLaneEvaluationCounts:
     """The vmapped path's counts: each lane's own evaluations, the rounds
     the batched loop ran, and the fill they give (LaneCounts, the tracker
@@ -1255,6 +1281,49 @@ class TestLaneEvaluationCounts:
                               np.asarray(counts.evaluation_rounds)))
 
         assert ran(chunked) <= ran(whole)
+
+    @pytest.mark.parametrize("solver,chunk", [("lbfgs", 9), ("owlqn", 10)])
+    def test_a_store_skipped_before_a_chunk_boundary_resumes_exactly(
+            self, rng, solver, chunk):
+        """The chunked solve against the single dispatch where a lane's
+        last iteration before the boundary stored no pair (``s.y <= 1e-10``,
+        a lane near its optimum under a tolerance it has not met yet): its
+        newest-first history crosses the boundary unshifted, the next
+        chunk's first direction reads the same pairs, and that lane's
+        coefficients, iterations, value and code are the single dispatch's
+        bit for bit."""
+        from photon_ml_tpu.game import random_effect as re_mod
+
+        X, y, off, wts, x0 = self._blocks(rng)
+        obj = self._problem(*self.SOLVERS[solver]).objective()
+        l1 = jnp.full(X.shape[2], 0.25 if solver == "owlqn" else 0.0)
+        tolerance, max_iter = 1e-13, 30
+
+        skipped_and_goes_on = lanes_that_skip_the_store_before(
+            chunk, (X, y, off, wts, x0), obj, l1, solver, tolerance)
+        assert skipped_and_goes_on
+
+        whole = re_mod._fit_blocks(X, y, off, wts, x0, obj, l1, solver,
+                                   max_iter, tolerance)
+        chunked = re_mod._fit_blocks_compacted(
+            X, y, off, wts, x0, obj, l1, solver, max_iter, tolerance, chunk,
+            donate=False)
+        lanes = np.asarray(skipped_and_goes_on)
+        assert (np.asarray(whole[1])[lanes] > chunk).all()
+        for mine, theirs in zip(chunked[:4], whole[:4]):
+            np.testing.assert_array_equal(np.asarray(mine)[lanes],
+                                          np.asarray(theirs)[lanes])
+        # every lane: the same iterations and evaluations; a lane that
+        # jitters at the float's floor until its budget ends (OWL-QN at
+        # this tolerance) differs by an ulp between a 5-lane program and a
+        # padded 8-lane one, as it did with the circular carry
+        np.testing.assert_allclose(np.asarray(chunked[0]),
+                                   np.asarray(whole[0]), rtol=1e-12)
+        np.testing.assert_array_equal(np.asarray(chunked[1]),
+                                      np.asarray(whole[1]))
+        np.testing.assert_array_equal(np.asarray(chunked[4].evaluations),
+                                      np.asarray(whole[4]))
+        assert len(chunked[4].bucket_lanes) > 1  # it did run in chunks
 
     def test_lane_fill_of_a_two_lane_bucket(self, rng):
         """One lane done at once (no weight: its gradient at the start is

@@ -196,3 +196,201 @@ def test_track_iterates_records_trajectory(rng):
     # default: no iterates recorded
     _, hist, _ = minimize_lbfgs(vg, x0, None, max_iter=5)
     assert hist.iterates is None
+
+
+# --- the two history layouts (circular, and newest-first under vmap) -------
+
+HISTORY_M, HISTORY_D, HISTORY_LANES = 10, 24, 64
+
+#: name -> (steps, share of steps whose pair is stored, share whose s.y is
+#: not positive, so that the solver's own rule ``sy > 1e-10`` skips them)
+HISTORY_CASES = {
+    "random_heads": (25, 0.7, 0.0),    # most lanes wrap, heads all over
+    "partly_valid": (12, 0.3, 0.0),    # 0..m valid slots, some lanes none
+    "skipped_store": (14, 1.0, 0.35),  # sy <= 1e-10 leaves the history be
+    "more_than_m": (23, 1.0, 0.0),     # 23 stores: the 13 oldest dropped
+}
+
+
+def _histories(rng, case):
+    """Both layouts of the same per-lane sequences of pairs, pushed by the
+    solvers' own ``push_pair`` under ``vmap``, float32 as on the chip:
+    ((S, Y, rho, valid, head) circular, (S, Y, rho, valid, None)
+    newest-first, stores [lanes])."""
+    from photon_ml_tpu.optimize.lbfgs import empty_history, push_pair
+
+    steps, stored, skipped = HISTORY_CASES[case]
+    lanes, m, d = HISTORY_LANES, HISTORY_M, HISTORY_D
+    push = jax.vmap(push_pair)
+
+    def lanes_of(history):
+        return tuple(None if leaf is None
+                     else jnp.broadcast_to(leaf, (lanes,) + leaf.shape)
+                     for leaf in history)
+
+    circular = lanes_of(empty_history(m, d, jnp.float32, False))
+    newest_first = lanes_of(empty_history(m, d, jnp.float32, True))
+    stores = np.zeros(lanes, int)
+    for _ in range(steps):
+        s = rng.normal(size=(lanes, d)).astype(np.float32)
+        # y = A s with A positive definite: s.y > 0, as after a Wolfe step
+        y = s * rng.uniform(0.5, 2.0, size=(lanes, d)).astype(np.float32)
+        y = np.where(rng.random(lanes)[:, None] < skipped, -y, y)
+        sy = jnp.sum(jnp.asarray(s) * jnp.asarray(y), axis=1)
+        store = jnp.asarray(rng.random(lanes) < stored) & (sy > 1e-10)
+        stores += np.asarray(store)
+        circular = push(*circular, jnp.asarray(s), jnp.asarray(y), sy, store)
+        newest_first = push(*newest_first, jnp.asarray(s), jnp.asarray(y),
+                            sy, store)
+    return circular, newest_first, stores
+
+
+@pytest.mark.parametrize("case", sorted(HISTORY_CASES))
+def test_newest_first_history_holds_the_circular_ones_pairs(rng, case):
+    """Slot ``i`` of the newest-first history is the circular history's
+    slot ``head - 1 - i``, bit for bit: a skipped store leaves both as they
+    were, and past ``m`` stores both have dropped the same oldest pair."""
+    circular, newest_first, stores = _histories(rng, case)
+    S, Y, rho, valid, head = (np.asarray(a) for a in circular)
+    assert newest_first[4] is None
+    steps, stored, skipped = HISTORY_CASES[case]
+    if case in ("random_heads", "more_than_m"):
+        assert stores.max() > HISTORY_M  # the oldest pairs have dropped
+    if case != "more_than_m":
+        assert len(set(stores)) > 3 and stores.min() < steps
+    np.testing.assert_array_equal(head, stores % HISTORY_M)
+    order = (head[:, None] - 1 - np.arange(HISTORY_M)) % HISTORY_M
+    for mine, theirs in zip(newest_first[:4], (S, Y, rho, valid)):
+        np.testing.assert_array_equal(
+            np.asarray(mine),
+            np.take_along_axis(
+                theirs, order.reshape(order.shape + (1,) * (theirs.ndim - 2)),
+                axis=1))
+    np.testing.assert_array_equal(
+        np.asarray(newest_first[3]).sum(axis=1),
+        np.minimum(stores, HISTORY_M))
+
+
+@pytest.mark.parametrize("case", sorted(HISTORY_CASES))
+def test_newest_first_direction_is_the_circular_one(rng, case):
+    """The same pairs in both layouts give the same direction, to within 4
+    ulp of its largest element (the compiler fuses the dots differently,
+    nothing else differs), lane by lane under ``vmap``."""
+    from photon_ml_tpu.optimize.lbfgs import two_loop_direction
+
+    circular, newest_first, stores = _histories(rng, case)
+    g = jnp.asarray(rng.normal(size=(HISTORY_LANES, HISTORY_D)), jnp.float32)
+    want = np.asarray(jax.vmap(two_loop_direction)(g, *circular))
+    got = np.asarray(jax.vmap(
+        lambda g, S, Y, rho, valid: two_loop_direction(g, S, Y, rho, valid,
+                                                       None)
+    )(g, *newest_first[:4]))
+    assert got.dtype == np.float32
+    ulp = np.spacing(np.abs(want).max(axis=1, keepdims=True))
+    assert (np.abs(got - want) <= 4 * ulp).all()
+    empty = stores == 0  # no pair yet: steepest descent, exactly
+    np.testing.assert_array_equal(got[empty], -np.asarray(g)[empty])
+    assert empty.any() == (case == "partly_valid")
+
+
+def test_newest_first_direction_lowers_without_a_gather():
+    """What the layout is for: under ``vmap`` the circular form indexes its
+    history with one ``head`` a lane (gathers), the newest-first form with
+    the scans' own counter (none), and its push is no scatter."""
+    from photon_ml_tpu.optimize.lbfgs import push_pair, two_loop_direction
+
+    lanes, m, d = 7, HISTORY_M, HISTORY_D
+    f32 = jnp.float32
+    g, S, rho = jnp.zeros((lanes, d), f32), jnp.zeros((lanes, m, d), f32), \
+        jnp.zeros((lanes, m), f32)
+    valid, head = jnp.zeros((lanes, m), bool), jnp.zeros(lanes, jnp.int32)
+    sy, store = jnp.zeros(lanes, f32), jnp.zeros(lanes, bool)
+
+    def lowered(fn, *args):
+        return jax.jit(jax.vmap(fn)).lower(*args).as_text()
+
+    circular = lowered(two_loop_direction, g, S, S, rho, valid, head)
+    newest = lowered(lambda *a: two_loop_direction(*a, None),
+                     g, S, S, rho, valid)
+    assert circular.count("stablehlo.gather") > 8
+    assert "stablehlo.gather" not in newest
+    assert "stablehlo.dynamic_slice" in newest
+    pushed = lowered(lambda *a: push_pair(*a[:4], None, *a[4:]),
+                     S, S, rho, valid, g, g, sy, store)
+    assert "scatter" not in pushed and "gather" not in pushed
+    assert "scatter" in lowered(push_pair, S, S, rho, valid, head, g, g, sy,
+                                store)
+
+
+def _lane_blocks(rng, lanes=9, n=64, d=6):
+    """Well-conditioned float32 logistic blocks, unequal across lanes."""
+    X = rng.normal(size=(lanes, n, d)) * rng.uniform(
+        0.5, 1.5, size=(lanes, 1, 1))
+    w = rng.normal(size=(lanes, d))
+    z = np.einsum("end,ed->en", X, w)
+    y = (rng.random((lanes, n)) < 1 / (1 + np.exp(-z))).astype(float)
+    return jnp.asarray(X, jnp.float32), jnp.asarray(y, jnp.float32)
+
+
+def check_vmapped_solve_is_each_lanes_own(rng, minimize, **solver_kw):
+    """The newest-first solve under ``vmap`` against every lane's own
+    unbatched (circular) solve: the same iterations and evaluations, the
+    coefficients within 1e-6 relative. Shared with tests/test_owlqn_tron."""
+    X, y = _lane_blocks(rng)
+    lanes, n, d = X.shape
+    obj = GLMObjective(loss=get_loss("logistic"), l2_lambda=1.0)
+    kw = dict(max_iter=40, tolerance=1e-5, **solver_kw)
+
+    def payload(Xe, ye):
+        return obj, dense_batch(Xe, ye, dtype=jnp.float32)
+
+    x, hist, ok = jax.vmap(
+        lambda Xe, ye: minimize(_obj_vg, jnp.zeros(d, jnp.float32),
+                                payload(Xe, ye), newest_first=True, **kw)
+    )(X, y)
+    iterations = np.asarray(hist.num_iterations)
+    assert len(set(iterations.tolist())) > 1 and iterations.max() < 40
+    assert iterations.min() > 3  # the history is in use
+    for e in range(lanes):
+        x_e, hist_e, ok_e = minimize(_obj_vg, jnp.zeros(d, jnp.float32),
+                                     payload(X[e], y[e]), **kw)
+        k = int(hist_e.num_iterations)
+        assert k == iterations[e], e
+        np.testing.assert_array_equal(np.asarray(hist.evaluations)[e],
+                                      np.asarray(hist_e.evaluations))
+        assert bool(ok_e) == bool(ok[e])
+        scale = float(jnp.max(jnp.abs(x_e)))
+        np.testing.assert_allclose(np.asarray(x[e]), np.asarray(x_e),
+                                   rtol=0, atol=1e-6 * scale)
+        # written by select under vmap, by ``.at[].set`` alone
+        np.testing.assert_allclose(np.asarray(hist.values)[e, :k + 1],
+                                   np.asarray(hist_e.values)[:k + 1],
+                                   rtol=1e-6)
+        assert np.isnan(np.asarray(hist.values)[e, k + 1:]).all()
+
+
+def test_vmapped_newest_first_solve_is_each_lanes_own_solve(rng):
+    check_vmapped_solve_is_each_lanes_own(rng, minimize_lbfgs)
+
+
+@pytest.mark.parametrize("newest_first", (False, True),
+                         ids=("circular", "newest_first"))
+def test_a_carry_goes_back_into_its_own_layout(rng, newest_first):
+    """A solve stopped after 3 iterations and resumed is the uninterrupted
+    solve in either layout, and a carry is refused by the other layout."""
+    _, _, batch, obj = _logistic_fit_problem(rng)
+    x0 = jnp.zeros(6, jnp.float64)
+    kw = dict(tolerance=1e-9, newest_first=newest_first)
+    whole, whole_hist, _ = minimize_lbfgs(_obj_vg, x0, (obj, batch),
+                                          max_iter=12, **kw)
+    _, _, _, carry = minimize_lbfgs(_obj_vg, x0, (obj, batch), max_iter=3,
+                                    return_carry=True, **kw)
+    assert (carry.head is None) == newest_first
+    resumed, hist, _ = minimize_lbfgs(_obj_vg, carry.x, (obj, batch),
+                                      max_iter=9, resume=carry, **kw)
+    np.testing.assert_array_equal(np.asarray(resumed), np.asarray(whole))
+    assert int(hist.num_iterations) + 3 == int(whole_hist.num_iterations)
+    with pytest.raises(ValueError, match="history layout"):
+        minimize_lbfgs(_obj_vg, carry.x, (obj, batch), max_iter=9,
+                       resume=carry, tolerance=1e-9,
+                       newest_first=not newest_first)

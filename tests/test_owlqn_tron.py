@@ -19,6 +19,7 @@ from photon_ml_tpu.optimize.common import BoxConstraints, OptimizationResult
 from photon_ml_tpu.optimize.lbfgs import minimize_lbfgs
 from photon_ml_tpu.optimize.owlqn import minimize_owlqn, pseudo_gradient
 from photon_ml_tpu.optimize.tron import minimize_tron
+from test_lbfgs import check_vmapped_solve_is_each_lanes_own
 
 
 def _obj_vg(w, payload):
@@ -410,3 +411,33 @@ def test_owlqn_on_the_ragged_layout_against_the_textbook_and_the_dense_fit(
     zero, zero_ref = on_ell == 0.0, w_ref == 0.0
     assert 0.2 < zero.mean() < 0.99  # the penalty selects
     assert np.mean(zero != zero_ref) <= 0.01
+
+
+# --- OWL-QN's history in the layout for a solve under vmap ------------------
+
+def test_vmapped_newest_first_owlqn_is_each_lanes_own_solve(rng):
+    """OWL-QN shares L-BFGS's recursion and its two history layouts: under
+    ``vmap`` with the newest-first one, every lane runs its own circular
+    solve (same iterations, same evaluations, coefficients to 1e-6)."""
+    check_vmapped_solve_is_each_lanes_own(rng, minimize_owlqn, l1=0.3)
+
+
+@pytest.mark.parametrize("newest_first", (False, True),
+                         ids=("circular", "newest_first"))
+def test_an_owlqn_carry_goes_back_into_its_own_layout(rng, newest_first):
+    batch, obj = _problem(rng, l2=0.1)
+    x0 = jnp.zeros(8, jnp.float64)
+    kw = dict(l1=0.05, tolerance=1e-9, newest_first=newest_first)
+    whole, whole_hist, _ = minimize_owlqn(_obj_vg, x0, (obj, batch),
+                                          max_iter=12, **kw)
+    _, _, _, carry = minimize_owlqn(_obj_vg, x0, (obj, batch), max_iter=4,
+                                    return_carry=True, **kw)
+    assert (carry.head is None) == newest_first
+    resumed, hist, _ = minimize_owlqn(_obj_vg, carry.x, (obj, batch),
+                                      max_iter=8, resume=carry, **kw)
+    np.testing.assert_array_equal(np.asarray(resumed), np.asarray(whole))
+    assert int(hist.num_iterations) + 4 == int(whole_hist.num_iterations)
+    with pytest.raises(ValueError, match="history layout"):
+        minimize_owlqn(_obj_vg, carry.x, (obj, batch), max_iter=8,
+                       resume=carry, l1=0.05, tolerance=1e-9,
+                       newest_first=not newest_first)

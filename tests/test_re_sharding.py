@@ -14,6 +14,7 @@ entity mesh is carved from the conftest's 8 virtual CPU devices
 compaction, and the psum score reduction all run for real.
 """
 
+import dataclasses
 import logging
 import os
 
@@ -66,6 +67,7 @@ from photon_ml_tpu.parallel.mesh import (
 from photon_ml_tpu.utils import faults
 from photon_ml_tpu.utils import sync_telemetry
 from photon_ml_tpu.utils.events import EventEmitter, RecoveryEvent
+from test_game import lanes_that_skip_the_store_before
 
 
 @pytest.fixture(autouse=True)
@@ -210,6 +212,41 @@ def test_sharded_bucketed_parity_f64(rng, name, opt, reg, lam):
     set_default_mesh(_entity_mesh())
     s_out = np.asarray(score_random_effect(ds, out[0], entity_shards=4))
     np.testing.assert_allclose(s_out, s_ref, rtol=1e-10, atol=1e-12)
+
+
+@pytest.mark.parametrize("name,opt,reg,lam", SOLVERS[:2],
+                         ids=[s[0] for s in SOLVERS[:2]])
+def test_sharded_chunks_resume_past_a_skipped_store_f64(rng, name, opt, reg,
+                                                        lam):
+    """The sharded resume path with the newest-first carry where it is
+    tested hardest: under a tolerance of 1e-13 some lanes' 10th iteration
+    stores no pair (``s.y <= 1e-10``) and they go on, so with chunks of 10
+    their history crosses the boundary unshifted and is gathered, on
+    device, into the resumed program. Per-lane iteration counts exactly
+    and coefficients to the file's f64 tolerance, as unchunked."""
+    chunk = 10
+    data = _re_data(rng)
+    ds = _re_ds(data, num_buckets=1)
+    n = len(data.responses)
+    cfg = dataclasses.replace(_glm_cfg(opt, reg, lam), tolerance=1e-13)
+
+    problem = RandomEffectOptimizationProblem(cfg,
+                                              TaskType.LOGISTIC_REGRESSION)
+    e, _, d = ds.X.shape
+    l1 = jnp.full(d, cfg.regularization_context.l1_weight(
+        cfg.regularization_weight))
+
+    skipped_and_goes_on = lanes_that_skip_the_store_before(
+        chunk, (ds.X, ds.labels, jnp.asarray(ds.offsets_with(np.zeros(n))),
+                ds.weights, jnp.zeros((e, d))),
+        problem.objective(), l1, name, 1e-13)
+    assert skipped_and_goes_on
+
+    ref, out = _run_pair(ds, n, cfg, chunk)
+    assert (np.asarray(ref[1])[skipped_and_goes_on] > chunk).all()
+    np.testing.assert_allclose(np.asarray(out[0]), np.asarray(ref[0]),
+                               rtol=1e-10, atol=1e-12)
+    np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(ref[1]))
 
 
 def test_entity_shards_without_mesh_falls_back_bit_identical(rng, caplog):

@@ -287,6 +287,47 @@ def test_donating_random_effect_fit_compiles(one_chip, variant):
     assert e * n * 4 <= aliased < 2 * e * n * 4, (variant, aliased)
 
 
+# "%gather.3 = f32[675,128]{1,0:T(8,128)} gather(": the instruction, with
+# the scopes it was traced under in its metadata
+_INDEXED_BY_LANE = re.compile(r" (gather|scatter)\(.*op_name=\"([^\"]*)\"")
+
+
+@pytest.mark.parametrize("solver", ["lbfgs", "owlqn"])
+def test_per_entity_solve_indexes_no_history_by_lane(one_chip, solver):
+    """The per-entity solves keep the curvature history newest-first
+    (optimize/lbfgs.py): under ``vmap`` a circular history's ``head`` is one
+    index a lane, and the optimised program of the parent held 11 gathers
+    under ``lbfgs.direction`` (one 128-float row a lane a step) and 6
+    scatters under ``lbfgs.update`` (PERF.md, PR 36). Now neither scope
+    holds either, for both solvers that share the recursion; the scans
+    slice every lane's slot at once, and the compiler lays the
+    ``[E, 10, D]`` history out slot-major, its 10 rows unpadded."""
+    from photon_ml_tpu.game import random_effect
+
+    e, n, d = GLMIX_BUCKET
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    obj = GLMObjective(loss=get_loss("logistic"), l2_lambda=1.0)
+    compiled = random_effect._fit_blocks.lower(
+        sds(e, n, d), sds(e, n), sds(e, n), sds(e, n), sds(e, d), obj,
+        sds(d), solver=solver, max_iter=20, tolerance=1e-7).compile()
+    text = compiled.as_text()
+    for scope in ("direction", "linesearch", "update"):
+        assert f"{solver}.{scope}" in text  # the scopes are there to read
+    by_lane = [(op, name) for op, name in _INDEXED_BY_LANE.findall(text)
+               if f"{solver}.direction" in name or f"{solver}.update" in name]
+    assert by_lane == []
+    assert f"f32[{e},10,{d}]{{2,0,1" in text  # slot-major: 10 rows, not 16
+    # the bound: less than one [E, 10, D] array, so no copy of the history
+    # is among the temporaries (1,345,024 bytes for L-BFGS and 1,657,344
+    # for OWL-QN, what the parent's programs read here too; at the cell's
+    # [31492, 128, 128] bucket 1,068,955,136 against the parent's
+    # 1,404,802,560: PERF.md, PR 36)
+    assert compiled.memory_analysis().temp_size_in_bytes < e * 10 * d * 4
+
+
 @pytest.mark.parametrize("d", [GLMIX_FIXED_DIM, GLM_SHAPE[1]])
 def test_sharded_fixed_effect_step_compiles(mesh, as_on_tpu_mesh, d):
     """``chip_smoke.py --chips 4``'s fixed-effect update: the solver inside
@@ -616,6 +657,10 @@ def test_scoring_by_position_compiles_without_a_scatter(one_chip):
         text = compiled.as_text()
         assert not re.search(r"\bscatter\(", text)  # the instruction
         assert "re.score" in text
+        # the margins are a product and a sum in float32: as an einsum
+        # alone in its program, a bucket 64 columns wide or more became an
+        # MXU convolution over coefficients rounded to bfloat16 (PR 36)
+        assert "convolution" not in text and "bf16" not in text
     text = gather.as_text()
     assert "jit__gather_scores" in text[:400]
     assert f"f32[{GAME_ROWS}]" in text  # the gathered score vector
