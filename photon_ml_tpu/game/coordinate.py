@@ -297,6 +297,15 @@ class FixedEffectCoordinate:
 # ---------------------------------------------------------------------------
 
 
+def _exchange_offsets(coordinate, extra_scores: Array):
+    """The offset half of a random-effect coordinate's score exchange,
+    under the host span that says where it was dispatched."""
+    ds = coordinate.dataset
+    with trace.span("re.offsets", coordinate=coordinate.coordinate_id,
+                    blocks=ds.num_blocks):
+        return ds.offsets_with(extra_scores)
+
+
 @dataclasses.dataclass
 class RandomEffectCoordinate:
     """Per-entity GLM coordinate, vmapped over the entity axis.
@@ -309,6 +318,10 @@ class RandomEffectCoordinate:
 
     dataset: RandomEffectDataset
     problem: RandomEffectOptimizationProblem
+    # the id the coordinate-descent loop runs it under (it says so at its
+    # start): the ``coordinate`` label of the exchange's spans, spelled as
+    # ``cd.update`` spells it
+    coordinate_id: str = ""
 
     @property
     def num_samples(self) -> int:
@@ -319,7 +332,7 @@ class RandomEffectCoordinate:
 
     def update(self, coefs: Optional[Array], extra_scores: Array
                ) -> tuple[Array, Tracker]:
-        offsets = self.dataset.offsets_with(extra_scores)
+        offsets = _exchange_offsets(self, extra_scores)
         # ``donate=True``: the per-update offset block is rebuilt from the
         # CD score vector every update, so the solver may reuse its device
         # buffer in place (no-op on CPU; ``coefs`` — the CD loop's live
@@ -338,7 +351,8 @@ class RandomEffectCoordinate:
         return score_random_effect(
             self.dataset, coefs,
             entity_shards=self.problem.entity_shards,
-            collective_quant=self.problem.collective_quant)
+            collective_quant=self.problem.collective_quant,
+            coordinate=self.coordinate_id)
 
     def regularization_value(self, coefs: Array) -> float:
         return self.problem.regularization_value(coefs)
@@ -413,6 +427,7 @@ class FactoredRandomEffectCoordinate:
     latent_dim: int
     num_inner_iterations: int = 2
     seed: int = 0
+    coordinate_id: str = ""  # as RandomEffectCoordinate's
 
     def __post_init__(self):
         ds = self.dataset
@@ -524,7 +539,7 @@ class FactoredRandomEffectCoordinate:
                extra_scores: Array) -> tuple[tuple[Array, Array], Tracker]:
         coefs, B = state if state is not None else self.initial_state()
         ds = self.dataset
-        offsets = ds.offsets_with(extra_scores)
+        offsets = _exchange_offsets(self, extra_scores)
         # The init is drawn in f32 so its BITS don't depend on the x64
         # mode; the running state then promotes to the ambient dtype (x64
         # runs keep solving in f64, with the identical starting values).
@@ -578,7 +593,8 @@ class FactoredRandomEffectCoordinate:
         return score_random_effect(
             self.dataset, self.entity_coefficients(state),
             entity_shards=self.problem.entity_shards,
-            collective_quant=self.problem.collective_quant)
+            collective_quant=self.problem.collective_quant,
+            coordinate=self.coordinate_id)
 
     def regularization_value(self, state: tuple[Array, Array]) -> float:
         coefs, B = state

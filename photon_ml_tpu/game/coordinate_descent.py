@@ -113,9 +113,15 @@ def _sample_live_bytes(sweep: int) -> None:
     sweep-boundary drain, so pipeline depth and the drain policy can be
     tuned from a trace (are deferred buffers accumulating between
     drains?). Metadata-only — enumerating live arrays never syncs the
-    device — and skipped entirely when tracing is off (the enumeration
-    is O(#arrays) host work that the untraced hot path must not pay)."""
-    if trace.get_tracer() is None:
+    device — and run only where an observed run asked for it
+    (``--trace-dir``: ``obs/run.py`` switches ``devicemem.watch_sweeps``):
+    the enumeration is two O(#arrays) walks of host work that neither the
+    untraced hot path nor a process that is merely armed (it records
+    spans, as every benchmark run does) must pay."""
+    # --device-telemetry: the sweep's per-coordinate commit watermarks go
+    # out at the same boundary (a switch of their own: no-op unless armed)
+    devicemem.drain_coordinate_watermarks(sweep)
+    if not devicemem.sweeps_watched():
         return
     try:
         total_bytes = sum(int(getattr(a, "nbytes", 0) or 0)
@@ -142,9 +148,6 @@ def _sample_live_bytes(sweep: int) -> None:
         pass
     with trace.span("cd.hbm_sample", sweep=sweep, live_bytes=total_bytes):
         pass
-    # --device-telemetry: attribute the sweep's per-coordinate commit
-    # watermarks at the same boundary (no-op unless armed)
-    devicemem.drain_coordinate_watermarks(sweep)
 
 
 @dataclasses.dataclass
@@ -550,6 +553,11 @@ def run_coordinate_descent(
                                (snap.get("coordinate_failures")
                                 or {}).items()}
         quarantined = set(snap.get("quarantined") or [])
+
+    # a coordinate that labels its spans learns the id it runs under
+    for cid in ids:
+        if hasattr(coordinates[cid], "coordinate_id"):
+            coordinates[cid].coordinate_id = cid
 
     # Init: zero states, zero scores (CoordinateDescent.scala:93-101).
     states = dict(initial_states or {})

@@ -29,11 +29,13 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import time
 from typing import Optional
 
 import numpy as np
 import scipy.sparse as sp
 
+import jax
 import jax.numpy as jnp
 
 from photon_ml_tpu.data.batch import (
@@ -43,6 +45,9 @@ from photon_ml_tpu.data.batch import (
     ell_from_csr,
 )
 from photon_ml_tpu.io.native_loader import pack_projected_rows_native
+from photon_ml_tpu.obs import compile as obs_compile
+from photon_ml_tpu.obs import trace
+from photon_ml_tpu.obs.metrics import REGISTRY
 from photon_ml_tpu.projector.projectors import (
     IndexMapProjectors,
     ProjectorConfig,
@@ -122,6 +127,48 @@ def zero_scores(n: int) -> np.ndarray:
     return np.zeros(n)
 
 
+class _BuildStages:
+    """The stages of one block build, one after another: entering a stage
+    closes the one before it, so the stages tile the build and their
+    seconds add up to it. Each is a ``dataset.<stage>`` span (the
+    timeline) and, at its close, its seconds on the always-on counter
+    ``block_build_secs{stage, coordinate}`` (what the benchmark's
+    ``build_*_s`` read), as ``lower_secs{site}`` is booked beside
+    ``xla.lower``. Host clocks only: no stage waits for the device."""
+
+    def __init__(self, coordinate: str):
+        self._coordinate = coordinate
+        self._stage = self._span = None
+        self._t0 = 0.0
+
+    def enter(self, stage: str, **labels) -> None:
+        if stage == self._stage and self._span is not None:
+            return  # still in it
+        self.close()
+        self._stage = stage
+        self._span = trace.span(f"dataset.{stage}",
+                                coordinate=self._coordinate, **labels)
+        self._span.__enter__()
+        # the counter's clock runs inside the span: both read one interval
+        self._t0 = time.perf_counter()
+
+    def close(self) -> None:
+        if self._span is None:
+            return
+        REGISTRY.counter("block_build_secs").inc(
+            time.perf_counter() - self._t0, stage=self._stage,
+            coordinate=self._coordinate)
+        span, self._span = self._span, None
+        span.__exit__(None, None, None)
+
+    def __enter__(self) -> "_BuildStages":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.close()
+        return False
+
+
 # ---------------------------------------------------------------------------
 # Fixed-effect view
 # ---------------------------------------------------------------------------
@@ -179,10 +226,13 @@ def build_fixed_effect_dataset(
     dense_threshold: int = DENSE_FEATURE_THRESHOLD,
 ) -> FixedEffectDataset:
     mat = data.feature_shards[shard_id]
-    batch = csr_to_batch(mat, data.responses, data.offsets, data.weights,
-                         dtype=dtype, dense_threshold=dense_threshold)
-    return FixedEffectDataset(shard_id=shard_id, batch=batch,
-                              base_offsets=batch.offsets)
+    with _BuildStages(shard_id) as stages:
+        stages.enter("fixed", rows=int(mat.shape[0]), cols=int(mat.shape[1]))
+        batch = csr_to_batch(mat, data.responses, data.offsets,
+                             data.weights, dtype=dtype,
+                             dense_threshold=dense_threshold)
+        return FixedEffectDataset(shard_id=shard_id, batch=batch,
+                                  base_offsets=batch.offsets)
 
 
 # ---------------------------------------------------------------------------
@@ -423,26 +473,24 @@ class RandomEffectDataset:
     def num_passive(self) -> int:
         return 0 if self.passive_X is None else int(self.passive_X.shape[0])
 
-    def gather_offsets(self, scores: Array) -> Array:
-        """Entity-major view of a sample-major score vector (CD offset
-        injection — the all-to-all resharding analog of
-        RandomEffectDataSet.addScoresToOffsets :55-74)."""
-        padded = jnp.concatenate([scores, jnp.zeros(1, scores.dtype)])
-        return padded[self.row_ids]
-
     def offsets_with(self, extra_scores: Array):
         """Per-block training offsets (base + other coordinates' scores):
-        one ``[E, N_max]`` array, or a list per bucket when bucketed."""
-        if self.buckets is None:
-            return self.base_offsets + self.gather_offsets(extra_scores)
-        padded = jnp.concatenate(
-            [extra_scores, jnp.zeros(1, extra_scores.dtype)])
-        return [b.base_offsets + padded[b.row_ids] for b in self.buckets]
+        one ``[E, N_max]`` array, or a list per bucket when bucketed. The
+        offset half of the score exchange (CD offset injection — the
+        all-to-all resharding analog of
+        RandomEffectDataSet.addScoresToOffsets :55-74): one device program
+        a dataset, ``_block_offsets``."""
+        blocks = self.buckets if self.buckets is not None else [self]
+        out = obs_compile.call(
+            "re.offsets", _block_offsets,
+            (tuple(b.base_offsets for b in blocks),
+             tuple(b.row_ids for b in blocks), extra_scores),
+            arg_names=("base_offsets", "row_ids", "extra_scores"))
+        return out if self.buckets is not None else out[0]
 
-    def gather_passive_offsets(self, scores: Array) -> Array:
-        if self.passive_row_ids is None:
-            return jnp.zeros(0)
-        return scores[self.passive_row_ids]
+    @property
+    def num_blocks(self) -> int:
+        return 1 if self.buckets is None else len(self.buckets)
 
     def score_positions(self) -> Array:
         """``[num_samples]`` int32: each row's place in the concatenation
@@ -475,6 +523,20 @@ class RandomEffectDataset:
                     "its score cannot be gathered from one position")
             self._score_positions = jnp.asarray(positions)
         return self._score_positions
+
+
+@jax.jit
+def _block_offsets(base_offsets, row_ids, extra_scores: Array) -> list:
+    """Every block's ``base_offsets + extra_scores[row_ids]`` (a padded
+    row, id ``num_samples``, reads the one zero appended): what the eager
+    ``padded[b.row_ids]`` a block did, as one program a dataset under a
+    name of the program's own (``jit__block_offsets`` in a device trace;
+    one dispatch an update where the eager form made three a block)."""
+    with jax.named_scope("re.offsets"):
+        padded = jnp.concatenate(
+            [extra_scores, jnp.zeros(1, extra_scores.dtype)])
+        return [base + padded[ids]
+                for base, ids in zip(base_offsets, row_ids)]
 
 
 def _topk_per_segment(seg: np.ndarray, score: np.ndarray,
@@ -765,9 +827,12 @@ def _pack_entity_buckets(
     random_projector: Optional[RandomProjector],
     d_red: int,
     dtype,
+    stages: _BuildStages,
     pad_dim_multiple: int = 8,
 ) -> list[EntityBucket]:
-    """Pack active rows into per-bucket (N_b, D_b) blocks.
+    """Pack active rows into per-bucket (N_b, D_b) blocks (stage ``pack``)
+    and hand each to the device as it is done (stage ``transfer``), so the
+    host holds one bucket's rows at a time.
 
     ``ent_of_act`` are GLOBAL compact entity indices (bucket-major order);
     bucket b owns entities [starts[b], starts[b] + bucket_sizes[b]). Each
@@ -775,10 +840,12 @@ def _pack_entity_buckets(
     projection narrows tall-entity buckets too — that is the D half of the
     (N, D) bucketing), padded for lane alignment.
     """
+    stages.enter("pack")
     starts = np.concatenate([[0], np.cumsum(bucket_sizes)])
     bucket_of_act = np.searchsorted(starts, ent_of_act, side="right") - 1
     buckets: list[EntityBucket] = []
     for b in range(len(bucket_sizes)):
+        stages.enter("pack")
         nr = int(bucket_sizes[b])
         start = int(starts[b])
         n_b = int(bucket_n_max[b])
@@ -814,6 +881,7 @@ def _pack_entity_buckets(
             raw_indices=None if projectors is None
             else projectors.raw_indices[start:start + nr, :d_b])
 
+        stages.enter("transfer")
         buckets.append(EntityBucket(
             entity_start=start, num_real=nr,
             X=jnp.asarray(X, dtype),
@@ -847,12 +915,35 @@ def build_random_effect_dataset(
     EntityBucket. Entity order becomes bucket-major (balanced within each
     bucket) and the returned dataset carries ``buckets`` instead of one
     global block.
+
+    One ``dataset.build{coordinate, rows, entities}`` span (``coordinate``
+    is the id type), tiled by its stages (:class:`_BuildStages`): ``group``
+    (the lexsort and reservoir split, the bucket plan, the balanced
+    order), ``project`` (the CSR row gather and the per-entity feature
+    spaces), ``pack`` (rows into padded blocks), ``passive`` (densify and
+    project the passive rows), ``transfer`` (the host's part of every copy
+    to the device: nothing here waits for the device, the rest of a copy
+    shows where its array is first used or waited for).
     """
     id_type = config.random_effect_type
     if id_type not in data.id_columns:
         raise KeyError(f"id type {id_type!r} not in dataset (have "
                        f"{list(data.id_columns)})")
-    codes = np.asarray(data.id_columns[id_type])
+    with trace.span("dataset.build", coordinate=id_type) as build_span, \
+            _BuildStages(id_type) as stages:
+        out = _build_random_effect_dataset(
+            data, config, seed, pad_rows_multiple, dtype, entity_axis_size,
+            num_buckets, stages)
+        build_span.label(rows=out.num_samples, entities=out.num_entities)
+        return out
+
+
+def _build_random_effect_dataset(data, config, seed, pad_rows_multiple,
+                                 dtype, entity_axis_size, num_buckets,
+                                 stages: _BuildStages
+                                 ) -> RandomEffectDataset:
+    stages.enter("group")
+    codes = np.asarray(data.id_columns[config.random_effect_type])
     mat = data.feature_shards[config.feature_shard_id].tocsr()
     n, raw_dim = mat.shape
     rng = np.random.default_rng(seed)
@@ -916,6 +1007,7 @@ def build_random_effect_dataset(
     counts = act_counts[perm]  # active rows per local entity
 
     # --- per-entity feature space (projection).
+    stages.enter("project")
     proj_cfg = config.projector
     projectors = None
     random_projector = None
@@ -945,9 +1037,10 @@ def build_random_effect_dataset(
             bucket_sizes=bucket_sizes, bucket_n_max=bucket_n_max,
             entity_axis_size=entity_axis_size,
             projectors=projectors, random_projector=random_projector,
-            d_red=d_red, dtype=dtype)
+            d_red=d_red, dtype=dtype, stages=stages)
         X = None
     else:
+        stages.enter("pack")
         buckets = None
         # --- pad E to the entity axis and N to a stable multiple.
         e_pad = max(1,
@@ -974,6 +1067,7 @@ def build_random_effect_dataset(
             else projectors.raw_indices)
 
     # --- passive side (sample-major, already projected per entity).
+    stages.enter("passive")
     p_X = p_ent = p_rows = p_off = None
     if passive_mask.any():
         pr = order[passive_mask]
@@ -986,11 +1080,15 @@ def build_random_effect_dataset(
             table_ent=local.astype(np.int64), global_ent=local,
             raw_indices=None if projectors is None
             else projectors.raw_indices)
+        p_rows = pr.astype(np.int32)
+        p_off = data.offsets[pr].astype(np.float32)
+        stages.enter("transfer")
         p_X = jnp.asarray(dense)
         p_ent = jnp.asarray(local)
-        p_rows = jnp.asarray(pr.astype(np.int32))
-        p_off = jnp.asarray(data.offsets[pr].astype(np.float32))
+        p_rows = jnp.asarray(p_rows)
+        p_off = jnp.asarray(p_off)
 
+    stages.enter("transfer")
     return RandomEffectDataset(
         config=config,
         entity_codes=ent_codes,
@@ -1083,8 +1181,32 @@ def build_random_effect_dataset_streamed(
     partitioned shuffle output. Requires ``entity_axis_size`` divisible
     by K (every bucket's padded E_b then splits evenly). Passive arrays
     remain global.
+
+    The same ``dataset.build`` span and stages as the in-RAM builder's:
+    ``group`` is pass 1, ``project`` the stats pass, ``pack`` pass 2 (which
+    scatters a part's active and passive rows together, so this builder
+    books no ``passive``), ``transfer`` the device commit where there is
+    one.
     """
+    with trace.span("dataset.build",
+                    coordinate=config.random_effect_type) as build_span, \
+            _BuildStages(config.random_effect_type) as stages:
+        out = _build_random_effect_dataset_streamed(
+            stream_factory, config, raw_dim, seed, pad_rows_multiple,
+            entity_axis_size, num_buckets, blocks_dir, pad_dim_multiple,
+            keep_host_blocks, entity_shard, dtype, stages)
+        build_span.label(rows=out.num_samples, entities=out.num_entities,
+                         streamed=True)
+        return out
+
+
+def _build_random_effect_dataset_streamed(
+        stream_factory, config, raw_dim, seed, pad_rows_multiple,
+        entity_axis_size, num_buckets, blocks_dir, pad_dim_multiple,
+        keep_host_blocks, entity_shard, dtype, stages: _BuildStages
+) -> RandomEffectDataset:
     # ---- pass 1: scalar columns only ------------------------------------
+    stages.enter("group")
     codes_parts, y_parts, off_parts, wt_parts = [], [], [], []
     for chunk in stream_factory():
         _, c, y, o, w = chunk
@@ -1164,6 +1286,7 @@ def build_random_effect_dataset_streamed(
          passive_mask, codes)
 
     # ---- projector (streamed stats pass for INDEX_MAP) -------------------
+    stages.enter("project")
     proj_cfg = config.projector
     projectors = None
     random_projector = None
@@ -1189,6 +1312,7 @@ def build_random_effect_dataset_streamed(
         d_red = raw_dim
 
     # ---- allocate destination blocks ------------------------------------
+    stages.enter("pack")
     if entity_shard is not None:
         shard_k, shard_count = entity_shard
         if not 0 <= shard_k < shard_count:
@@ -1291,6 +1415,7 @@ def build_random_effect_dataset_streamed(
             p_off[pp] = offs[rows_g]
         lo = hi
 
+    stages.enter("transfer")
     host_blocks = blocks_dir is not None or keep_host_blocks
     buckets = []
     for b in range(len(bucket_sizes)):

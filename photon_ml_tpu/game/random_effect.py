@@ -1097,6 +1097,19 @@ def _passive_margins(passive_X: Array, passive_entity: Array,
         return jnp.sum(passive_X * coefs[passive_entity], axis=-1)
 
 
+@partial(jax.jit, static_argnames=("spans",))
+def _bucket_coefs(coefs: Array, spans: tuple) -> list:
+    """The compact global block cut into every bucket's own ``[E_b, D_b]``
+    block: bucket ``(start, num_real, e_b, d_b)`` takes rows ``start:start
+    + num_real`` and the first ``d_b`` columns, its pad lanes zeros (the
+    zeros and ``.at[].set`` a bucket the eager form made, one program a
+    dataset: ``jit__bucket_coefs`` in a device trace)."""
+    with jax.named_scope("re.score"):
+        return [jnp.zeros((e_b, d_b), coefs.dtype).at[:num_real].set(
+                    coefs[start:start + num_real, :d_b])
+                for start, num_real, e_b, d_b in spans]
+
+
 @jax.jit
 def _gather_scores(margins, positions: Array) -> Array:
     """Each sample's score from its one place among the blocks' margins
@@ -1109,7 +1122,8 @@ def _gather_scores(margins, positions: Array) -> Array:
 
 def score_random_effect(dataset: RandomEffectDataset, coefs: Array,
                         entity_shards: int = 1,
-                        collective_quant: str = "none") -> Array:
+                        collective_quant: str = "none",
+                        coordinate: str = "") -> Array:
     """Full sample-axis score vector (active + passive) for this coordinate.
 
     ``coefs`` is the compact global block ``[num_entities, reduced_dim]``.
@@ -1127,7 +1141,9 @@ def score_random_effect(dataset: RandomEffectDataset, coefs: Array,
     CD fused epilogue with zero added host syncs;
     ``collective_quant="int8"`` ships that psum's partials
     blockwise-quantized (parallel/quantized_collectives.py) and counts
-    the wire bytes on ``collective_bytes{site="re.score_psum"}``."""
+    the wire bytes on ``collective_bytes{site="re.score_psum"}``.
+    ``coordinate`` labels the host span of the gathering form, ``re.score``
+    (the mesh path's blocks have ``re.shard_score``)."""
     from photon_ml_tpu.parallel.quantized_collectives import \
         record_collective_bytes
 
@@ -1144,27 +1160,33 @@ def score_random_effect(dataset: RandomEffectDataset, coefs: Array,
                 return out
         return score_active(X, c_b, row_ids, weights, dataset.num_samples)
 
-    def _bucket_coefs(bucket):
-        e_b, _, d_b = bucket.X.shape
-        nr, start = bucket.num_real, bucket.entity_start
-        c_b = jnp.zeros((e_b, d_b), coefs.dtype)
-        return c_b.at[:nr].set(coefs[start:start + nr, :d_b])
+    def bucket_coefs():
+        return obs_compile.call(
+            "re.bucket_coefs", _bucket_coefs,
+            (coefs, tuple(
+                (b.entity_start, b.num_real, int(b.X.shape[0]),
+                 int(b.X.shape[2])) for b in dataset.buckets)),
+            static_argnums=(1,), arg_names=("coefs", "spans"))
 
     positions = dataset.score_positions() if entity_shards <= 1 else None
     if positions is not None:
-        if dataset.buckets is not None:
-            margins = [_active_margins(b.X, _bucket_coefs(b), b.weights)
-                       for b in dataset.buckets]
-        else:
-            margins = [_active_margins(dataset.X, coefs, dataset.weights)]
-        if dataset.num_passive:
-            margins.append(_passive_margins(
-                dataset.passive_X, dataset.passive_entity, coefs))
-        return _gather_scores(margins, positions)
+        with trace.span("re.score", coordinate=coordinate,
+                        blocks=dataset.num_blocks):
+            if dataset.buckets is not None:
+                margins = [_active_margins(b.X, c_b, b.weights)
+                           for b, c_b in zip(dataset.buckets,
+                                             bucket_coefs())]
+            else:
+                margins = [_active_margins(dataset.X, coefs,
+                                           dataset.weights)]
+            if dataset.num_passive:
+                margins.append(_passive_margins(
+                    dataset.passive_X, dataset.passive_entity, coefs))
+            return _gather_scores(margins, positions)
     if dataset.buckets is not None:
         s = jnp.zeros(dataset.num_samples, jnp.float32)
-        for bucket in dataset.buckets:
-            s = s + _score_block(bucket.X, _bucket_coefs(bucket),
+        for bucket, c_b in zip(dataset.buckets, bucket_coefs()):
+            s = s + _score_block(bucket.X, c_b,
                                  bucket.row_ids, bucket.weights)
     else:
         s = _score_block(dataset.X, coefs, dataset.row_ids, dataset.weights)

@@ -79,14 +79,15 @@ def arm(registry: Optional[MetricsRegistry] = None) -> None:
     _REGISTRY = registry or REGISTRY
     _ARMED = True
     # the device plane's one switch: armed spans also land in any
-    # jax.profiler capture, on the device trace's clock
-    trace.mirror_to_profiler(True)
+    # jax.profiler capture, on the device trace's clock, and are kept for
+    # a reader in this process (obs/trace.py, "Armed")
+    trace.arm(True)
 
 
 def disarm() -> None:
     global _ARMED
     _ARMED = False
-    trace.mirror_to_profiler(False)
+    trace.arm(False)
 
 
 def is_armed() -> bool:

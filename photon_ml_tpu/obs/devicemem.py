@@ -19,9 +19,17 @@ The second device-plane half of ``--device-telemetry``. Armed, it:
   emitting a ``hbm_watermark_bytes{coordinate}`` gauge plus one
   ``cd.hbm_watermark`` span per coordinate touched that sweep.
 
-Everything is gated on :func:`armed` so the un-flagged hot path pays
-one module-global check, and jax is imported lazily so ``obs.run``
+Everything above is gated on :func:`armed` so the un-flagged hot path
+pays one module-global check, and jax is imported lazily so ``obs.run``
 stays importable on a bare host.
+
+One switch here is not ``--device-telemetry``'s: :func:`watch_sweeps`,
+which every observed run (``--trace-dir``, ``obs/run.py``) turns on and
+the coordinate-descent loop asks (:func:`sweeps_watched`) before it walks
+``jax.live_arrays()`` at a sweep's end. It is a switch of its own because
+"a tracer is installed" no longer means "somebody wants samples": a
+process that is only armed (a benchmark run) records spans and must run
+no other host code for it.
 """
 
 from __future__ import annotations
@@ -33,6 +41,7 @@ from photon_ml_tpu.obs import trace
 from photon_ml_tpu.obs.metrics import REGISTRY, MetricsRegistry
 
 _ARMED = False
+_SWEEPS_WATCHED = False
 _REGISTRY: MetricsRegistry = REGISTRY
 _LOCK = threading.Lock()
 _PEAK_BYTES = 0
@@ -62,6 +71,17 @@ def disarm() -> None:
 
 def armed() -> bool:
     return _ARMED
+
+
+def watch_sweeps(on: bool) -> None:
+    """An observed run asks for (or stops) the sweep-boundary live-bytes
+    sample of ``game/coordinate_descent.py``."""
+    global _SWEEPS_WATCHED
+    _SWEEPS_WATCHED = on
+
+
+def sweeps_watched() -> bool:
+    return _SWEEPS_WATCHED
 
 
 def peak_bytes() -> int:

@@ -33,7 +33,7 @@ import threading
 import time
 from typing import Callable, Optional
 
-from photon_ml_tpu.obs import trace
+from photon_ml_tpu.obs import devicemem, trace
 from photon_ml_tpu.obs.export import TELEMETRY_PROTO, TelemetrySink
 from photon_ml_tpu.obs.heartbeat import Heartbeat
 from photon_ml_tpu.obs.metrics import REGISTRY, MetricsRegistry
@@ -143,7 +143,6 @@ class ObservedRun:
         self._sample_on_beat = False
         if device_telemetry:
             from photon_ml_tpu.obs import compile as obs_compile
-            from photon_ml_tpu.obs import devicemem
 
             obs_compile.arm(registry=self._registry)
             devicemem.arm(registry=self._registry)
@@ -208,6 +207,9 @@ class ObservedRun:
         self._spill_lock = threading.Lock()
         self._pending: list = []  # drained but not yet durably written
         self.tracer = trace.enable(process_index=process_index)
+        # an observed run wants the sweep-boundary live-bytes samples; a
+        # process that merely records spans (armed) does not get them
+        devicemem.watch_sweeps(True)
         self.heartbeat = Heartbeat(
             self.tracer, out_path=self.metrics_path,
             interval_seconds=heartbeat_seconds,
@@ -305,6 +307,7 @@ class ObservedRun:
             obs_compile.disarm()
             if self._devicemem is not None:
                 self._devicemem.disarm()
+        devicemem.watch_sweeps(False)
         if trace.get_tracer() is self.tracer:
             trace.disable()
 

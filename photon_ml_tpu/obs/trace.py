@@ -9,10 +9,15 @@ metrics half). Call sites write::
 
 and pay essentially nothing when tracing is disabled (the module-level
 ``span()`` returns a shared no-op singleton) and two
-``time.perf_counter_ns`` reads plus one locked list append when enabled —
+``time.perf_counter_ns`` reads plus one locked append when enabled —
 no jax import, no device work, so instrumented hot loops keep their
 sync-discipline contract (tests/test_obs.py proves a traced CD sweep
 survives ``jax.transfer_guard_device_to_host("disallow")``).
+
+The store is a ring: a :class:`Tracer` keeps the **newest**
+``max_buffered_spans`` closed spans and counts every older one it had to
+let go on ``spans_dropped``, so a tracer nobody drains holds a fixed
+amount of host memory however long it records.
 
 Export formats:
 
@@ -28,18 +33,33 @@ Per-thread nesting depth comes from a ``threading.local`` span stack; the
 stack snapshots also feed the heartbeat's stall report (which spans are
 currently open when nothing has closed for too long).
 
-**Mirroring into the profiler's trace** (:func:`mirror_to_profiler`,
-switched by ``obs.compile.arm()`` / ``--device-telemetry``): a span also
-enters a ``jax.profiler.TraceAnnotation(name, **labels)``, so it lands on
-the host plane of any ``jax.profiler`` capture, on the profiler's own
-clock beside the device's operations, its labels as the event's stats —
-whether or not a tracer is installed. Outside a capture the annotation is
-a microsecond of native code. Hand-timed :func:`record_span` spans are not
-mirrored (an annotation cannot be back-dated).
+**Armed** (:func:`arm`, switched by ``obs.compile.arm()`` /
+``--device-telemetry``; every benchmark run arms) means two things, and
+nothing else:
+
+- spans are *mirrored* into the profiler's trace
+  (:func:`mirror_to_profiler`): a span also enters a
+  ``jax.profiler.TraceAnnotation(name, **labels)``, so it lands on the
+  host plane of any ``jax.profiler`` capture, on the profiler's own clock
+  beside the device's operations, its labels as the event's stats. Outside
+  a capture the annotation is a microsecond of native code. Hand-timed
+  :func:`record_span` spans are not mirrored (an annotation cannot be
+  back-dated);
+- spans are *kept*: where no tracer is installed, arming installs one
+  that holds the newest :data:`ARMED_MAX_BUFFERED_SPANS`, so that a reader
+  in the same process can take a window's durations after the window
+  (``benchmark/readers/span_time.py``). An ``ObservedRun`` installs its own
+  tracer over it and keeps it: there is one store either way, whatever
+  :func:`get_tracer` returns.
+
+Armed switches on no other host work: what samples or exports because a
+run is *observed* (``--trace-dir``) asks ``obs/run.py`` for it, never
+"is there a tracer".
 """
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import threading
@@ -116,11 +136,17 @@ class _Span:
 
 
 #: Buffer backstop for a tracer nobody drains (tests, ad-hoc
-#: ``trace.enable()``): past this many buffered spans new ones are
-#: dropped (and counted on ``spans_dropped``) instead of growing host
-#: RAM without bound. An ObservedRun never gets near it — its heartbeat
+#: ``trace.enable()``): past this many buffered spans the oldest are let
+#: go (and counted on ``spans_dropped``) instead of growing host RAM
+#: without bound. An ObservedRun never gets near it — its heartbeat
 #: drains the buffer into ``spans.jsonl`` every few seconds.
 DEFAULT_MAX_BUFFERED_SPANS = 1_000_000
+
+#: What the tracer that :func:`arm` installs keeps: a benchmark window is
+#: a few thousand spans (PERF.md, "what recording costs armed"), a span
+#: with its labels some 300 bytes, so an armed process that never drains
+#: holds 20 MB of them at the most.
+ARMED_MAX_BUFFERED_SPANS = 1 << 16
 
 
 class Tracer:
@@ -134,7 +160,9 @@ class Tracer:
         self._t0_ns = time.perf_counter_ns()
         self.start_unix = time.time()
         self._lock = threading.Lock()
-        self._events: list[tuple] = []
+        # the newest max_buffered_spans closed spans, oldest first
+        self._events: collections.deque = collections.deque(
+            maxlen=max_buffered_spans)
         self._local = threading.local()
         # thread id -> that thread's live span stack (mutated only by its
         # owner; read racily by the heartbeat for stall reporting)
@@ -174,12 +202,9 @@ class Tracer:
         event = (name, threading.get_ident(), depth,
                  start_ns - self._t0_ns, end_ns - start_ns, labels)
         with self._lock:
-            if len(self._events) < self.max_buffered_spans:
-                self._events.append(event)
-            else:
-                self.spans_dropped += 1
-            # closed (even if the record was dropped): the stall signal
-            # must not flip just because the buffer is full
+            if len(self._events) == self.max_buffered_spans:
+                self.spans_dropped += 1  # the append lets the oldest go
+            self._events.append(event)
             self.spans_closed += 1
             self._last_close_ns = end_ns
 
@@ -249,8 +274,8 @@ class Tracer:
         ``spans.jsonl`` so a long run's buffer stays bounded and a
         killed run keeps every span spilled so far."""
         with self._lock:
-            snapshot = self._events
-            self._events = []
+            snapshot = list(self._events)
+            self._events.clear()
         return self._as_dicts(snapshot)
 
     def chrome_trace(self) -> dict:
@@ -294,9 +319,13 @@ def chrome_document(events: list[dict], process_index: int,
 #: Process-global tracer; None = tracing disabled (the default).
 _tracer: Optional[Tracer] = None
 
+#: The tracer :func:`arm` installed (None where it found one installed).
+_armed_tracer: Optional[Tracer] = None
+
 
 def enable(process_index: int = 0) -> Tracer:
-    """Install (and return) a fresh process-global tracer."""
+    """Install (and return) a fresh process-global tracer (over the one
+    arming installed, where there is one)."""
     global _tracer
     _tracer = Tracer(process_index=process_index)
     return _tracer
@@ -311,10 +340,26 @@ def get_tracer() -> Optional[Tracer]:
     return _tracer
 
 
+def arm(on: bool) -> None:
+    """Armed: spans are mirrored into ``jax.profiler`` captures and kept
+    (see the module docstring). ``obs.compile.arm()`` / ``disarm()`` call
+    this: there is one switch for the device plane, not two. Disarming
+    takes away only the tracer that arming installed."""
+    global _tracer, _armed_tracer
+    mirror_to_profiler(on)
+    if on:
+        if _tracer is None:
+            _tracer = _armed_tracer = Tracer(
+                max_buffered_spans=ARMED_MAX_BUFFERED_SPANS)
+    else:
+        if _tracer is _armed_tracer:
+            _tracer = None
+        _armed_tracer = None
+
+
 def mirror_to_profiler(on: bool) -> None:
-    """Switch the mirroring of spans into ``jax.profiler`` captures (see
-    the module docstring). ``obs.compile.arm()`` / ``disarm()`` call this:
-    there is one switch for the device plane, not two."""
+    """Switch the mirroring of spans into ``jax.profiler`` captures alone
+    (:func:`arm` calls this; a test of the mirror calls it directly)."""
     global _annotation
     if on:
         from jax.profiler import TraceAnnotation

@@ -590,11 +590,18 @@ class TestDriverFlags:
 
 class TestHbmSampling:
     def test_live_bytes_gauge_sampled_at_drain(self, rng):
+        """Where an observed run asked for it (``obs/run.py`` switches
+        ``devicemem.watch_sweeps``), never merely because spans are
+        recorded (tests/test_obs_device.py holds that half)."""
+        from photon_ml_tpu.obs import devicemem
+
         data = make_data(rng)
         tracer = trace.enable()
+        devicemem.watch_sweeps(True)
         try:
             run_cd(data, iters=1)
         finally:
+            devicemem.watch_sweeps(False)
             events = tracer.events()
             trace.disable()
         samples = [e for e in events if e["name"] == "cd.hbm_sample"]

@@ -118,6 +118,36 @@ class TestSpanTracer:
         trace.disable()
         assert trace.span("outer") is trace._NULL_SPAN
 
+    def test_armed_keeps_spans_in_the_one_store(self):
+        """Arming installs a tracer of the newest ARMED_MAX_BUFFERED_SPANS
+        where there is none; a tracer that was enabled (an observed run's)
+        stays the store, and disarming takes away only its own."""
+        from photon_ml_tpu.obs import compile as obs_compile
+
+        assert trace.get_tracer() is None
+        obs_compile.arm()
+        armed = trace.get_tracer()
+        assert armed.max_buffered_spans == trace.ARMED_MAX_BUFFERED_SPANS
+        with trace.span("kept", n=1):
+            pass
+        assert [(e["name"], e["labels"]) for e in armed.events()] \
+            == [("kept", {"n": 1})]
+        obs_compile.arm()  # idempotent: the same store
+        assert trace.get_tracer() is armed
+        mine = trace.enable()  # an observed run installs its own over it
+        with trace.span("observed"):
+            pass
+        assert [e["name"] for e in mine.events()] == ["observed"]
+        assert len(armed.events()) == 1
+        obs_compile.disarm()
+        assert trace.get_tracer() is mine  # not arming's to take away
+        trace.disable()
+        obs_compile.arm()
+        assert trace.get_tracer() not in (None, armed, mine)
+        obs_compile.disarm()
+        assert trace.get_tracer() is None
+        assert trace.span("off") is trace._NULL_SPAN
+
     def test_thread_safety(self):
         t = trace.enable()
         n_threads, n_spans = 8, 200
@@ -452,6 +482,23 @@ class TestObservedRunDurability:
         assert t.spans_dropped == 2
         # the stall signal counts every close, dropped record or not
         assert t.spans_closed == 5
+
+    def test_a_full_buffer_keeps_the_newest_and_lets_the_oldest_go(self):
+        """The store is a ring: a tracer nobody drains holds its newest
+        ``max_buffered_spans`` (what a reader wants after a window), and
+        a drain gives the ring its whole room back."""
+        t = trace.Tracer(max_buffered_spans=3)
+        for i in range(5):
+            with t.span("s", i=i):
+                pass
+        assert [e["labels"]["i"] for e in t.events()] == [2, 3, 4]
+        assert [e["labels"]["i"] for e in t.drain()] == [2, 3, 4]
+        assert t.events() == [] and t.spans_dropped == 2
+        for i in range(5, 9):
+            with t.span("s", i=i):
+                pass
+        assert [e["labels"]["i"] for e in t.events()] == [6, 7, 8]
+        assert (t.spans_dropped, t.spans_closed) == (3, 9)
 
     def test_drain_empties_buffer_and_keeps_recording(self):
         t = trace.Tracer()

@@ -22,6 +22,7 @@ The ``--device-telemetry`` contracts:
   usage error.
 """
 
+import collections
 import json
 import os
 
@@ -236,6 +237,74 @@ class TestArmedHotLoopContracts:
         assert sync_telemetry.host_fetch_count() == 2 * len(coords)
         # and the armed run attributed watermarks without syncing
         assert devicemem.peak_bytes() > 0
+
+    def test_armed_alone_records_spans_and_runs_no_live_array_walk(
+            self, rng, monkeypatch):
+        """Armed without an observed run (what every benchmark run is): a
+        sweep's spans are kept in the store arming installed, the sweep
+        still survives the device-to-host transfer guard with one fetch an
+        update beside its tracker's drain, and ``_sample_live_bytes`` does
+        not walk
+        ``jax.live_arrays()`` merely because spans are recorded. An
+        observed run (``devicemem.watch_sweeps``, which ``obs/run.py``
+        switches) gets its two walks a sweep."""
+        from photon_ml_tpu.game import coordinate_descent as cd
+        from photon_ml_tpu.game.coordinate_descent import (
+            run_coordinate_descent,
+        )
+        from photon_ml_tpu.optimize.config import TaskType
+        from photon_ml_tpu.utils import sync_telemetry
+
+        coords, labels, weights, offsets = _cd_inputs(
+            rng, n=240, n_entities=6)
+        walks = []
+        live_arrays = jax.live_arrays
+        monkeypatch.setattr(
+            jax, "live_arrays",
+            lambda *a, **k: walks.append(1) or live_arrays(*a, **k))
+
+        def sweep():
+            return run_coordinate_descent(
+                coords, 1, TaskType.LOGISTIC_REGRESSION, labels, weights,
+                offsets)
+
+        obs_compile.arm()
+        tracer = trace.get_tracer()
+        assert tracer is not None
+        sweep()  # compile everything at these shapes OUTSIDE the guard
+        tracer.drain()
+        cd.reset_hot_loop_stats()
+        sync_telemetry.reset_host_fetches()
+        with jax.transfer_guard_device_to_host("disallow"):
+            res = sweep()
+        assert len(res.states) == len(coords)
+        # an update's one epilogue fetch and its tracker's explicit drain
+        assert sync_telemetry.host_fetch_count() == 2 * len(coords)
+        assert walks == []
+        names = collections.Counter(e["name"] for e in tracer.events())
+        assert names["cd.sweep"] == 1
+        assert names["cd.dispatch"] == names["cd.epilogue_fetch"] \
+            == len(coords)
+        assert names["re.offsets"] == names["re.score"] == 1
+        assert names["cd.hbm_sample"] == 0
+        devicemem.watch_sweeps(True)
+        try:
+            sweep()
+        finally:
+            devicemem.watch_sweeps(False)
+        assert len(walks) == 2
+        assert [e["name"] for e in tracer.events()].count(
+            "cd.hbm_sample") == 1
+
+    def test_observed_runs_watch_their_sweeps_and_stop_at_finish(
+            self, tmp_path):
+        assert not devicemem.sweeps_watched()
+        run = start_observed_run(str(tmp_path), heartbeat_seconds=3600)
+        try:
+            assert devicemem.sweeps_watched()
+        finally:
+            run.finish()
+        assert not devicemem.sweeps_watched()
 
     def test_armed_records_count_updates_not_solver_iterations(
             self, rng, registry):

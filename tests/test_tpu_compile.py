@@ -667,3 +667,39 @@ def test_scoring_by_position_compiles_without_a_scatter(one_chip):
     assert gather.memory_analysis().output_size_in_bytes >= GAME_ROWS * 4
     assert sum(c.memory_analysis().temp_size_in_bytes
                for c in programs + [gather]) < V5E_HBM_BYTES / 2
+
+
+# glmix-ml10m.train's rows and its four per-user buckets (E, N, D)
+GLMIX_CELL_ROWS = 10_000_054
+GLMIX_CELL_BUCKETS = ((31492, 128, 128), (9268, 96, 96), (13170, 72, 72),
+                      (15948, 48, 48))
+
+
+def test_the_score_exchanges_named_programs_compile_at_the_cells_shapes(
+        one_chip):
+    """The offset exchange is one program a dataset and the buckets'
+    coefficient blocks one more (PR 37): at GLMix's four buckets and ten
+    million scores both compile for the chip under the names a device
+    trace shows (``jit__block_offsets``, ``jit__bucket_coefs``; a
+    ``jit_gather`` a bucket before), their operations under the scopes
+    ``re.offsets`` / ``re.score``."""
+    from photon_ml_tpu.game import dataset, random_effect
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    offsets = dataset._block_offsets.lower(
+        tuple(sds((e, n)) for e, n, _ in GLMIX_CELL_BUCKETS),
+        tuple(sds((e, n), jnp.int32) for e, n, _ in GLMIX_CELL_BUCKETS),
+        sds((GLMIX_CELL_ROWS,)))
+    assert "jit__block_offsets" in offsets.as_text()[:200]
+    text = offsets.compile().as_text()
+    assert "re.offsets" in text and text.count(" gather(") >= 1
+    spans, start = [], 0
+    for e, _, d in GLMIX_CELL_BUCKETS:
+        spans.append((start, e - 3, e, d))  # three pad lanes a bucket
+        start += e - 3
+    coefs = random_effect._bucket_coefs.lower(sds((start, 128)),
+                                              spans=tuple(spans))
+    assert "jit__bucket_coefs" in coefs.as_text()[:200]
+    assert "re.score" in coefs.compile().as_text()
