@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from photon_ml_tpu.data.batch import EllBatch
+from photon_ml_tpu.obs.metrics import REGISTRY
 from photon_ml_tpu.optimize.common import (
     BoxConstraints,
     RunHistory,
@@ -254,6 +256,43 @@ def record(trail: Array, at: Array, value: Array, by_select: bool) -> Array:
     return trail.at[at].set(value)
 
 
+def start_evaluation(value_and_grad_fn, x0: Array, data):
+    """The value and gradient at ``x0`` that a fresh solve starts from, in
+    the form the batch's layout needs. Made at the top level of the
+    program, a row-sparse batch's (``EllBatch``) gather reads its table,
+    its indices and its output from HBM, at twice a line-search trial's
+    time a slot (PERF.md, PR 30). The line search evaluates inside a loop,
+    on the table ``x + a*d`` its body makes, and there the compiler places
+    all three in VMEM. So on that layout the start is evaluated the same
+    way: in a loop, on a table its body makes by a select, which keeps
+    every bit of ``x0`` (``x0 + 0*x0`` would turn an infinite entry into
+    NaN). The loop stops on a flag it sets, not on a counted trip, which
+    the compiler would see through and inline. Every other batch keeps
+    the direct call: a dense start is as fast or faster outside the loop
+    (PERF.md, section 5). Booked on ``solver_start_lowerings{site, form}``
+    at trace time, as ``objective_lowerings`` is."""
+    in_loop = any(
+        isinstance(node, EllBatch)
+        for node in jax.tree.leaves(
+            data, is_leaf=lambda node: isinstance(node, EllBatch)))
+    REGISTRY.counter("solver_start_lowerings").inc(
+        site="optimizer.lbfgs", form="in_loop" if in_loop else "direct")
+    if not in_loop:
+        return value_and_grad_fn(x0, data)
+
+    def body(c):
+        done, _, _ = c
+        return (jnp.bool_(True),
+                *value_and_grad_fn(jnp.where(done, jnp.zeros_like(x0), x0),
+                                   data))
+
+    with jax.named_scope("lbfgs.start"):
+        _, f, g = lax.while_loop(
+            lambda c: ~c[0], body,
+            (jnp.bool_(False), jnp.zeros((), x0.dtype), jnp.zeros_like(x0)))
+    return f, g
+
+
 @partial(jax.jit, static_argnums=(0, 3, 4, 5, 7, 9, 10, 11, 12))
 def _minimize_lbfgs_impl(
     value_and_grad_fn,
@@ -297,7 +336,7 @@ def _minimize_lbfgs_impl(
     d = x0.shape[0]
     dtype = x0.dtype
     if resume is None:
-        f_start, g_start = value_and_grad_fn(x0, data)
+        f_start, g_start = start_evaluation(value_and_grad_fn, x0, data)
         anchor_f0 = f_start
         anchor_g0n = vnorm(g_start)
         x_start = x0

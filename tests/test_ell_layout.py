@@ -455,6 +455,90 @@ def test_rows_of_one_length_give_the_one_block_bit_for_bit(rng):
     assert lowered[0] == lowered[1]  # one program, not two
 
 
+def _one_block(rng):
+    cols, vals, y = _criteo_like(rng, n=700, d=96, k=7)
+    return ell_batch(cols.T, vals.T, y, dim=96)
+
+
+# Starts whose every square and partial sum is a float32 integer below
+# 2**24, so that no order of summing them rounds: XLA:CPU takes the L2
+# term's ``dot(x, x)`` through a library call at the top level of a program
+# and as a fused loop inside a ``while``, which round differently (by 1 ulp
+# at 96 columns of a normal draw; the TPU makes both a multiply and a reduce
+# in one fusion). So what the solves below differ by is the start's form,
+# never the CPU's emission of a dot.
+_STARTS = {"zeros": lambda rng, d: np.zeros(d),
+           "large-and-negative": lambda rng, d: np.where(
+               np.arange(d) % 3 == 0, -500.0,
+               np.round(rng.normal(size=d) * 30.0))}
+
+
+def _row_sparse_payload(rng, ragged, layout):
+    batch = _one_block(rng) if layout == "one-block" else ragged[1]
+    return batch, (GLMObjective(losses.logistic_loss, l2_lambda=0.7),
+                   rows_in_layout_order(batch))
+
+
+@pytest.mark.parametrize("start", sorted(_STARTS))
+@pytest.mark.parametrize("layout", ["one-block", "several-blocks"])
+def test_the_row_sparse_start_made_in_a_loop_is_the_direct_starts_solve(
+        rng, ragged, monkeypatch, layout, start):
+    """On an ``EllBatch`` the L-BFGS start is evaluated inside a loop (the
+    placement the line search gets on a TPU), booked ``in_loop`` once a
+    trace; the solve returns, bit for bit, what the same solve returns
+    from the start taken by ``objective.calculate(x0, batch)`` directly."""
+    from photon_ml_tpu.obs.metrics import REGISTRY
+    from photon_ml_tpu.optimize import lbfgs
+
+    batch, payload = _row_sparse_payload(rng, ragged, layout)
+    x0 = jnp.asarray(_STARTS[start](rng, batch.dim), batch.values.dtype)
+    booked = REGISTRY.counter("solver_start_lowerings")
+
+    def solve():
+        def vg(w, p):  # a new function: the solve traces anew
+            return p[0].calculate(w, p[1])
+
+        x, hist, _ = lbfgs.minimize_lbfgs(vg, x0, payload, max_iter=12,
+                                          tolerance=1e-12)
+        return [x, hist.values, hist.grad_norms, hist.evaluations]
+
+    before = booked.value(site="optimizer.lbfgs", form="in_loop")
+    in_loop = solve()
+    assert booked.value(site="optimizer.lbfgs",
+                        form="in_loop") == before + 1
+    with monkeypatch.context() as m:
+        m.setattr(lbfgs, "start_evaluation",
+                  lambda vg, x0, data: vg(x0, data))
+        direct = solve()
+    assert int(in_loop[3][0]) == 1 and int(in_loop[3][1]) >= 1
+    for a, b in zip(in_loop, direct):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("layout", ["one-block", "several-blocks"])
+def test_the_row_sparse_start_keeps_every_bit_of_x0(rng, ragged, layout):
+    """The table the loop's body makes is ``x0`` bit for bit: an infinite
+    entry stays infinite (``x0 + 0*x0`` would make it NaN, and its column's
+    gradient with it) and a negative zero stays negative."""
+    from photon_ml_tpu.optimize.lbfgs import start_evaluation
+
+    batch, payload = _row_sparse_payload(rng, ragged, layout)
+    x0 = np.round(rng.normal(size=batch.dim) * 3.0)
+    x0[[0, 5]] = -0.0, np.inf
+    x0 = jnp.asarray(x0, batch.values.dtype)
+
+    def vg(w, p):
+        return p[0].calculate(w, p[1])
+
+    got = jax.jit(lambda w, p: start_evaluation(vg, w, p))(x0, payload)
+    want = jax.jit(vg)(x0, payload)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.atleast_1d(a).view(np.uint8),
+                                      np.atleast_1d(b).view(np.uint8))
+    assert np.isinf(np.asarray(got[1])[5])
+
+
 def _lengths(law: str, n: int) -> np.ndarray:
     z = np.random.default_rng(8).normal(size=n)
     if law == "kddb":  # the KDD Cup 2010 cell's law (benchmark/configs)

@@ -442,6 +442,114 @@ def test_wide_sparse_solve_makes_no_plane_sized_temporary(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < plane * 4
 
 
+# "%fused_computation.3 (param_0.1: f32[1000000], ...) -> f32[11468800] {":
+# a computation's header, then its instructions, then "}"
+_HLO_COMPUTATION = re.compile(
+    r"^(?:ENTRY )?%(\S+) \([^\n]*\) -> [^\n]* \{$\n(.*?)^\}$",
+    re.MULTILINE | re.DOTALL)
+_HLO_SHAPE = re.compile(r"= (\w+\[[\d,]*\]\S*) ")
+VMEM = "S(1)"
+
+
+def _fusions_of(text, opcode, scope):
+    """[(output shape, parameters' shapes, scope path)] of every fusion
+    whose computation holds an ``opcode`` under ``scope``, the path that
+    instruction's. A parameter of a fused computation carries the memory
+    space its operand is read from."""
+    bodies = dict(_HLO_COMPUTATION.findall(text))
+    callers = {}
+    for body in bodies.values():
+        for line in body.splitlines():
+            called = re.search(r" fusion\(.*calls=%([\w.\-]+)", line)
+            if called:
+                callers[called.group(1)] = line
+    found = []
+    for name, body in bodies.items():
+        ops = [line for line in body.splitlines()
+               if f" {opcode}(" in line and f"/{scope}/" in line]
+        if ops and name in callers:
+            params = [_HLO_SHAPE.search(line).group(1)
+                      for line in body.splitlines() if " parameter(" in line]
+            path = re.search(r'op_name="([^"]*)"', ops[0]).group(1)
+            found.append((_HLO_SHAPE.search(callers[name]).group(1), params,
+                          path))
+    return found
+
+
+def test_the_row_sparse_solve_keeps_its_gathers_and_scatters_in_vmem(
+        one_chip):
+    """Every gather of the Criteo-shaped solve, the start's and the line
+    search's, reads its ``f32[1000000]`` table and its clamped indices and
+    writes its output in VMEM (memory space ``S(1)``); every scatter-add
+    writes its column sums there. The start is evaluated in a loop of its
+    own (``lbfgs.start``): evaluated at the program's top level its gather
+    had none of the three in VMEM and ran at 14.2 ns a slot against the
+    line search's 7.13 (PERF.md, PR 30 and PR 38)."""
+    text = _criteo_solve(one_chip).as_text()
+    gathers = _fusions_of(text, "gather", "objective.margins")
+    scatters = _fusions_of(text, "scatter", "objective.feature_sum")
+    for found in (gathers, scatters):
+        assert sorted("lbfgs.start" in path for _, _, path in found) == [
+            False, True]  # the start, and the line search's trials
+        assert all("lbfgs.start/while/" in path
+                   or "lbfgs.linesearch/while/" in path
+                   for _, _, path in found)
+    for out, params, _ in gathers:
+        assert params[0].startswith(f"f32[{CRITEO_DIM}]")
+        assert params[1].startswith(f"s32[{CRITEO_ROWS}]")
+        assert out.startswith(f"f32[{CRITEO_ROWS}]")
+        assert all(VMEM in shape for shape in [out] + params), (out, params)
+    for out, _, _ in scatters:
+        assert out.startswith(f"f32[{CRITEO_DIM}]") and VMEM in out
+
+
+def _start_scopes(text):
+    """The scope paths above ``objective.value_and_grad`` of the program's
+    start evaluation: every one outside the line search."""
+    return {name.split("objective.value_and_grad")[0]
+            for name in re.findall(r'op_name="([^"]*)"', text)
+            if "objective.value_and_grad" in name
+            and "lbfgs.linesearch" not in name}
+
+
+@pytest.mark.parametrize("solve", ["dense-2048", "fixed-effect-65", "criteo"])
+def test_the_start_is_made_in_a_loop_for_a_row_sparse_batch_alone(
+        one_chip, as_on_one_tpu, solve):
+    """``solver_start_lowerings{site, form}`` books the form once a traced
+    L-BFGS program: ``in_loop`` for the ``EllBatch`` solve, whose start
+    sits inside ``lbfgs.start``'s ``while``, and ``direct`` for the dense
+    solves (the GLM cells' at 2,048 columns, the sweep cells' 65-wide fixed
+    effect), whose start stays at the program's top level: its scope path
+    holds no ``/while/`` (a dense start is as fast or faster there: PERF.md,
+    section 5)."""
+    from photon_ml_tpu.obs.metrics import REGISTRY
+
+    counter = REGISTRY.counter("solver_start_lowerings")
+    forms = ("in_loop", "direct")
+    before = {f: counter.value(site="optimizer.lbfgs", form=f) for f in forms}
+    jax.clear_caches()  # the solver's trace is cached across programs
+    if solve == "criteo":
+        text, form = _criteo_solve(one_chip).as_text(), "in_loop"
+    else:
+        (n, d), form = {"dense-2048": GLM_SHAPE,
+                        "fixed-effect-65": (GLMIX_ROWS, GLMIX_FIXED_DIM)}[
+            solve], "direct"
+        problem = _l2_problem(6, 1e-30, 10.0)
+        x0 = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=one_chip)
+        text = jax.jit(problem.solve).lower(
+            problem.objective(), _dense(n, d, one_chip), x0).compile(
+        ).as_text()
+    assert {f: counter.value(site="optimizer.lbfgs", form=f) - before[f]
+            for f in forms} == {f: int(f == form) for f in forms}
+    scopes = _start_scopes(text)
+    assert scopes
+    if form == "direct":
+        assert not any("/while/" in scope for scope in scopes), scopes
+    else:
+        assert all(scope.endswith("lbfgs.start/while/body/")
+                   for scope in scopes), scopes
+
+
 def test_sharded_wide_sparse_solve_compiles(mesh, as_on_tpu_mesh):
     """The same solve inside ``shard_map``, the planes split along their
     row (minor) axis over the data axis of the 2x2 mesh."""
