@@ -132,6 +132,7 @@ class RandomEffectTracker:
     evaluation_rounds: Optional[np.ndarray] = None  # [B]
     bucket_lanes: Optional[np.ndarray] = None  # [B], pad lanes included
     site: str = "re.fit_blocks"
+    line_trials: Optional[np.ndarray] = None  # [E] each entity's own
     coordinate: Optional[str] = None  # the counters' second label
 
     def for_coordinate(self, coordinate: str) -> "RandomEffectTracker":
@@ -144,28 +145,32 @@ class RandomEffectTracker:
         if not isinstance(self.iterations, np.ndarray):
             from photon_ml_tpu.utils.sync_telemetry import record_host_fetch
 
-            it, v, c, ev, rounds = jax.device_get(tuple(
+            it, v, c, ev, rounds, tr = jax.device_get(tuple(
                 None if a is None else ensure_addressable(a)
                 for a in (self.iterations, self.final_values,
                           self.convergence_codes, self.evaluations,
-                          self.evaluation_rounds)))
+                          self.evaluation_rounds, self.line_trials)))
             record_host_fetch(site="tracker.materialize")
             nr = self.num_real
             if nr is not None:
                 it, v = it[:nr], v[:nr]
                 c = None if c is None else c[:nr]
                 ev = None if ev is None else ev[:nr]
+                tr = None if tr is None else tr[:nr]
             self.iterations, self.final_values = np.asarray(it), np.asarray(v)
             self.convergence_codes = None if c is None else np.asarray(c)
             self.num_real = None
             if ev is not None:
                 self.evaluations = np.asarray(ev)
                 self.evaluation_rounds = np.asarray(rounds)
+                self.line_trials = None if tr is None else np.asarray(tr)
                 record_solve(
                     self.site, int(self.iterations.sum()),
                     int(self.evaluations.sum()),
                     lane_evaluations=self._lane_evaluations(),
-                    coordinate=self.coordinate)
+                    coordinate=self.coordinate,
+                    line_trials=(None if tr is None
+                                 else int(self.line_trials.sum())))
         return self
 
     def _lane_evaluations(self) -> int:

@@ -88,11 +88,17 @@ class LaneCounts(NamedTuple):
     evaluation_rounds: Array  # [B] int32: the rounds it ran
     bucket_lanes: np.ndarray  # [B] host: its lanes, pads included
     site: str  # the obs/compile.py site the programs dispatched through
+    line_trials: Array  # [E] int32: each lane's line-search trials
 
 
 def _vg(w, payload):
     obj, batch = payload
     return obj.calculate(w, batch)
+
+
+def _line(w, d, payload):
+    obj, batch = payload
+    return obj.line(w, d, batch)
 
 
 def _hvp(w, v, payload):
@@ -216,13 +222,15 @@ def _fit_blocks_impl(
 ):
     """vmapped solve over entity blocks; returns (coefs [E,D], iters [E],
     final loss values [E], convergence codes [E] int8 — see
-    CONVERGENCE_CODE_NAMES — evaluations [E] int32, rounds [1] int32), plus
-    a per-lane solver carry when ``return_carry``. ``solver`` is
-    "lbfgs"/"owlqn"/"tron".
+    CONVERGENCE_CODE_NAMES — evaluations [E] int32, line trials [E] int32,
+    rounds [1] int32), plus a per-lane solver carry when
+    ``return_carry``. ``solver`` is "lbfgs"/"owlqn"/"tron".
 
     ``evaluations`` is each lane's own count of objective evaluations
     (the sum of its ``RunHistory.evaluations``: what its solo solve would
-    make). ``rounds`` = Σ_k max over lanes of ``evaluations[k]``: the
+    make); ``line trials`` its ``RunHistory.line_trials`` (L-BFGS, whose
+    search tries its steps on carried margins here: below), 0 for the other
+    solvers. ``rounds`` = Σ_k max over lanes of ``evaluations[k]``: the
     rounds of evaluation the batched loop ran for this dispatch, as far as
     the lanes themselves can tell. The solvers' loops evaluate once a pass
     and under no conditional (a batched ``lax.switch``/``lax.cond`` runs
@@ -258,7 +266,17 @@ def _fit_blocks_impl(
     ``head`` would be one index a lane and every slot read a gather of one
     row a lane. All four fit paths (this dispatch, the compacted one, and
     both sharded ones) trace this function, so all carry that layout, and
-    the ``LBFGSResume`` they pass between chunks has ``head=None``."""
+    the ``LBFGSResume`` they pass between chunks has ``head=None``.
+
+    L-BFGS is asked for trials on carried margins too (``line_fn``,
+    optimize/lbfgs.py), for the same reason: under ``vmap`` every lane pays
+    for the slowest lane's trials, and a full trial reads the ``[E, N, D]``
+    block twice where a trial on the margins reads ``[E, N]`` arrays. An
+    L-BFGS lane then makes its start and one full evaluation an iteration,
+    so ``rounds`` is 1 + the most iterations any lane made; the trials are
+    in no round, and the one pass an iteration that forms the margins in no
+    count. The carry stays an ``LBFGSResume`` (the margins are formed again
+    from its iterate)."""
 
     def solve_one(Xe, ye, oe, we, x0, res):
         batch = DenseBatch(X=Xe, labels=ye, offsets=oe, weights=we)
@@ -276,7 +294,8 @@ def _fit_blocks_impl(
             out = minimize_lbfgs(
                 _vg, x0, (obj, batch),
                 max_iter=max_iter, tolerance=tolerance,
-                resume=res, return_carry=return_carry, newest_first=True)
+                resume=res, return_carry=return_carry, newest_first=True,
+                line_fn=_line)
         x, hist, progressed = out[:3]
         carry = out[3] if return_carry else None
         k = hist.num_iterations
@@ -322,8 +341,10 @@ def _fit_blocks_impl(
         else:
             exhausted = CONV_MAX_ITERATIONS
         code = jnp.where(k >= max_iter, exhausted, converged)
+        trials = (jnp.zeros_like(hist.evaluations)
+                  if hist.line_trials is None else hist.line_trials)
         return (x, k, final_value, code.astype(jnp.int8), hist.evaluations,
-                carry)
+                trials, carry)
 
     with jax.named_scope("re.solve"):
         if resume is None:
@@ -334,8 +355,10 @@ def _fit_blocks_impl(
         else:
             out = jax.vmap(solve_one)(X, labels, offsets, weights, initial,
                                       resume)
-    x, k, final_value, code, evals_by_iter, carry = out  # [E, max_iter+1]
+    # [E, max_iter+1] each
+    x, k, final_value, code, evals_by_iter, trials_by_iter, carry = out
     counted = (x, k, final_value, code, jnp.sum(evals_by_iter, axis=1),
+               jnp.sum(trials_by_iter, axis=1),
                jnp.sum(jnp.max(evals_by_iter, axis=0), keepdims=True))
     return counted + (carry,) if return_carry else counted
 
@@ -458,12 +481,12 @@ def _fit_blocks_compacted(X, labels, offsets, weights, x0, obj, l1,
                                 boundary_convergence=not final_chunk,
                                 resume=carry,
                                 return_carry=not final_chunk)
-            c, it, v, k, ev, chunk_rounds = out[:6]
-            new_carry = None if final_chunk else out[6]
+            c, it, v, k, ev, tr, chunk_rounds = out[:7]
+            new_carry = None if final_chunk else out[7]
             rounds.append(chunk_rounds)
             lanes.append(int(c.shape[0]))
             still, still_local = state.absorb(idx, c, it, ev, v, k,
-                                              CONV_MAX_ITERATIONS)
+                                              CONV_MAX_ITERATIONS, tr)
         REGISTRY.histogram("re_chunk_active_lanes").observe(active_lanes)
         SOLVE_STATS["chunks"] += 1
         chunk_index += 1
@@ -495,12 +518,12 @@ def _fit_blocks_compacted(X, labels, offsets, weights, x0, obj, l1,
 
 def _with_counts(out, rounds: list, lanes: list,
                  site: str = "re.fit_blocks"):
-    """(coefs, iters, values, codes, evaluations) of one block plus the
-    rounds and lane counts of the programs that solved it -> the 5-tuple
-    :meth:`RandomEffectOptimizationProblem._fit` returns."""
+    """(coefs, iters, values, codes, evaluations, line trials) of one
+    block plus the rounds and lane counts of the programs that solved it
+    -> the 5-tuple :meth:`RandomEffectOptimizationProblem._fit` returns."""
     return tuple(out[:4]) + (LaneCounts(
         out[4], jnp.concatenate(rounds), np.asarray(lanes, np.int64),
-        site),)
+        site, out[5]),)
 
 
 # ---------------------------------------------------------------------------
@@ -531,7 +554,7 @@ def _sharded_fit_fn(mesh, solver, max_iter, tolerance,
 
     fit = _shard_map(impl, mesh,
                      in_specs=(lane, lane, lane, lane, lane, P(), P()),
-                     out_specs=tuple([lane] * (7 if return_carry else 6)))
+                     out_specs=tuple([lane] * (8 if return_carry else 7)))
     return jax.jit(fit)
 
 
@@ -565,7 +588,7 @@ def _sharded_resume_fit_fn(mesh, solver, max_iter, tolerance,
     fit = _shard_map(
         impl, mesh,
         in_specs=(lane, lane, lane, lane, lane, lane, P(), P(), lane),
-        out_specs=tuple([lane] * (7 if return_carry else 6)))
+        out_specs=tuple([lane] * (8 if return_carry else 7)))
     return jax.jit(fit)
 
 
@@ -663,16 +686,16 @@ def _fit_blocks_compacted_sharded(mesh, shards: int, X, labels, offsets,
                     cur_idx[1], obj, l1, carry, solver, budget, tolerance,
                     boundary_convergence=not final_chunk,
                     return_carry=not final_chunk)
-            c, it, v, k, ev, chunk_rounds = out[:6]  # rounds: [K]
-            new_carry = None if final_chunk else out[6]
+            c, it, v, k, ev, tr, chunk_rounds = out[:7]  # rounds: [K]
+            new_carry = None if final_chunk else out[7]
             rounds.append(chunk_rounds)
             lanes.extend([int(c.shape[0]) // K] * K)
             if idx is None:
                 still, still_local = state.absorb(None, c, it, ev, v, k,
-                                                  CONV_MAX_ITERATIONS)
+                                                  CONV_MAX_ITERATIONS, tr)
             else:
                 still, still_local = state.absorb_padded(
-                    idx, mask, c, it, ev, v, k, CONV_MAX_ITERATIONS)
+                    idx, mask, c, it, ev, v, k, CONV_MAX_ITERATIONS, tr)
         REGISTRY.histogram("re_chunk_active_lanes").observe(active_lanes)
         SOLVE_STATS["chunks"] += 1
         chunk_index += 1
@@ -867,7 +890,7 @@ class RandomEffectOptimizationProblem:
                         mesh, X, labels, offsets, weights, x0, obj,
                         l1_arr, solver, cfg.max_iterations,
                         float(cfg.tolerance))
-                    out = _with_counts(out[:5], [out[5]],
+                    out = _with_counts(out[:6], [out[6]],
                                        [e // shards] * shards,
                                        "re.shard_fit_blocks")
             # host-level chaos site (never traced): a drill here proves a
@@ -889,7 +912,7 @@ class RandomEffectOptimizationProblem:
         out = _dispatch_fit(
             X, labels, offsets, weights, x0, obj, l1_arr, solver,
             cfg.max_iterations, float(cfg.tolerance), donate)
-        return _with_counts(out[:5], [out[5]], [int(X.shape[0])])
+        return _with_counts(out[:6], [out[6]], [int(X.shape[0])])
 
     def run(
         self,
@@ -1011,7 +1034,9 @@ class RandomEffectOptimizationProblem:
             np.concatenate([n.bucket_lanes for *_, n in outs]),
             # one label a coordinate: a bucket too ragged for the mesh
             # falls back alone, and is booked with its coordinate's rest
-            outs[0][4].site)
+            outs[0][4].site,
+            jnp.concatenate([n.line_trials[:b.num_real]
+                             for b, (*_, n) in zip(dataset.buckets, outs)]))
         return coefs, iters, values, codes, counts
 
     def regularization_value_device(self, coefs: Array):

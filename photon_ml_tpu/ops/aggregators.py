@@ -148,6 +148,40 @@ def hessian_vector(
         return norm.reconstruct_gradient(vector_sum, prefactor_sum)
 
 
+def line_loss(
+    loss: PointwiseLoss,
+    norm: NormalizationContext,
+    coef: Array,
+    direction: Array,
+    batch: DenseBatch,
+    axis_name: Optional[str] = None,
+    collective_quant: str = "none",
+):
+    """The weighted loss along a line, a -> (sum_i w_i l(z_i(a)), its
+    slope), from one pass over the rows. The margins are affine in the
+    coefficients, z(coef + a d) = z + a zv with zv the direction's margins
+    without offsets (as in :func:`hessian_vector`), so after the pass that
+    forms z and zv a point of the line is elementwise work over the rows:
+      value(a) = sum_i w_i l(z_i + a zv_i)
+      slope(a) = sum_i w_i l'(z_i + a zv_i) zv_i  = grad(coef + a d) . d
+    (the gradient's reconstruction from raw-feature sums, dotted with d, is
+    exactly this sum). A dense batch only: the per-entity blocks."""
+    with jax.named_scope("objective.line"):
+        w_eff, margin_shift = norm.effective_coefficients(coef)
+        v_eff, v_shift = norm.effective_coefficients(direction)
+        z, zv = batch.margin_pair(w_eff, margin_shift, v_eff, v_shift)
+
+    def at(a):
+        l, d1 = loss.loss_and_d1(z + a * zv, batch.labels)
+        value = _maybe_psum(jnp.sum(batch.weights * l), axis_name,
+                            collective_quant)
+        slope = _maybe_psum(jnp.sum(batch.weights * d1 * zv), axis_name,
+                            collective_quant)
+        return value, slope
+
+    return at
+
+
 def hessian_diagonal(
     loss: PointwiseLoss,
     norm: NormalizationContext,
@@ -232,6 +266,22 @@ class GLMObjective:
         value = value + 0.5 * self.l2_lambda * jnp.dot(coef, coef)
         grad = grad + self.l2_lambda * coef
         return value, grad
+
+    def line(self, coef: Array, direction: Array, batch: DenseBatch):
+        """The objective along ``coef + a * direction``: makes one pass over
+        the rows (:func:`line_loss`) and returns ``phi(a) -> (f(coef + a
+        direction), grad(coef + a direction) . direction)``, which makes
+        none."""
+        loss_at = line_loss(self.loss, self.norm, coef, direction, batch,
+                            self.axis_name, self.collective_quant)
+
+        def phi(a):
+            value, slope = loss_at(a)
+            x_a = coef + a * direction
+            return (value + 0.5 * self.l2_lambda * jnp.dot(x_a, x_a),
+                    slope + self.l2_lambda * jnp.dot(x_a, direction))
+
+        return phi
 
     def hessian_vector(self, coef: Array, vector: Array, batch: Batch) -> Array:
         hv = hessian_vector(self.loss, self.norm, coef, vector, batch,

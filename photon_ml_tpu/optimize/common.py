@@ -121,11 +121,19 @@ class RunHistory(NamedTuple):
     # iteration k: the line search's trials plus the box projection's
     # re-evaluation. TRON's rejected trial steps are booked to the slot of
     # the iteration they were tried for, so the total is the sum over ALL
-    # slots, not only the first ``num_iterations + 1``.
+    # slots, not only the first ``num_iterations + 1``. An L-BFGS solve
+    # whose line search tries its steps on carried margins (``line_fn``)
+    # books its start and one evaluation an iteration, at the accepted
+    # point: its trials are ``line_trials``.
     evaluations: Optional[Array] = None
     # TRON only: Hessian-vector products per iteration (one more pass over
     # the rows each), booked like ``evaluations``.
     hvps: Optional[Array] = None
+    # L-BFGS with ``line_fn`` only: the line search's trials per iteration,
+    # each elementwise work over the rows' carried margins and no pass
+    # over the rows (the one pass an iteration that forms the margins is
+    # in neither count).
+    line_trials: Optional[Array] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -173,7 +181,9 @@ class OptimizationResult:
                        else int(np.sum(history.evaluations)))
         hvps = None if history.hvps is None else int(np.sum(history.hvps))
         if site is not None:
-            record_solve(site, k, evaluations, hvps, coordinate=coordinate)
+            record_solve(site, k, evaluations, hvps, coordinate=coordinate,
+                         line_trials=(None if history.line_trials is None
+                                      else int(np.sum(history.line_trials))))
         values = np.asarray(history.values)[: k + 1]
         grad_norms = np.asarray(history.grad_norms)[: k + 1]
         reason = _convergence_reason(
@@ -197,12 +207,15 @@ class OptimizationResult:
 def record_solve(site: str, iterations: int, evaluations: Optional[int],
                  hvps: Optional[int] = None,
                  lane_evaluations: Optional[int] = None,
-                 coordinate: Optional[str] = None) -> None:
+                 coordinate: Optional[str] = None,
+                 line_trials: Optional[int] = None) -> None:
     """Book solves whose counts just reached the host on the
     ``solver_*{site}`` counters. A solve with no evaluation count books
     nothing: a ratio of the counters must never mix counted and uncounted
     solves. ``lane_evaluations`` is what a batched loop executed (lanes x
     rounds, pad lanes included); a single solve executes what it needs.
+    ``line_trials`` (``RunHistory.line_trials``) goes on
+    ``solver_line_trials``, 0 for solves whose trials are full evaluations.
     ``coordinate`` (the id of the GAME coordinate whose update made the
     solve, in the updating sequence) is a second label beside ``site``: a
     sweep's coordinates share their sites, and a reader that filters on
@@ -217,6 +230,7 @@ def record_solve(site: str, iterations: int, evaluations: Optional[int],
     REGISTRY.counter("solver_lane_evaluations").inc(
         evaluations if lane_evaluations is None else lane_evaluations,
         **labels)
+    REGISTRY.counter("solver_line_trials").inc(line_trials or 0, **labels)
     if hvps is not None:
         REGISTRY.counter("solver_hvps").inc(hvps, **labels)
 
@@ -328,6 +342,7 @@ class LaneCompactionState:
     coefs: Array  # [E, D] device
     iterations: Array  # [E] int32 device (accumulated across chunks)
     evaluations: Array  # [E] int32 device (accumulated like iterations)
+    line_trials: Array  # [E] int32 device (accumulated like iterations)
     values: Array  # [E] device (last chunk's final value per lane)
     codes: Array  # [E] int8 device (last chunk's convergence code)
     active: np.ndarray  # host int32 global lane ids still unconverged
@@ -339,15 +354,18 @@ class LaneCompactionState:
             coefs=x0,
             iterations=jnp.zeros(e, jnp.int32),
             evaluations=jnp.zeros(e, jnp.int32),
+            line_trials=jnp.zeros(e, jnp.int32),
             values=jnp.zeros(e, value_dtype),
             codes=jnp.zeros(e, jnp.int8),
             active=np.arange(e, dtype=np.int32),
         )
 
     def absorb(self, idx, c: Array, it: Array, ev: Array, v: Array,
-               k: Array, max_iterations_code: int) -> tuple[np.ndarray, np.ndarray]:
+               k: Array, max_iterations_code: int,
+               tr: Array) -> tuple[np.ndarray, np.ndarray]:
         """Fold one chunk's output (lane-compacted when ``idx`` is not
-        None) into the global buffers; returns ``(global_ids,
+        None; ``tr`` its lanes' line trials) into the global buffers;
+        returns ``(global_ids,
         local_positions)`` of lanes the chunk did NOT converge (they hit
         the chunk's iteration budget) — the local positions index this
         chunk's dispatch lanes, which is what the carry-based restart
@@ -360,7 +378,7 @@ class LaneCompactionState:
 
         if idx is None:  # first chunk: all lanes ran, in global order
             self.coefs, self.values, self.codes = c, v, k
-            self.iterations, self.evaluations = it, ev
+            self.iterations, self.evaluations, self.line_trials = it, ev, tr
             unconverged = np.asarray(
                 jax.device_get(k == max_iterations_code))
             record_host_fetch(site="re.compact_mask")
@@ -371,6 +389,7 @@ class LaneCompactionState:
         self.coefs = self.coefs.at[idx_dev].set(c[:n_real])
         self.iterations = self.iterations.at[idx_dev].add(it[:n_real])
         self.evaluations = self.evaluations.at[idx_dev].add(ev[:n_real])
+        self.line_trials = self.line_trials.at[idx_dev].add(tr[:n_real])
         self.values = self.values.at[idx_dev].set(v[:n_real])
         self.codes = self.codes.at[idx_dev].set(k[:n_real])
         unconverged = np.asarray(
@@ -381,7 +400,7 @@ class LaneCompactionState:
 
     def absorb_padded(self, idx: np.ndarray, mask: np.ndarray, c: Array,
                       it: Array, ev: Array, v: Array, k: Array,
-                      max_iterations_code: int
+                      max_iterations_code: int, tr: Array
                       ) -> tuple[np.ndarray, np.ndarray]:
         """Mesh-sharded-chunk variant of :meth:`absorb`: the dispatch lanes
         arrive in per-shard padded layout (flat ``[K * L]``), where a pad
@@ -405,6 +424,8 @@ class LaneCompactionState:
             jnp.where(mask_dev, it, 0))
         self.evaluations = self.evaluations.at[idx_dev].add(
             jnp.where(mask_dev, ev, 0))
+        self.line_trials = self.line_trials.at[idx_dev].add(
+            jnp.where(mask_dev, tr, 0))
         self.values = self.values.at[idx_dev].set(v)
         self.codes = self.codes.at[idx_dev].set(k)
         unconverged = np.asarray(
@@ -414,9 +435,9 @@ class LaneCompactionState:
         local = np.nonzero(real)[0].astype(np.int32)
         return idx[real], local
 
-    def results(self) -> tuple[Array, Array, Array, Array, Array]:
+    def results(self) -> tuple[Array, Array, Array, Array, Array, Array]:
         return (self.coefs, self.iterations, self.values, self.codes,
-                self.evaluations)
+                self.evaluations, self.line_trials)
 
 
 def padded_lane_count(n: int, floor: int = 8) -> int:

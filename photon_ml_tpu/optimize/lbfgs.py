@@ -25,6 +25,20 @@ The history has two layouts, and the call site names the one it needs
   needs neither. Per lane the operations and their order are the circular
   form's: newest to oldest, invalid slots skipped by the same ``where``.
 
+The line search has two forms, and the same call site names the one it
+needs (``line_fn``):
+
+- a trial is a full evaluation (the default, every unbatched solve): the
+  accepted trial's value and gradient are the next iterate's, so a search
+  that accepts its first trial costs one pass over the rows. Those solves
+  make 1.2-1.9 evaluations an iteration.
+- trials on carried margins (the per-entity solves): one pass forms the
+  margins at the iterate and the direction's margins (``line_fn``, scope
+  ``objective.line``), every trial is elementwise work on them, and the
+  accepted point is evaluated in full once. Under ``vmap`` every lane pays
+  for the slowest lane's trials, about 13 an iteration where a lane needs
+  5.9, and a full trial reads an ``[E, N, D]`` block twice.
+
 Convergence checks mirror Optimizer.scala:156-170 (see optimize/common.py).
 """
 
@@ -72,6 +86,8 @@ class _LBFGSCarry(NamedTuple):
     grad_norms: Array
     evaluations: Array  # [max_iter+1] int32 (RunHistory.evaluations)
     iterates: Optional[Array]  # [max_iter+1, d] when tracking, else None
+    # [max_iter+1] int32 (RunHistory.line_trials); None: full trials
+    line_trials: Optional[Array]
 
 
 class LBFGSResume(NamedTuple):
@@ -293,7 +309,7 @@ def start_evaluation(value_and_grad_fn, x0: Array, data):
     return f, g
 
 
-@partial(jax.jit, static_argnums=(0, 3, 4, 5, 7, 9, 10, 11, 12))
+@partial(jax.jit, static_argnums=(0, 3, 4, 5, 7, 9, 10, 11, 12, 13))
 def _minimize_lbfgs_impl(
     value_and_grad_fn,
     x0: Array,
@@ -308,6 +324,7 @@ def _minimize_lbfgs_impl(
     update_axis_name: Optional[str] = None,
     collective_quant: str = "none",
     newest_first: bool = False,
+    line_fn=None,
 ):
     # ``data`` is a traced pytree (the batch): one compiled kernel per
     # function object serves every batch of the same shape — critical for the
@@ -327,10 +344,15 @@ def _minimize_lbfgs_impl(
     # unsupported in sharded-update mode (callers fall back).
     # ``newest_first``: the history layout of a solve under ``vmap`` (module
     # docstring); its RunHistory is written by select for the same reason.
+    # ``line_fn(x, d, data)``: the line search's trials on carried margins
+    # (module docstring); it returns ``phi(a) -> (f, grad . d)`` at
+    # ``x + a d``.
     if update_axis_name is not None and (box is not None or track_iterates):
         raise ValueError(
             "sharded weight update supports neither box constraints nor "
             "track_iterates")
+    if line_fn is not None and box is not None:
+        raise ValueError("trials on carried margins take no box constraints")
     vdot = axis_dot(update_axis_name, collective_quant)
     vnorm = axis_norm(update_axis_name, collective_quant)
     d = x0.shape[0]
@@ -368,6 +390,8 @@ def _minimize_lbfgs_impl(
         head=head0, made_progress=jnp.bool_(True),
         values=values, grad_norms=grad_norms, evaluations=evaluations,
         iterates=iterates0,
+        line_trials=(None if line_fn is None
+                     else jnp.zeros(max_iter + 1, jnp.int32)),
     )
 
     def cond(c: _LBFGSCarry) -> Array:
@@ -390,10 +414,21 @@ def _minimize_lbfgs_impl(
             direction = jnp.where(bad, -c.g, direction)
             dphi0 = jnp.where(bad, -vdot(c.g, c.g), dphi0)
 
-        def phi(a):
-            x_a = c.x + a * direction
-            f_a, g_a = value_and_grad_fn(x_a, data)
-            return f_a, vdot(g_a, direction), g_a
+        if line_fn is None:
+            g_search = c.g
+
+            def phi(a):
+                x_a = c.x + a * direction
+                f_a, g_a = value_and_grad_fn(x_a, data)
+                return f_a, vdot(g_a, direction), g_a
+        else:
+            # the search carries no gradient: the accepted point's is made
+            # below, once
+            g_search = jnp.zeros((), dtype)
+            phi_line = line_fn(c.x, direction, data)
+
+            def phi(a):
+                return (*phi_line(a), g_search)
 
         # Breeze convention: first iteration starts at 1/||d||, then 1.0.
         # A chunk-resumed solve is never at its true first iteration —
@@ -407,11 +442,19 @@ def _minimize_lbfgs_impl(
         else:
             init_alpha = jnp.asarray(1.0, dtype)
         with jax.named_scope("lbfgs.linesearch"):
-            ls = strong_wolfe(phi, c.f, dphi0, c.g, init_alpha=init_alpha)
+            ls = strong_wolfe(phi, c.f, dphi0, g_search,
+                              init_alpha=init_alpha)
 
         x_new = c.x + ls.alpha * direction
-        f_new, g_new = ls.value, ls.grad
-        evals = ls.num_evals
+        if line_fn is None:
+            f_new, g_new = ls.value, ls.grad
+            evals = ls.num_evals
+        else:
+            # made whether or not the search found a step (under ``vmap``
+            # a conditional would run it for every lane anyway); kept only
+            # where it did, by ``ok`` below
+            f_new, g_new = value_and_grad_fn(x_new, data)
+            evals = jnp.int32(1)
         if box is not None:
             x_proj = project_box(x_new, box)
             changed = jnp.any(x_proj != x_new)
@@ -443,6 +486,8 @@ def _minimize_lbfgs_impl(
                                 newest_first)
             # always by select: this array is here to be cheap
             evaluations = record(c.evaluations, it_new, evals, True)
+            line_trials = (None if line_fn is None else record(
+                c.line_trials, it_new, ls.num_evals, True))
             x_acc = jnp.where(ok, x_new, c.x)
             iterates = (c.iterates.at[it_new].set(x_acc)
                         if track_iterates else None)
@@ -456,13 +501,14 @@ def _minimize_lbfgs_impl(
             S=S, Y=Y, rho=rho, valid=valid, head=head,
             made_progress=ok,
             values=values, grad_norms=grad_norms, evaluations=evaluations,
-            iterates=iterates,
+            iterates=iterates, line_trials=line_trials,
         )
 
     final = lax.while_loop(cond, body, init)
     history = RunHistory(values=final.values, grad_norms=final.grad_norms,
                          num_iterations=final.it, iterates=final.iterates,
-                         evaluations=final.evaluations)
+                         evaluations=final.evaluations,
+                         line_trials=final.line_trials)
     if return_carry:
         carry = LBFGSResume(
             x=final.x, f=final.f, g=final.g, prev_f=final.prev_f,
@@ -486,6 +532,7 @@ def minimize_lbfgs(
     update_axis_name: Optional[str] = None,
     collective_quant: str = "none",
     newest_first: bool = False,
+    line_fn=None,
 ):
     """Minimize ``f(x, data)`` from ``x0``; returns (x, RunHistory, made_progress).
 
@@ -506,6 +553,13 @@ def minimize_lbfgs(
     (the per-entity solves): the curvature history, and the carry, in the
     layout that needs no per-lane index (module docstring). Every other
     caller leaves it off and keeps the in-place circular history.
+
+    ``line_fn(x, d, data)``, a function as static as ``value_and_grad_fn``
+    (``GLMObjective.line`` behind the same payload), is for that caller
+    too: the line search's trials on margins carried from one pass an
+    iteration, the accepted point evaluated in full (module docstring).
+    ``RunHistory.evaluations`` then counts the full evaluations (the start
+    and one an iteration) and ``RunHistory.line_trials`` the trials.
     """
     from photon_ml_tpu.obs import compile as obs_compile
 
@@ -513,9 +567,9 @@ def minimize_lbfgs(
         "optimizer.lbfgs", _minimize_lbfgs_impl,
         (value_and_grad_fn, x0, data, max_iter, m, tolerance, box,
          track_iterates, resume, return_carry, update_axis_name,
-         collective_quant, newest_first),
-        static_argnums=(0, 3, 4, 5, 7, 9, 10, 11, 12),
+         collective_quant, newest_first, line_fn),
+        static_argnums=(0, 3, 4, 5, 7, 9, 10, 11, 12, 13),
         arg_names=("value_and_grad_fn", "x0", "data", "max_iter", "m",
                    "tolerance", "box", "track_iterates", "resume",
                    "return_carry", "update_axis_name", "collective_quant",
-                   "newest_first"))
+                   "newest_first", "line_fn"))

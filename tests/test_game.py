@@ -1142,7 +1142,7 @@ def lanes_that_skip_the_store_before(chunk, blocks, obj, l1, solver,
     from photon_ml_tpu.game import random_effect as re_mod
 
     def stopped_at(budget):
-        *_, iters, _, codes, _, _, carry = re_mod._fit_blocks(
+        *_, iters, _, codes, _, _, _, carry = re_mod._fit_blocks(
             *blocks, obj, l1, solver, budget, tolerance,
             boundary_convergence=True, return_carry=True)
         return np.asarray(iters), np.asarray(codes), carry
@@ -1200,9 +1200,9 @@ class TestLaneEvaluationCounts:
         prob = self._problem(*self.SOLVERS[solver])
         obj = prob.objective()
         l1 = jnp.full(X.shape[2], 0.25 if solver == "owlqn" else 0.0)
-        _, iters, _, _, evals, rounds = re_mod._fit_blocks(
+        _, iters, _, _, evals, trials, rounds = re_mod._fit_blocks(
             X, y, off, wts, x0, obj, l1, solver, 12, 1e-9)
-        solo = []
+        solo, solo_trials = [], []
         for e in range(X.shape[0]):
             payload = (obj, DenseBatch(X=X[e], labels=y[e], offsets=off[e],
                                        weights=wts[e]))
@@ -1212,12 +1212,24 @@ class TestLaneEvaluationCounts:
             elif solver == "tron":
                 out = minimize_tron(re_mod._vg, re_mod._hvp, x0[e], payload,
                                     **kw)
-            else:
-                out = minimize_lbfgs(re_mod._vg, x0[e], payload, **kw)
+            else:  # the form the per-entity solves ask for
+                out = minimize_lbfgs(re_mod._vg, x0[e], payload,
+                                     newest_first=True, line_fn=re_mod._line,
+                                     **kw)
             assert int(out[1].num_iterations) == int(iters[e])
             solo.append(int(np.asarray(out[1].evaluations).sum()))
+            solo_trials.append(0 if out[1].line_trials is None
+                               else int(np.asarray(out[1].line_trials).sum()))
         assert list(np.asarray(evals)) == solo
+        assert list(np.asarray(trials)) == solo_trials
         assert len(set(solo)) > 1  # the lanes do differ
+        if solver == "lbfgs":
+            # a full evaluation at the start and at each accepted point
+            assert solo == [1 + int(k) for k in np.asarray(iters)]
+            assert all(t >= int(k) for t, k in zip(solo_trials,
+                                                   np.asarray(iters)))
+        else:
+            assert not any(solo_trials)
         # the batched loop ran at least what its slowest lane needed, and
         # no lane can need more in a round than the round's largest
         assert rounds.shape == (1,)
@@ -1231,30 +1243,43 @@ class TestLaneEvaluationCounts:
         that execution carries. With a budget that ends every lane's solve
         (none finishes early and rides along), rounds x lanes booked is
         exactly what ran: no evaluation under a batched conditional is
-        paid once per branch."""
+        paid once per branch. L-BFGS's one pass an iteration over the rows
+        for its line (``_line``) is counted apart and is in no round."""
         import jax
 
         from photon_ml_tpu.game import random_effect as re_mod
 
-        ran = {"vg": 0}
+        ran = {"vg": 0, "line": 0}
+
+        def bump(name):
+            jax.debug.callback(lambda: ran.__setitem__(name, ran[name] + 1))
 
         def counted_vg(w, payload):
-            jax.debug.callback(lambda: ran.__setitem__("vg", ran["vg"] + 1))
+            bump("vg")
             obj, batch = payload
             return obj.calculate(w, batch)
 
+        def counted_line(w, d, payload):
+            bump("line")
+            obj, batch = payload
+            return obj.line(w, d, batch)
+
         monkeypatch.setattr(re_mod, "_vg", counted_vg)
+        monkeypatch.setattr(re_mod, "_line", counted_line)
         X, y, off, wts, x0 = self._blocks(rng)
         prob = self._problem(*self.SOLVERS[solver])
         l1 = jnp.full(X.shape[2], 0.25 if solver == "owlqn" else 0.0)
         budget = 3
-        _, iters, _, _, evals, rounds = re_mod._fit_blocks_impl(
+        _, iters, _, _, evals, trials, rounds = re_mod._fit_blocks_impl(
             X, y, off, wts, x0, prob.objective(), l1, solver, budget, 1e-30)
         jax.effects_barrier()
         assert list(np.asarray(iters)) == [budget] * X.shape[0]
-        if solver != "tron":  # which evaluates once an iteration
+        if solver == "owlqn":  # the others evaluate once an iteration
             assert len(set(np.asarray(evals).tolist())) > 1  # unequal lanes
+        if solver == "lbfgs":  # whose trials are what differs
+            assert len(set(np.asarray(trials).tolist())) > 1
         assert int(rounds[0]) == ran["vg"]
+        assert ran["line"] == (budget if solver == "lbfgs" else 0)
 
     @pytest.mark.parametrize("solver", sorted(SOLVERS))
     def test_compacted_chunks_count_what_one_dispatch_counts(self, rng,
@@ -1328,7 +1353,10 @@ class TestLaneEvaluationCounts:
     def test_lane_fill_of_a_two_lane_bucket(self, rng):
         """One lane done at once (no weight: its gradient at the start is
         zero, so it makes the start's evaluation and stops), one slow: the
-        slow lane sets every round, and the fill is what arithmetic says."""
+        slow lane sets every round, and the fill is what arithmetic says.
+        An L-BFGS lane makes its start and one full evaluation an
+        iteration, so the slow lane's rounds are 1 + its iterations; its
+        trials go on ``solver_line_trials``."""
         from photon_ml_tpu.game import random_effect as re_mod
         from photon_ml_tpu.game.coordinate import RandomEffectTracker
         from photon_ml_tpu.obs.metrics import REGISTRY
@@ -1338,21 +1366,23 @@ class TestLaneEvaluationCounts:
         prob = self._problem(OptimizerType.LBFGS)
         out = re_mod._fit_blocks(X, y, off, wts, x0, prob.objective(),
                                  jnp.zeros(X.shape[2]), "lbfgs", 12, 1e-9)
-        coefs, iters, values, codes, evals, rounds = out
+        coefs, iters, values, codes, evals, trials, rounds = out
         slow = int(evals[1])
         assert int(iters[0]) == 0 and int(evals[0]) == 1 and slow > 3
+        assert slow == 1 + int(iters[1]) and int(trials[0]) == 0
+        assert int(trials[1]) >= int(iters[1])
         assert int(rounds[0]) == slow
         tracker = RandomEffectTracker(
             iters, values, codes, evaluations=evals,
             evaluation_rounds=rounds, bucket_lanes=np.asarray([2]),
-            site="t.two_lanes")
+            site="t.two_lanes", line_trials=trials)
 
         def booked(name):
             return REGISTRY.counter(name).value(site="t.two_lanes")
 
         before = {n: booked(n) for n in (
             "solver_iterations", "solver_evaluations",
-            "solver_lane_evaluations")}
+            "solver_lane_evaluations", "solver_line_trials")}
         assert tracker.lane_fill() == pytest.approx((1 + slow) / (2 * slow))
         tracker.materialize().materialize()  # booked once
         assert booked("solver_iterations") \
@@ -1361,6 +1391,48 @@ class TestLaneEvaluationCounts:
             - before["solver_evaluations"] == 1 + slow
         assert booked("solver_lane_evaluations") \
             - before["solver_lane_evaluations"] == 2 * slow
+        assert booked("solver_line_trials") \
+            - before["solver_line_trials"] == int(trials[1])
+
+    def test_trials_on_margins_land_where_full_trials_do(self, rng):
+        """A small float32 bucket of one-hot rows (a user's rated movies),
+        weights up to 8, L2 1, 8 iterations and a tolerance no solve meets,
+        as the sweep cells solve their users: the per-entity form, whose
+        trials are made on carried margins, against the same vmapped solve
+        whose every trial is a full evaluation. The coefficients agree to
+        1e-4 of their norm."""
+        import jax
+
+        from photon_ml_tpu.data.batch import DenseBatch
+        from photon_ml_tpu.game import random_effect as re_mod
+        from photon_ml_tpu.optimize.lbfgs import minimize_lbfgs
+
+        e, n, d = 64, 32, 16
+        f32 = jnp.float32
+        X = np.eye(d)[rng.integers(0, d, size=(e, n))]
+        rows = rng.integers(4, n + 1, size=e)  # the rest are padding
+        live = np.arange(n)[None, :] < rows[:, None]
+        z = np.einsum("end,ed->en", X, rng.normal(size=(e, d)))
+        y = (rng.random((e, n)) < 1 / (1 + np.exp(-z))).astype(float)
+        blocks = (jnp.asarray(X, f32), jnp.asarray(y, f32),
+                  jnp.asarray(rng.normal(size=(e, n)) * 0.3, f32),
+                  jnp.asarray(live * rng.uniform(1.0, 8.0, size=(e, 1)), f32),
+                  jnp.zeros((e, d), f32))
+        obj = self._problem(OptimizerType.LBFGS).objective().with_l2(1.0)
+        on_margins = re_mod._fit_blocks(*blocks, obj, jnp.zeros(d, f32),
+                                        "lbfgs", 8, 1e-30)[0]
+
+        def full_trials(Xe, ye, oe, we, x0):
+            return minimize_lbfgs(
+                re_mod._vg, x0,
+                (obj, DenseBatch(X=Xe, labels=ye, offsets=oe, weights=we)),
+                max_iter=8, tolerance=1e-30, newest_first=True)[0]
+
+        full = jax.vmap(full_trials)(*blocks)
+        assert on_margins.dtype == full.dtype == f32
+        gap = float(jnp.linalg.norm(on_margins - full)
+                    / jnp.linalg.norm(full))
+        assert gap <= 1e-4, gap
 
     def test_bucketed_update_fills_the_tracker(self, rng):
         data, *_ = make_game_data(rng, n=600, n_entities=24)
@@ -1369,10 +1441,24 @@ class TestLaneEvaluationCounts:
             num_buckets=3)
         coord = RandomEffectCoordinate(ds, self._problem(OptimizerType.LBFGS))
         _, tracker = coord.update(None, jnp.zeros(data.num_samples))
+        from photon_ml_tpu.obs.metrics import REGISTRY
+
+        def booked(name):
+            return REGISTRY.counter(name).value(site="re.fit_blocks")
+
+        before = {n: booked(n) for n in (
+            "solver_evaluations", "solver_line_trials")}
         tracker.materialize()
         assert tracker.site == "re.fit_blocks"
         assert tracker.evaluations.shape == tracker.iterations.shape
-        assert (tracker.evaluations >= tracker.iterations + 1).all()
+        # L-BFGS: the start and one full evaluation an iteration
+        np.testing.assert_array_equal(tracker.evaluations,
+                                      tracker.iterations + 1)
+        assert (tracker.line_trials >= tracker.iterations).all()
+        assert booked("solver_evaluations") - before["solver_evaluations"] \
+            == len(tracker.iterations) + int(tracker.iterations.sum())
+        assert booked("solver_line_trials") - before["solver_line_trials"] \
+            == int(tracker.line_trials.sum()) > 0
         assert len(tracker.bucket_lanes) == len(ds.buckets) \
             == len(tracker.evaluation_rounds)
         assert list(tracker.bucket_lanes) == [

@@ -369,8 +369,94 @@ def check_vmapped_solve_is_each_lanes_own(rng, minimize, **solver_kw):
         assert np.isnan(np.asarray(hist.values)[e, k + 1:]).all()
 
 
+def _obj_line(w, d, payload):
+    obj, batch = payload
+    return obj.line(w, d, batch)
+
+
 def test_vmapped_newest_first_solve_is_each_lanes_own_solve(rng):
-    check_vmapped_solve_is_each_lanes_own(rng, minimize_lbfgs)
+    """The per-entity L-BFGS form (newest-first history, trials on carried
+    margins) under ``vmap`` against every lane's own solve in the same
+    form: the same iterations, the same full evaluations and trials in
+    every iteration, the same verdict, the coefficients and values within
+    1e-6 relative. (Not bit for bit: XLA:CPU emits the gradient's
+    ``nd,n->d`` sum one way for a block and another for a batch of them,
+    1 ulp apart in either form of the search.)"""
+    X, y = _lane_blocks(rng)
+    lanes, n, d = X.shape
+    obj = GLMObjective(loss=get_loss("logistic"), l2_lambda=1.0)
+    kw = dict(max_iter=40, tolerance=1e-5, newest_first=True,
+              line_fn=_obj_line)
+
+    def solve(Xe, ye):
+        return minimize_lbfgs(_obj_vg, jnp.zeros(d, jnp.float32),
+                              (obj, dense_batch(Xe, ye, dtype=jnp.float32)),
+                              **kw)
+
+    x, hist, ok = jax.vmap(solve)(X, y)
+    iterations = np.asarray(hist.num_iterations)
+    assert len(set(iterations.tolist())) > 1 and iterations.max() < 40
+    assert iterations.min() > 3  # the history is in use
+    for e in range(lanes):
+        x_e, hist_e, ok_e = solve(X[e], y[e])
+        k = int(hist_e.num_iterations)
+        assert k == iterations[e], e
+        assert bool(ok_e) == bool(ok[e])
+        # the start and one full evaluation an iteration; the trials apart
+        np.testing.assert_array_equal(np.asarray(hist.evaluations)[e],
+                                      [1] * (k + 1) + [0] * (40 - k))
+        np.testing.assert_array_equal(np.asarray(hist.evaluations)[e],
+                                      np.asarray(hist_e.evaluations))
+        np.testing.assert_array_equal(np.asarray(hist.line_trials)[e],
+                                      np.asarray(hist_e.line_trials))
+        assert int(np.sum(hist_e.line_trials)) >= k
+        scale = float(jnp.max(jnp.abs(x_e)))
+        np.testing.assert_allclose(np.asarray(x[e]), np.asarray(x_e),
+                                   rtol=0, atol=1e-6 * scale)
+        np.testing.assert_allclose(np.asarray(hist.values)[e, :k + 1],
+                                   np.asarray(hist_e.values)[:k + 1],
+                                   rtol=1e-6)
+
+
+def _line_problem(loss, rng, n=96, d=5):
+    """A float32 batch with offsets, weights and a normalization that
+    both shifts and scales, for ``loss``, and a point and a direction."""
+    from photon_ml_tpu.ops.normalization import NormalizationContext
+
+    X = rng.normal(size=(n, d)) * rng.uniform(0.5, 3.0, size=d) + 0.7
+    z = X @ rng.normal(size=d) * 0.3
+    y = {"logistic": (rng.random(n) < 1 / (1 + np.exp(-z))).astype(float),
+         "squared": z + rng.normal(size=n),
+         "poisson": rng.poisson(np.exp(z))}[loss]
+    batch = dense_batch(X, y, offsets=rng.normal(size=n) * 0.2,
+                        weights=rng.uniform(0.2, 3.0, size=n),
+                        dtype=jnp.float32)
+    norm = NormalizationContext(
+        factors=jnp.asarray(rng.uniform(0.3, 2.0, size=d), jnp.float32),
+        shifts=jnp.asarray(rng.normal(size=d), jnp.float32))
+    obj = GLMObjective(get_loss(loss), norm=norm, l2_lambda=0.7)
+    x = jnp.asarray(rng.normal(size=d) * 0.3, jnp.float32)
+    direction = jnp.asarray(rng.normal(size=d) * 0.3, jnp.float32)
+    return obj, batch, x, direction
+
+
+@pytest.mark.parametrize("loss", ["logistic", "squared", "poisson"])
+def test_the_line_from_carried_margins_is_the_full_evaluation(rng, loss):
+    """``GLMObjective.line``'s ``phi(a)`` and its slope against the full
+    evaluation at ``x + a d``: its value and ``g . d``, to float32 rounding,
+    through offsets, weights and a normalization's shifts and factors."""
+    obj, batch, x, direction = _line_problem(loss, rng)
+    phi = jax.jit(lambda a: obj.line(x, direction, batch)(a))
+    full = jax.jit(lambda a: obj.calculate(x + a * direction, batch))
+    for a in rng.uniform(-2.0, 2.0, size=6).astype(np.float32):
+        value, slope = phi(a)
+        f, g = full(a)
+        assert value.dtype == slope.dtype == jnp.float32
+        np.testing.assert_allclose(float(value), float(f), rtol=2e-6)
+        # the slope's rounding is that of its terms, not of their sum
+        scale = float(jnp.linalg.norm(g) * jnp.linalg.norm(direction))
+        assert abs(float(slope) - float(jnp.dot(g, direction))) \
+            <= 2e-6 * scale
 
 
 @pytest.mark.parametrize("newest_first", (False, True),

@@ -328,6 +328,77 @@ def test_per_entity_solve_indexes_no_history_by_lane(one_chip, solver):
     assert compiled.memory_analysis().temp_size_in_bytes < e * 10 * d * 4
 
 
+def _block_readers(text, block, scope):
+    """[(instruction, scope path)] of every instruction traced under
+    ``scope`` that takes an operand of shape ``block`` (``f32[E,N,D]``): in
+    any computation, fused ones included, an operand's shape is its
+    defining line's in the same computation."""
+    found = []
+    for _, body in _HLO_COMPUTATION.findall(text):
+        lines = body.splitlines()
+        shapes = dict(re.findall(r"^\s*(?:ROOT )?%(\S+) = (\S+)", body,
+                                 re.MULTILINE))
+        for line in lines:
+            path = re.search(r'op_name="([^"]*)"', line)
+            if path is None or scope not in path.group(1):
+                continue
+            defined, rest = line.split(" = ", 1)
+            operands = re.findall(r"%([\w.\-]+)", rest.split("metadata=")[0])
+            if any(shapes.get(o, "").startswith(block) for o in operands):
+                found.append((defined.strip(), path.group(1)))
+    return found
+
+
+def test_per_entity_line_search_reads_no_block(one_chip):
+    """The per-entity L-BFGS tries its steps on margins it carries
+    (optimize/lbfgs.py, ``line_fn``): one pass an iteration under
+    ``objective.line`` forms them, and no instruction of the line search
+    takes the ``[E, N, D]`` block, where the same solve with full trials,
+    vmapped at the same shape, reads it in every trial (twice: PERF.md,
+    section 6)."""
+    from photon_ml_tpu.game import random_effect
+    from photon_ml_tpu.optimize.lbfgs import _minimize_lbfgs_impl
+
+    e, n, d = GLMIX_BUCKET
+    block = f"f32[{e},{n},{d}]"
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    obj = GLMObjective(loss=get_loss("logistic"), l2_lambda=1.0)
+    blocks = (sds(e, n, d), sds(e, n), sds(e, n), sds(e, n), sds(e, d))
+    text = random_effect._fit_blocks.lower(
+        *blocks, obj, sds(d), solver="lbfgs", max_iter=8,
+        tolerance=1e-30).compile().as_text()
+    assert "lbfgs.linesearch" in text and "objective.line/" in text
+    assert _block_readers(text, block, "lbfgs.linesearch") == []
+    assert _block_readers(text, block, "objective.line/")
+
+    def full_trials(X, y, o, w, x0):
+        return _minimize_lbfgs_impl(
+            random_effect._vg, x0,
+            (obj, DenseBatch(X=X, labels=y, offsets=o, weights=w)),
+            8, 10, 1e-30, newest_first=True)[0]
+
+    control = jax.jit(jax.vmap(full_trials)).lower(*blocks).compile()
+    assert len(_block_readers(control.as_text(), block,
+                              "lbfgs.linesearch")) >= 2
+
+
+@pytest.mark.parametrize("n,d", [(GLMIX_ROWS, GLMIX_FIXED_DIM), GLM_SHAPE])
+def test_unbatched_lbfgs_tries_its_steps_in_full(one_chip, as_on_one_tpu,
+                                                  n, d):
+    """Every unbatched L-BFGS solve (a fixed effect at 65 columns, a dense
+    GLM at 2,048) keeps the trial-on-full-evaluation form: its program
+    holds no ``objective.line``."""
+    problem = _l2_problem(6, 1e-30, 10.0)
+    x0 = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=one_chip)
+    text = jax.jit(problem.solve).lower(
+        problem.objective(), _dense(n, d, one_chip), x0).compile().as_text()
+    assert "lbfgs.linesearch" in text
+    assert "objective.line" not in text
+
+
 @pytest.mark.parametrize("d", [GLMIX_FIXED_DIM, GLM_SHAPE[1]])
 def test_sharded_fixed_effect_step_compiles(mesh, as_on_tpu_mesh, d):
     """``chip_smoke.py --chips 4``'s fixed-effect update: the solver inside
