@@ -17,7 +17,8 @@ Two layouts:
   pointing at a dummy column with value 0.
   A pass walks the slots: margins gather one slot of every row at a time
   into an ``[N]`` accumulator, gradients scatter-add one slot at a time
-  into a ``[D]`` one. Right for wide sparse spaces (reference policy switches
+  into a ``[D]`` one (a tile of slots at a time over a block of few
+  rows). Right for wide sparse spaces (reference policy switches
   representation around 200k features; SURVEY §7 hard-part 5). Slot-major
   because a TPU tiles a 32-bit array (8, 128) over its two minor
   dimensions: a solver loop re-lays row-major ``[N, 39]`` planes with every
@@ -145,6 +146,12 @@ class EllBatch:
     :func:`ell_from_rows`, or from arrays already in this layout with
     :func:`ell_batch`.
 
+    A step of fewer than ``ELL_TILE_ROWS`` elements costs far more than
+    its elements (on a v5e the scatter-add takes another path, 7x the time
+    a slot), so a block over fewer rows walks a tile of slots a step
+    instead (:func:`ell_tile_slots`): ``[T, n]`` of both planes, summed
+    over T into the margins, one scatter-add into the column sums.
+
     **Rows of uneven length.** A padded slot costs a pass what a stored one
     costs, so where the rows differ in length the layout holds them
     **longest first** and in several blocks of slots: ``indices``/``values``
@@ -262,6 +269,13 @@ class EllBatch:
     def margins(self, w_eff: Array, margin_shift: Array) -> Array:
         with jax.named_scope(MARGINS_SCOPE):
             def walk(indices, values, z):
+                tile = _walk_tile(indices)
+                if tile > 1:
+                    return _walk_tiles(
+                        indices, values, tile,
+                        lambda ix, v, z: z + jnp.sum(w_eff[ix] * v, axis=0),
+                        z)
+
                 def add_slot(k, z):
                     return z + w_eff[indices[k]] * values[k]
 
@@ -285,6 +299,13 @@ class EllBatch:
                 row_scalars = row_scalars[self.order]
 
             def walk(indices, values, r, sums):
+                tile = _walk_tile(indices)
+                if tile > 1:
+                    return _walk_tiles(
+                        indices, values, tile,
+                        lambda ix, v, sums: sums.at[ix].add(
+                            (v * v if square else v) * r[None, :]), sums)
+
                 def add_slot(k, sums):
                     v = values[k]
                     return sums.at[indices[k]].add(
@@ -610,6 +631,63 @@ def canonicalized_csr(mat):
 _SUBLANES = 8
 _MAX_BLOCKS = 8
 
+# Below ``ELL_TILE_ROWS`` elements a step a walk pays for the step, not for
+# its elements: a block over fewer rows walks a tile of slots a step
+# (:func:`ell_tile_slots`). Read on a v5e into a 16.6M-column table (PERF.md,
+# the long-row cell). Jitted alone, one slot a step of n rows: the scatter-add 72-75 ns a
+# slot up to 8,192 rows, 8.3-9.1 from 16,384 on. In the L-BFGS solve, tiles
+# from 16,384: the scatter-add 10.4 / 11.0 / 12.4 / 15.2 ns a slot in blocks
+# of 87,500 / 63,955 / 43,131 / 26,443 rows, 16.4 and 19.1 in tiles of
+# 24,008 and 18,720 elements (about 190 us a step beside 8.4 ns an element);
+# tiles from 32,768: 8.3-9.4 ns in every block. The gather 6.6-7.1 in both.
+ELL_TILE_ROWS = 32768
+
+
+def ell_tile_slots(slots: int, rows: int) -> int:
+    """Slots one step of a walk takes over a block of ``slots`` x ``rows``:
+    one where the rows are ``ELL_TILE_ROWS`` or more, else the fewest whole
+    groups of ``_SUBLANES`` slots that give a step ``ELL_TILE_ROWS``
+    elements or more, and never more than the block's depth."""
+    if rows >= ELL_TILE_ROWS:
+        return 1
+    tile = _SUBLANES * -(-ELL_TILE_ROWS // (_SUBLANES * max(rows, 1)))
+    return min(tile, max(slots, 1))
+
+
+def ell_walk_steps(slots: int, rows: int) -> int:
+    """Loop steps a walk makes over a block of ``slots`` x ``rows``, the
+    last step shorter where the tile does not divide the depth."""
+    return -(-slots // ell_tile_slots(slots, rows))
+
+
+def _walk_tile(indices) -> int:
+    """The tile a walk over the ``[K, n]`` plane ``indices`` takes, booked
+    on ``ell_walk_lowerings{form}`` (``slot`` or ``tile``) at trace time,
+    when the form is decided."""
+    from photon_ml_tpu.obs.metrics import REGISTRY
+
+    tile = ell_tile_slots(*indices.shape)
+    REGISTRY.counter("ell_walk_lowerings").inc(
+        form="tile" if tile > 1 else "slot")
+    return tile
+
+
+def _walk_tiles(indices, values, tile: int, add, acc):
+    """``acc = add(indices, values, acc)`` over the ``[tile, n]`` slices
+    of a block's ``[K, n]`` planes, one ``fori_loop`` step a slice, and
+    the last ``K mod tile`` slots in one shorter step after the loop."""
+    slots = indices.shape[0]
+    whole = slots // tile
+
+    def add_tile(k, acc):
+        return add(lax.dynamic_slice_in_dim(indices, k * tile, tile),
+                   lax.dynamic_slice_in_dim(values, k * tile, tile), acc)
+
+    acc = lax.fori_loop(0, whole, add_tile, acc)
+    if slots % tile:
+        acc = add(indices[whole * tile:], values[whole * tile:], acc)
+    return acc
+
 
 def ell_block_bounds(lengths: np.ndarray, multiple: int = _SUBLANES) -> list:
     """Where the blocks of slots of an ELL layout end, from the rows'
@@ -677,7 +755,10 @@ def _ell_from_csr_arrays(indptr, cols, data, dim: int, labels, offsets,
     bounds from the rows' lengths (:func:`ell_block_bounds`), the rows
     longest first where there is more than one block, every block packed
     on the host and placed slot-major. Books what a pass will walk against
-    what the matrix stores (``ell_walked_slots`` / ``ell_stored_slots``)."""
+    what the matrix stores (``ell_walked_slots`` / ``ell_stored_slots``)
+    and the loop steps one of its walks makes on one device
+    (``ell_walk_steps``: dealt over a mesh, a shard's walk takes its tile
+    from its own share of the rows)."""
     from photon_ml_tpu.obs import trace
     from photon_ml_tpu.obs.metrics import REGISTRY
 
@@ -689,16 +770,22 @@ def _ell_from_csr_arrays(indptr, cols, data, dim: int, labels, offsets,
     meta = jnp.promote_types(dtype, jnp.float32)
     # Host staging in the narrowest exact container (f64 only when asked).
     stage = np.float64 if meta == jnp.float64 else np.float32
-    with trace.span("ell.build", rows=n, nonzeros=nnz, blocks=len(bounds)):
+    # the rows each block covers: those that reach its first slot
+    covered = [n if lo == 0 else int(np.count_nonzero(lens > lo))
+               for lo in [0] + bounds[:-1]]
+    steps = sum(ell_walk_steps(hi - lo, rows) for lo, hi, rows in zip(
+        [0] + bounds[:-1], bounds, covered))
+    with trace.span("ell.build", rows=n, nonzeros=nnz, blocks=len(bounds),
+                    steps=steps):
         order = None
         if len(bounds) > 1:
             order = np.argsort(-lens, kind="stable").astype(np.int32)
         planes, lo = [], 0
-        for hi in bounds:
+        for hi, count in zip(bounds, covered):
             if order is None:  # one block over the rows as they come
                 rows = None
             else:  # the rows that reach slot ``lo``, longest first
-                rows = order[:n if lo == 0 else np.count_nonzero(lens > lo)]
+                rows = order[:count]
             indices = values = None
             if rows is None and nnz and stage == np.float32:
                 from photon_ml_tpu.io.native_loader import pack_ell_native
@@ -724,6 +811,7 @@ def _ell_from_csr_arrays(indptr, cols, data, dim: int, labels, offsets,
     REGISTRY.counter("ell_stored_slots").inc(nnz, blocks=len(bounds))
     REGISTRY.counter("ell_walked_slots").inc(batch.walked_slots,
                                              blocks=len(bounds))
+    REGISTRY.counter("ell_walk_steps").inc(steps, blocks=len(bounds))
     return batch
 
 

@@ -19,12 +19,15 @@ from photon_ml_tpu.data.batch import (
     ell_batch,
     deal_rows,
     ell_block_bounds,
+    ell_tile_slots,
+    ell_walk_steps,
     ell_from_csr,
     ell_from_rows,
     pad_batch,
     row_partition_specs,
     rows_in_layout_order,
 )
+from photon_ml_tpu.data import batch as batch_module
 from photon_ml_tpu.ops import losses
 from photon_ml_tpu.ops.aggregators import GLMObjective
 from photon_ml_tpu.optimize.config import OptimizerType, TaskType
@@ -242,13 +245,19 @@ def test_the_row_sharded_ell_fit_equals_one_device(rng, shard_update):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-9)
 
 
+@pytest.mark.parametrize("form", ["slot", "tile"])
 @pytest.mark.parametrize("slots", [1, 5, 39])
-def test_the_slot_walk_equals_a_float64_dense_pass(rng, slots):
+def test_the_slot_walk_equals_a_float64_dense_pass(rng, slots, form,
+                                                   monkeypatch):
     """``margins``, ``weighted_feature_sum`` and ``hadamard_square_sum``
-    carry one accumulator through the K slots; each against the dense
+    carry one accumulator through the K slots, one a step or a tile of
+    them a step (257 rows are too few for a slot a step to pay; with no
+    rows too few every walk takes one slot a step); each against the dense
     float64 sum over the same rows, with padded slots and one column that
     most rows hold. Under ``vmap`` over a stack of coefficient vectors the
-    walk is still one gather a slot."""
+    walk is still one gather a step."""
+    if form == "slot":
+        monkeypatch.setattr(batch_module, "ELL_TILE_ROWS", 0)
     n, d, stack = 257, 64, 3
     X = np.zeros((n, d))
     for i in range(n):
@@ -760,3 +769,148 @@ def test_a_default_mesh_routes_the_ragged_fit_over_the_shards(ragged):
     np.testing.assert_allclose(np.asarray(routed.coefficients.means),
                                np.asarray(local.coefficients.means),
                                rtol=1e-6, atol=1e-8)
+
+
+# --- long rows: blocks thousands of slots deep over few rows ---------------
+
+
+def _long_rows(seed=7, n=512, d=6000, mean=300.0, cap=4096):
+    """Rows of log-normal length (mean about ``mean``, cut at ``cap``) over
+    ``d`` columns, ascending and distinct, positive values of unit-length
+    rows, labels of both signs: the layout's deeper blocks hold a few rows
+    each."""
+    rng = np.random.default_rng(seed)
+    lens = np.clip(np.round(np.exp(np.log(mean) - 0.32
+                                   + 0.8 * rng.standard_normal(n))),
+                   1, cap).astype(int)
+    cols = np.concatenate([np.sort(rng.choice(d, size=l, replace=False))
+                           for l in lens]).astype(np.int32)
+    indptr = np.concatenate([[0], np.cumsum(lens)])
+    vals = rng.random(indptr[-1]).astype(np.float32) + 0.5
+    norms = np.sqrt(np.add.reduceat(vals * vals, indptr[:-1]))
+    vals /= np.repeat(norms, lens).astype(np.float32)
+    mat = sp.csr_matrix((vals, cols, indptr), shape=(n, d))
+    return mat, (rng.random(n) < 0.6).astype(np.float32)
+
+
+@pytest.fixture(scope="module")
+def long_rows():
+    mat, y = _long_rows()
+    return mat, y, ell_from_csr(mat, y)
+
+
+def test_the_tile_is_read_from_a_blocks_rows():
+    rows = batch_module.ELL_TILE_ROWS
+    assert ell_tile_slots(64, rows) == ell_tile_slots(64, 10 * rows) == 1
+    for n in (1, 7, 100, rows // 3, rows - 1):
+        tile = ell_tile_slots(10**6, n)
+        assert tile % 8 == 0 and tile * n >= rows > (tile - 8) * n
+        assert ell_tile_slots(5, n) == 5  # no deeper than the block
+    # a step over the block's depth at most, the last step shorter
+    assert ell_walk_steps(40, rows) == 40
+    assert ell_walk_steps(40, 1) == 1 and ell_walk_steps(0, 1) == 0
+    tile = ell_tile_slots(10**6, rows // 2)
+    assert ell_walk_steps(2 * tile + 8, rows // 2) == 3
+
+
+def _walk_steps_in(jaxpr):
+    """Loop steps a traced walk makes: every ``scan``'s length, and every
+    ``gather`` outside one (a shorter last step)."""
+    steps = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            steps += eqn.params["length"]
+            continue
+        if eqn.primitive.name == "gather":
+            steps += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            steps += _walk_steps_in(sub)
+    return steps
+
+
+def test_long_rows_walk_tiles_and_the_counters_book_them(long_rows):
+    from photon_ml_tpu.obs.metrics import REGISTRY
+
+    mat, y, _ = long_rows
+    steps = REGISTRY.counter("ell_walk_steps")
+    lowerings = REGISTRY.counter("ell_walk_lowerings")
+    before = sum(steps.items().values())
+    ell = ell_from_csr(mat, y)
+    booked = sum(steps.items().values()) - before
+    shapes = [ix.shape[-2:] for ix, _ in ell.blocks]
+    assert len(shapes) >= 6 and shapes[-1][1] < 50  # deep blocks, few rows
+    assert booked == sum(ell_walk_steps(k, n) for k, n in shapes)
+    assert booked < sum(k for k, _ in shapes) / 8  # tiles, not slots
+    moved = rows_in_layout_order(ell)
+    forms = {f: lowerings.value(form=f) for f in ("slot", "tile")}
+    jaxpr = jax.make_jaxpr(lambda w: moved.margins(w, 0.0))(
+        jnp.zeros(mat.shape[1], jnp.float32)).jaxpr
+    assert _walk_steps_in(jaxpr) == booked  # what one walk makes
+    assert lowerings.value(form="tile") - forms["tile"] == len(shapes)
+    assert lowerings.value(form="slot") == forms["slot"]
+
+
+@pytest.mark.parametrize("loss", [losses.smoothed_hinge_loss,
+                                  losses.logistic_loss], ids=lambda l: l.name)
+def test_the_tiled_walk_equals_a_dense_float32_pass(long_rows, loss):
+    mat, y, ell = long_rows
+    n, d = mat.shape
+    rng = np.random.default_rng(3)
+    X = mat.toarray().astype(np.float64)
+    w = rng.normal(size=d).astype(np.float32) * 0.1
+    r = rng.normal(size=n).astype(np.float32)
+    margins, sums, squares = jax.jit(lambda b, w, r: (
+        b.margins(w, 0.0), b.weighted_feature_sum(r),
+        b.hadamard_square_sum(r)))(ell, jnp.asarray(w), jnp.asarray(r))
+    np.testing.assert_allclose(np.asarray(margins), X @ w, rtol=0,
+                               atol=1e-5 * np.abs(X @ np.abs(w)).max())
+    np.testing.assert_allclose(np.asarray(sums), X.T @ r, rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(squares), (X * X).T @ r,
+                               rtol=1e-5, atol=1e-5)
+    obj = jax.jit(GLMObjective(loss, l2_lambda=1.0).calculate)
+    dense = dense_batch(X.astype(np.float32), y)
+    for a, b in zip(obj(jnp.asarray(w), ell), obj(jnp.asarray(w), dense)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-5, atol=2e-5)
+
+
+def test_a_smoothed_hinge_fit_on_long_rows_follows_the_reference(long_rows):
+    """Four L-BFGS iterations of the smoothed-hinge SVM through
+    ``train_glm_grid`` on the tiled layout: the values it reports never
+    rise and are the plain reference's at its coefficients, it descends as
+    far as the reference's textbook L-BFGS in as many iterations, and the
+    dense batch of the same matrix takes the same path."""
+    from benchmark.reference import glm_ragged, glm_svm
+
+    mat, y, ell = long_rows
+    n, d = mat.shape
+
+    def fit(batch):
+        model, = train_glm_grid(
+            batch, TaskType.SMOOTHED_HINGE_LOSS_LINEAR_SVM,
+            regularization_weights=[1.0], max_iterations=4,
+            tolerance=1e-30)
+        return model.result
+
+    got = fit(ell)
+    assert int(got.iterations) == 4
+    history = np.asarray(got.values, np.float64)
+    assert np.all(np.diff(history) <= 0) and history[-1] < history[0]
+    data = tuple(jnp.asarray(a) for a in (
+        *glm_ragged.flat_blocks(mat.indptr, mat.indices, mat.data, 128), y,
+        np.zeros(n, np.float32), np.ones(n, np.float32)))
+
+    def fn(w):
+        return glm_svm.objective(*data, w, 1.0)
+
+    value, grad = fn(np.asarray(got.coefficients, np.float64))
+    assert float(got.value) == pytest.approx(value, rel=1e-5)
+    assert float(got.grad_norm) == pytest.approx(np.linalg.norm(grad),
+                                                 rel=1e-4)
+    _, textbook, _ = sparse_reference.lbfgs(fn, np.zeros(d), 4)
+    assert value <= textbook[-1] + 1e-3 * (textbook[0] - textbook[-1])
+    np.testing.assert_allclose(history[0], textbook[0], rtol=1e-6)
+    on_dense = fit(dense_batch(mat.toarray(), y))
+    np.testing.assert_allclose(history, np.asarray(on_dense.values),
+                               rtol=1e-5)

@@ -748,6 +748,112 @@ def test_the_ragged_fit_compiles_row_sharded_over_a_v5e_host(topo):
             < 0.5 * V5E_HBM_BYTES)
 
 
+# the long-row cell's shape (benchmark/configs/glm-longrow-webspam.json): 87,500
+# rows over 16,609,143 columns, the blocks of slots the layout's rule gives the
+# generator's row lengths, [slots, rows] each
+WEBSPAM_ROWS, WEBSPAM_DIM = 87_500, 16_609_143
+WEBSPAM_BLOCKS = ((1656, 87_500), (1080, 63_955), (1328, 43_131),
+                  (1688, 26_443), (2384, 15_072), (3480, 7_327),
+                  (6176, 3_001), (14976, 780))
+WEBSPAM_SLOTS = sum(k * n for k, n in WEBSPAM_BLOCKS)
+
+
+def _layout(blocks, dim, one_chip):
+    """An ``EllBatch`` of one run of rows in ``blocks``, as the builder
+    lays it out: block 0 ``[K, N]``, a further block ``[1, K, n_g]``."""
+    from photon_ml_tpu.data.batch import EllBatch
+
+    def planes(shape):
+        return (jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip),
+                jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip))
+
+    (k0, n0), rest = blocks[0], blocks[1:]
+    rows = [jax.ShapeDtypeStruct((n0,), jnp.float32, sharding=one_chip)
+            for _ in range(3)]
+    return EllBatch(*planes((k0, n0)), *rows,
+                    tuple(planes((1, k, n)) for k, n in rest), None, dim=dim)
+
+
+def _lowered_forms(monkeypatch, lower):
+    """``lower()``'s text as the program lowers it and with every walk
+    forced to one slot a step, and the forms each booked on
+    ``ell_walk_lowerings``."""
+    from photon_ml_tpu.data import batch as batch_module
+    from photon_ml_tpu.obs.metrics import REGISTRY
+
+    counter = REGISTRY.counter("ell_walk_lowerings")
+    out = []
+    for rows in (batch_module.ELL_TILE_ROWS, 0):
+        with monkeypatch.context() as patch:
+            patch.setattr(batch_module, "ELL_TILE_ROWS", rows)
+            jax.clear_caches()  # the walk's form is decided at trace time
+            before = {f: counter.value(form=f) for f in ("slot", "tile")}
+            text = lower().as_text()
+            out.append((text, {f: counter.value(form=f) - before[f]
+                               for f in ("slot", "tile")}))
+    jax.clear_caches()
+    return out
+
+
+@pytest.mark.parametrize("solve", ["criteo", "kddb"])
+def test_the_sparse_cells_walk_one_slot_a_step_as_they_did(
+        one_chip, monkeypatch, solve):
+    """Every block of the Criteo-shaped and KDD Cup 2010-shaped layouts
+    holds ``ELL_TILE_ROWS`` rows or more (the smallest, kddb's last, 33,388),
+    so their walks keep one slot a step: the lowered solve is the one the
+    walk forced to slots lowers to, text for text, and books ``slot``
+    alone (checked once against the program before the tiled walk, with
+    traceback locations off: PERF.md)."""
+    from photon_ml_tpu.data import batch as batch_module
+
+    if solve == "criteo":
+        problem, batch = _l2_problem(6, 1e-30, 10.0), _ell(
+            CRITEO_ROWS, CRITEO_SLOTS, CRITEO_DIM, one_chip)
+        dim = CRITEO_DIM
+    else:
+        problem, batch = _elastic_net_problem(), _kddb(one_chip, one_chip,
+                                                       one_chip)
+        dim = KDDB_DIM
+    assert min(n for _, n in KDDB_BLOCKS) >= batch_module.ELL_TILE_ROWS
+    x0 = jax.ShapeDtypeStruct((dim,), jnp.float32, sharding=one_chip)
+    (text, forms), (slots_text, _) = _lowered_forms(
+        monkeypatch,
+        lambda: jax.jit(problem.solve).lower(problem.objective(), batch, x0))
+    assert text == slots_text
+    assert forms["tile"] == 0 and forms["slot"] > 0
+
+
+def test_the_long_row_svm_solve_walks_its_deep_blocks_in_tiles(
+        one_chip, monkeypatch):
+    """The long-row cell's program, compiled: the smoothed-hinge L-BFGS over
+    the layout's eight blocks, the five deepest over fewer than
+    ``ELL_TILE_ROWS`` rows walked in tiles (``ell_walk_lowerings{form=
+    tile}``), the rest a slot a step; the planes, the rows' vectors and
+    the ``[10, D]`` history fit well inside one chip."""
+    from photon_ml_tpu.data import batch as batch_module
+
+    problem = _l2_problem(4, 1e-30, 1.0,
+                          task=TaskType.SMOOTHED_HINGE_LOSS_LINEAR_SVM)
+    batch = _layout(WEBSPAM_BLOCKS, WEBSPAM_DIM, one_chip)
+    x0 = jax.ShapeDtypeStruct((WEBSPAM_DIM,), jnp.float32, sharding=one_chip)
+    (text, forms), (slots_text, slot_forms) = _lowered_forms(
+        monkeypatch,
+        lambda: jax.jit(problem.solve).lower(problem.objective(), batch, x0))
+    tiled = sum(n < batch_module.ELL_TILE_ROWS for _, n in WEBSPAM_BLOCKS)
+    assert 1 <= tiled < len(WEBSPAM_BLOCKS)
+    # each walk of a block books its form: the tiled blocks' share of all
+    assert forms["tile"] * len(WEBSPAM_BLOCKS) == tiled * (
+        forms["tile"] + forms["slot"])
+    assert slot_forms["tile"] == 0 and text != slots_text
+    compiled = jax.jit(problem.solve).lower(problem.objective(), batch,
+                                            x0).compile()
+    memory = compiled.memory_analysis()
+    assert 8 * WEBSPAM_SLOTS < memory.argument_size_in_bytes < 1.02 * (
+        8 * WEBSPAM_SLOTS + 12 * WEBSPAM_ROWS + 4 * WEBSPAM_DIM)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 0.5 * V5E_HBM_BYTES)
+
+
 # --- the factored coordinate's projection refit (PR 33) ---------------------
 
 # benchmark/configs/game-ml20m.json: the per-user buckets of one chip's
