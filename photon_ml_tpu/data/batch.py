@@ -124,6 +124,31 @@ class DenseBatch(NamedTuple):
             )
 
 
+class ProductSumBatch(DenseBatch):
+    """A :class:`DenseBatch`, the same leaves, whose two halves of a pass
+    are float32 products and sums: the arithmetic a solver's loop gets on
+    the TPU, where the compiler makes :meth:`DenseBatch.margins` a float32
+    ``multiply_reduce`` (and :meth:`DenseBatch.margin_pair` is one by
+    construction). At a program's top level it may make the same einsums
+    MXU convolutions over operands cut to bfloat16 (it does for a batch of
+    solves under ``vmap``), so a warm-started solve whose start were made
+    there would test float32 trials against a start of another precision.
+    ``optimize/lbfgs.py:start_evaluation`` makes a two-pass dense start on
+    a TPU in this form; the fused kernel's gate still sees a dense batch."""
+
+    __slots__ = ()
+
+    def margins(self, w_eff: Array, margin_shift: Array) -> Array:
+        with jax.named_scope(MARGINS_SCOPE):
+            return (jnp.sum(self.X * w_eff, axis=-1, dtype=self.acc_dtype)
+                    + margin_shift + self.offsets)
+
+    def weighted_feature_sum(self, row_scalars: Array) -> Array:
+        with jax.named_scope(FEATURE_SUM_SCOPE):
+            return jnp.sum(self.X * row_scalars[:, None], axis=0,
+                           dtype=self.acc_dtype)
+
+
 @jax.tree_util.register_pytree_node_class
 class EllBatch:
     """Padded row-sparse (ELL) design matrix, slot-major on the device.

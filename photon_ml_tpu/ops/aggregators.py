@@ -39,15 +39,13 @@ from photon_ml_tpu.parallel.quantized_collectives import qpsum
 Array = jnp.ndarray
 
 
-def _fused_kernels(form: str, batch, axis_name: Optional[str]):
+def fused_kernels_for(form: str, batch, axis_name: Optional[str]):
     """The one gate of both fused forms: ops/pallas_kernels (imported here
     alone, so only a dense batch ever loads Pallas) where the pass takes
     the fused ``form`` ("value_and_grad" or "hvp"), a dense batch at a real
     size and at a width the form wins at on a TPU (``pallas_supported``),
-    else None. Books the form the pass is traced in on
-    ``objective_lowerings{scope, form}``: trace time is when the form is
-    decided, so the count can never add a device sync."""
-    kernels = None
+    else None. Books nothing: the solver's start reads the same rule
+    (optimize/lbfgs.py:start_form)."""
     if isinstance(batch, DenseBatch) and batch.X.ndim == 2:
         from photon_ml_tpu.ops import pallas_kernels
 
@@ -57,7 +55,15 @@ def _fused_kernels(form: str, batch, axis_name: Optional[str]):
         if pallas_kernels.pallas_supported(
                 form, n, d, batch.X.dtype,
                 inside_shard_map=axis_name is not None):
-            kernels = pallas_kernels
+            return pallas_kernels
+    return None
+
+
+def _fused_kernels(form: str, batch, axis_name: Optional[str]):
+    """:func:`fused_kernels_for`, booking the form the pass is traced in on
+    ``objective_lowerings{scope, form}``: trace time is when the form is
+    decided, so the count can never add a device sync."""
+    kernels = fused_kernels_for(form, batch, axis_name)
     REGISTRY.counter("objective_lowerings").inc(
         scope="objective." + form,
         form="two_pass" if kernels is None else "fused")

@@ -51,8 +51,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from photon_ml_tpu.data.batch import EllBatch
+from photon_ml_tpu.data.batch import DenseBatch, EllBatch, ProductSumBatch
 from photon_ml_tpu.obs.metrics import REGISTRY
+from photon_ml_tpu.ops.aggregators import GLMObjective, fused_kernels_for
 from photon_ml_tpu.optimize.common import (
     BoxConstraints,
     RunHistory,
@@ -272,29 +273,66 @@ def record(trail: Array, at: Array, value: Array, by_select: bool) -> Array:
     return trail.at[at].set(value)
 
 
+def start_form(data) -> str:
+    """The form of a fresh solve's start for its ``data``, read from the
+    batch's layout type and the fused kernel's gate, as no option can:
+    ``in_loop`` where the data holds an ``EllBatch``; ``product_sum`` where
+    it holds a ``DenseBatch`` that the gate (``fused_kernels_for``, at the
+    objective's ``axis_name``) sends to the two-pass form on a TPU (on
+    another backend that form's einsums are float32 products and sums
+    already); ``direct`` for every other batch (the fused kernel's dense
+    batches, the factored refit's)."""
+    def layout_or_objective(node):
+        return isinstance(node, (EllBatch, DenseBatch, GLMObjective))
+
+    nodes = jax.tree.leaves(data, is_leaf=layout_or_objective)
+    if any(isinstance(node, EllBatch) for node in nodes):
+        return "in_loop"
+    axis_name = next((node.axis_name for node in nodes
+                      if isinstance(node, GLMObjective)), None)
+    if jax.default_backend() == "tpu" and any(
+            isinstance(node, DenseBatch)
+            and fused_kernels_for("value_and_grad", node, axis_name) is None
+            for node in nodes):
+        return "product_sum"
+    return "direct"
+
+
 def start_evaluation(value_and_grad_fn, x0: Array, data):
     """The value and gradient at ``x0`` that a fresh solve starts from, in
-    the form the batch's layout needs. Made at the top level of the
-    program, a row-sparse batch's (``EllBatch``) gather reads its table,
-    its indices and its output from HBM, at twice a line-search trial's
-    time a slot (PERF.md, PR 30). The line search evaluates inside a loop,
-    on the table ``x + a*d`` its body makes, and there the compiler places
-    all three in VMEM. So on that layout the start is evaluated the same
-    way: in a loop, on a table its body makes by a select, which keeps
-    every bit of ``x0`` (``x0 + 0*x0`` would turn an infinite entry into
-    NaN). The loop stops on a flag it sets, not on a counted trip, which
-    the compiler would see through and inline. Every other batch keeps
-    the direct call: a dense start is as fast or faster outside the loop
-    (PERF.md, section 5). Booked on ``solver_start_lowerings{site, form}``
-    at trace time, as ``objective_lowerings`` is."""
-    in_loop = any(
-        isinstance(node, EllBatch)
-        for node in jax.tree.leaves(
-            data, is_leaf=lambda node: isinstance(node, EllBatch)))
+    the form the batch's layout needs (:func:`start_form`). Made at the top
+    level of the program, a row-sparse batch's (``EllBatch``) gather reads
+    its table, its indices and its output from HBM, at twice a line-search
+    trial's time a slot (PERF.md, section 5). The line search evaluates
+    inside a loop, on the table ``x + a*d`` its body makes, and there the
+    compiler places all three in VMEM. So on that layout the start is
+    evaluated the same way (``in_loop``): in a loop, on a table its body
+    makes by a select, which keeps every bit of ``x0`` (``x0 + 0*x0`` would
+    turn an infinite entry into NaN). The loop stops on a flag it sets, not
+    on a counted trip, which the compiler would see through and inline.
+
+    A dense batch's two-pass start (``product_sum``) is made on the same
+    leaves as a ``ProductSumBatch``: float32 products and sums, the
+    arithmetic the line search's trials get inside the loop. At the top
+    level the TPU compiler may make the einsums MXU convolutions over
+    operands cut to bfloat16 (it does for the per-entity solves under
+    ``vmap``), exact from a zero start (``X 0 = 0``) but not from a warm
+    one, where the search would test float32 trials against a start of
+    another precision (PERF.md, section 6). The fused kernel's starts
+    (``direct``) already run at its loop's precision and stay the direct
+    call. Booked on ``solver_start_lowerings{site, form}`` at trace time,
+    as ``objective_lowerings`` is."""
+    form = start_form(data)
     REGISTRY.counter("solver_start_lowerings").inc(
-        site="optimizer.lbfgs", form="in_loop" if in_loop else "direct")
-    if not in_loop:
+        site="optimizer.lbfgs", form=form)
+    if form == "direct":
         return value_and_grad_fn(x0, data)
+    if form == "product_sum":
+        with jax.named_scope("lbfgs.start"):
+            return value_and_grad_fn(x0, jax.tree.map(
+                lambda node: (ProductSumBatch(*node)
+                              if isinstance(node, DenseBatch) else node),
+                data, is_leaf=lambda node: isinstance(node, DenseBatch)))
 
     def body(c):
         done, _, _ = c

@@ -459,6 +459,93 @@ def test_the_line_from_carried_margins_is_the_full_evaluation(rng, loss):
             <= 2e-6 * scale
 
 
+@pytest.mark.parametrize("batched", (False, True),
+                         ids=("unbatched", "vmapped"))
+@pytest.mark.parametrize("loss", ["logistic", "squared"])
+def test_a_two_pass_dense_start_in_product_sums_is_the_two_pass_evaluation(
+        rng, as_on_one_tpu, loss, batched):
+    """A dense batch that the fused kernel's gate sends to the two-pass form
+    on a TPU starts in float32 products and sums (``product_sum``, booked
+    once a trace): at a warm point its value and gradient are
+    ``value_and_gradient``'s two-pass einsums to float32 rounding, through
+    offsets, weights and a normalization's shifts and factors, for one
+    solve and for lanes under ``vmap``."""
+    from photon_ml_tpu.obs.metrics import REGISTRY
+    from photon_ml_tpu.optimize.lbfgs import start_evaluation
+
+    problems = [_line_problem(loss, rng) for _ in range(3 if batched else 1)]
+    obj = problems[0][0]
+
+    def start(x, batch):
+        return start_evaluation(_obj_vg, x, (obj, batch))
+
+    def two_pass(x, batch):
+        return obj.calculate(x, batch)
+
+    if batched:
+        x = jnp.stack([p[2] for p in problems])
+        batch = jax.tree.map(lambda *leaves: jnp.stack(leaves),
+                             *[p[1] for p in problems])
+        start, two_pass = jax.vmap(start), jax.vmap(two_pass)
+    else:
+        x, batch = problems[0][2], problems[0][1]
+    booked = REGISTRY.counter("solver_start_lowerings")
+    before = booked.value(site="optimizer.lbfgs", form="product_sum")
+    f, g = jax.jit(start)(x, batch)
+    assert booked.value(site="optimizer.lbfgs",
+                        form="product_sum") == before + 1
+    f_ref, g_ref = jax.jit(two_pass)(x, batch)
+    assert f.dtype == g.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(f), np.asarray(f_ref), rtol=2e-6)
+    scale = float(jnp.max(jnp.abs(g_ref)))
+    np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), rtol=0,
+                               atol=2e-6 * scale)
+
+
+# (backend, devices, rows, columns or "ell", objective axis) -> start form
+START_FORMS = {
+    "cpu-narrow": ("cpu", 1, 10_000_054, 65, None, "direct"),
+    "tpu-narrow": ("tpu", 1, 10_000_054, 65, None, "product_sum"),
+    "tpu-small": ("tpu", 1, 512, 2048, None, "product_sum"),
+    "tpu-wide": ("tpu", 1, 262_144, 2048, None, "direct"),
+    "tpu-wide-four-chips": ("tpu", 4, 262_144, 2048, None, "product_sum"),
+    "tpu-wide-shard-map": ("tpu", 4, 262_144, 2048, "data", "direct"),
+    "tpu-row-sparse": ("tpu", 1, 1024, "ell", None, "in_loop"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(START_FORMS))
+def test_the_start_form_follows_the_layout_and_the_kernels_gate(
+        monkeypatch, case):
+    """``start_form`` reads the payload alone: ``in_loop`` for a row-sparse
+    batch; for a dense one ``direct`` where the fused kernel's gate takes
+    the pass (its width and size rule, and off ``shard_map`` one device:
+    an objective's ``axis_name`` says it runs under one) and everywhere off
+    a TPU, else ``product_sum``."""
+    import dataclasses
+
+    from photon_ml_tpu.data.batch import DenseBatch, EllBatch
+    from photon_ml_tpu.optimize.lbfgs import start_form
+
+    backend, devices, n, d, axis, form = START_FORMS[case]
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "device_count", lambda: devices)
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    if d == "ell":
+        batch = EllBatch(sds(39, n, dtype=jnp.int32), sds(39, n), sds(n),
+                         sds(n), sds(n), dim=1_000_000)
+    else:
+        batch = DenseBatch(sds(n, d), sds(n), sds(n), sds(n))
+    obj = dataclasses.replace(
+        GLMObjective(loss=get_loss("logistic"), l2_lambda=1.0),
+        axis_name=axis)
+    assert start_form((obj, batch)) == form
+    assert start_form(None) == "direct"
+
+
 @pytest.mark.parametrize("newest_first", (False, True),
                          ids=("circular", "newest_first"))
 def test_a_carry_goes_back_into_its_own_layout(rng, newest_first):

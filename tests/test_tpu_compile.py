@@ -583,28 +583,60 @@ def _start_scopes(text):
             and "lbfgs.linesearch" not in name}
 
 
-@pytest.mark.parametrize("solve", ["dense-2048", "fixed-effect-65", "criteo"])
+# "%name = f32[675,128]{...} opcode(...), metadata={op_name="..."}": an
+# instruction's result type, its opcode and its scope path
+_HLO_OP = re.compile(
+    r"= (\S+) ([\w-]+)\(.*op_name=\"([^\"]*)\"", re.MULTILINE)
+
+
+def _per_user_bucket_solve(one_chip):
+    """The per-user bucket program at ``GLMIX_BUCKET``: the vmapped L-BFGS
+    solves of a GLMix bucket, each making its own start."""
+    from photon_ml_tpu.game import random_effect
+
+    e, n, d = GLMIX_BUCKET
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    obj = GLMObjective(loss=get_loss("logistic"), l2_lambda=1.0)
+    return random_effect._fit_blocks.lower(
+        sds(e, n, d), sds(e, n), sds(e, n), sds(e, n), sds(e, d), obj,
+        sds(d), solver="lbfgs", max_iter=8, tolerance=1e-30).compile()
+
+
+@pytest.mark.parametrize("solve", ["dense-2048", "fixed-effect-65",
+                                   "per-user-bucket", "criteo"])
 def test_the_start_is_made_in_a_loop_for_a_row_sparse_batch_alone(
         one_chip, as_on_one_tpu, solve):
     """``solver_start_lowerings{site, form}`` books the form once a traced
     L-BFGS program: ``in_loop`` for the ``EllBatch`` solve, whose start
-    sits inside ``lbfgs.start``'s ``while``, and ``direct`` for the dense
-    solves (the GLM cells' at 2,048 columns, the sweep cells' 65-wide fixed
-    effect), whose start stays at the program's top level: its scope path
-    holds no ``/while/`` (a dense start is as fast or faster there: PERF.md,
-    section 5)."""
+    sits inside ``lbfgs.start``'s ``while``; ``product_sum`` for the dense
+    solves the fused kernel's gate sends to the two-pass form (the sweep
+    cells' 65-wide fixed effect, a per-user bucket under ``vmap``), whose
+    start stays at the program's top level under ``lbfgs.start``, in
+    float32 products and sums: no operation there is a ``convolution``
+    (the MXU's form of a default-precision einsum at the top level, over
+    operands cut to bfloat16) or makes a ``bf16`` array; and ``direct`` for
+    the fused kernel's dense solve (the GLM cells' at 2,048 columns), whose
+    start is the kernel's call at the top level: its scope path holds no
+    ``/while/``."""
     from photon_ml_tpu.obs.metrics import REGISTRY
 
     counter = REGISTRY.counter("solver_start_lowerings")
-    forms = ("in_loop", "direct")
+    forms = ("in_loop", "product_sum", "direct")
     before = {f: counter.value(site="optimizer.lbfgs", form=f) for f in forms}
     jax.clear_caches()  # the solver's trace is cached across programs
     if solve == "criteo":
         text, form = _criteo_solve(one_chip).as_text(), "in_loop"
+    elif solve == "per-user-bucket":
+        text = _per_user_bucket_solve(one_chip).as_text()
+        form = "product_sum"
     else:
-        (n, d), form = {"dense-2048": GLM_SHAPE,
-                        "fixed-effect-65": (GLMIX_ROWS, GLMIX_FIXED_DIM)}[
-            solve], "direct"
+        (n, d), form = {
+            "dense-2048": (GLM_SHAPE, "direct"),
+            "fixed-effect-65": ((GLMIX_ROWS, GLMIX_FIXED_DIM),
+                                "product_sum")}[solve]
         problem = _l2_problem(6, 1e-30, 10.0)
         x0 = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=one_chip)
         text = jax.jit(problem.solve).lower(
@@ -612,10 +644,27 @@ def test_the_start_is_made_in_a_loop_for_a_row_sparse_batch_alone(
         ).as_text()
     assert {f: counter.value(site="optimizer.lbfgs", form=f) - before[f]
             for f in forms} == {f: int(f == form) for f in forms}
+    if form == "product_sum":
+        start = [(out, opcode, path)
+                 for out, opcode, path in _HLO_OP.findall(text)
+                 if "lbfgs.start/" in path]
+        paths = {path for _, _, path in start}
+        assert any("objective.margins" in p for p in paths)
+        assert any("objective.feature_sum" in p for p in paths)
+        assert not any("/while/" in p for p in paths), paths
+        assert [(out, opcode) for out, opcode, _ in start
+                if opcode == "convolution" or out.startswith("bf16")] == []
+        return
     scopes = _start_scopes(text)
     assert scopes
     if form == "direct":
         assert not any("/while/" in scope for scope in scopes), scopes
+        calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+                 for line in text.splitlines()
+                 if " custom-call(" in line and "op_name=" in line]
+        assert any("objective.value_and_grad" in path
+                   and "/while/" not in path for path in calls), calls
+        assert "lbfgs.start" not in text
     else:
         assert all(scope.endswith("lbfgs.start/while/body/")
                    for scope in scopes), scopes
